@@ -1,7 +1,10 @@
 # TPU-native DSS server image (the analog of the reference's
 # single-binary Dockerfile).  The CPU jax wheel is installed by
-# default; on TPU hosts swap in the libtpu wheel at build time:
-#   docker build --build-arg JAX_EXTRA="jax[tpu]" .
+# default and the image names that backend (JAX_PLATFORMS=cpu); on TPU
+# hosts swap in the libtpu wheel AND name the tpu backend at build
+# time — `--storage tpu` never falls back to the CPU on its own:
+#   docker build --build-arg JAX_EXTRA="jax[tpu]" \
+#       --build-arg JAX_PLATFORMS=tpu .
 
 # Stage 1: compile the native host kernels (covering, host query,
 # window pack/decode).  The runtime image is slim (no toolchain), so
@@ -20,6 +23,8 @@ RUN python /src/native/_buildlib.py /src/native
 FROM python:3.12-slim
 
 ARG JAX_EXTRA=""
+ARG JAX_PLATFORMS=cpu
+ENV JAX_PLATFORMS=${JAX_PLATFORMS}
 
 WORKDIR /app
 COPY pyproject.toml README.md ./

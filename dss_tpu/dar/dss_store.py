@@ -1964,6 +1964,13 @@ class DSSStore:
         # dropped counters, ring depth, and the allocation counter the
         # zero-cost-when-disabled contract is asserted against
         out.update(trace.stats())
+        if self.storage == "tpu":
+            # XLA compiles of this process (count, seconds, persistent
+            # cache hits) — after boot warm each one is a compile on a
+            # request or fold path
+            from dss_tpu.ops import compile_stats
+
+            out.update(compile_stats())
         if self.region is not None:
             out.update(self.region.stats())
         return out

@@ -503,10 +503,15 @@ def stats(tiers: Tuple[Tier, ...]) -> dict:
     l0 = len(tiers[0].snap.ids) if tiers else 0
     l1 = sum(len(t.snap.ids) for t in tiers[1:])
     shadowed = sum(t.dead_count for t in tiers)
+    fasts = [t.snap.fast for t in tiers if t.snap.fast is not None]
     return {
         "tier_count": len(tiers),
         "tier_l0_records": l0,
         "tier_l1_records": l1,
         "tier_l0_dead": tiers[0].dead_count if tiers else 0,
         "tier_shadowed_rows": shadowed,
+        # what the device holds for this class: postings across tiers
+        # and the bytes of their block columns
+        "tier_postings": sum(ft.n_postings for ft in fasts),
+        "tier_device_bytes": sum(ft.device_bytes() for ft in fasts),
     }

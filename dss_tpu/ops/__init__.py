@@ -3,11 +3,80 @@
 x64 is enabled globally: entity times are exact int64 unix-nanoseconds
 on device, matching the reference's timestamp comparison semantics
 (pkg/scd/store/cockroach/operations.go:374-435).
+
+Importing this package also places JAX's persistent compilation cache
+(place_compile_cache) — every process that compiles a kernel imports
+it first, so the placement lands before the first compile.
 """
+
+import os
+import threading
+from typing import Optional
 
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+# the cache directory is part of the cache key's lookup path: it must
+# be the same in every run, so it is derived from the checkout alone —
+# never from tempfile, a pid, the cwd or the clock
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def place_compile_cache(environ=os.environ) -> Optional[str]:
+    """Place JAX's persistent compilation cache.  With
+    JAX_COMPILATION_CACHE_DIR set, JAX itself reads the directory from
+    the environment and this sets nothing (returns None); without it,
+    the one fixed directory inside the checkout is used (returned)."""
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
+place_compile_cache()
+
+
+class _CompileCounter:
+    """Process-wide count of XLA backend compiles, fed by JAX's own
+    monitoring events: every executable build (jit first call or AOT
+    .compile()) and every persistent-cache hit among them.  A compile
+    after boot warm is a compile on somebody's request path — the
+    dss_jax_* gauges (DSSStore.stats) make that countable."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_hits = 0
+
+    def on_duration(self, event: str, duration_secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            with self._lock:
+                self.compiles += 1
+                self.compile_s += duration_secs
+
+    def on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self.cache_hits += 1
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {
+                "dss_jax_compiles": self.compiles,
+                "dss_jax_compile_seconds": round(self.compile_s, 3),
+                "dss_jax_compile_cache_hits": self.cache_hits,
+            }
+
+
+_COMPILES = _CompileCounter()
+jax.monitoring.register_event_duration_secs_listener(_COMPILES.on_duration)
+jax.monitoring.register_event_listener(_COMPILES.on_event)
+compile_stats = _COMPILES.stats
 
 from dss_tpu.ops.conflict import (  # noqa: F401,E402
     EntityTable,

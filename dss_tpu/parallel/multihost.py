@@ -78,6 +78,11 @@ ENV_PROCESS_ID = "DSS_PROCESS_ID"
 ENV_NUM_PROCESSES = "DSS_NUM_PROCESSES"
 ENV_DRYRUN = "DSS_MULTIHOST_DRYRUN"
 
+# the coordination service declares a task dead (and tears the job down)
+# after this long without a heartbeat; the watchdog barrier owns
+# liveness here, so the stock timeout is pushed out of reach (10 years)
+_HEARTBEAT_TIMEOUT_S = 10 * 365 * 86400
+
 # exported gauge family (test_deploy_observability imports this)
 MULTIHOST_METRICS = (
     "dss_multihost_processes",
@@ -305,11 +310,10 @@ def initialize(cfg: MultihostConfig) -> MultihostRuntime:
 
     Differences from stock `jax.distributed.initialize`, all in
     service of serving availability:
-      - heartbeat intervals are effectively disabled: the stock
+      - the heartbeat timeout is pushed out of reach: the stock
         missed-heartbeat path TERMINATES the surviving processes
-        (training semantics — and jaxlib's custom-callback override
-        crashes with a nanobind cast bug), while a serving mesh must
-        outlive a peer.  Liveness belongs to the watchdog barrier.
+        (training semantics), while a serving mesh must outlive a
+        peer.  Liveness belongs to the watchdog barrier.
       - shutdown_on_destruction=False: a degraded survivor must not
         block on dead peers at exit.
       - dryrun_devices forces the virtual-CPU backend + gloo
@@ -341,7 +345,7 @@ def initialize(cfg: MultihostConfig) -> MultihostRuntime:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     from jax._src import distributed
-    from jax._src.lib import xla_extension
+    from jax._src.lib import _jax
 
     state = distributed.global_state
     if state.client is not None:
@@ -349,20 +353,18 @@ def initialize(cfg: MultihostConfig) -> MultihostRuntime:
     service = None
     if cfg.process_id == 0:
         bind = "[::]:" + cfg.coordinator.rsplit(":", 1)[1]
-        service = xla_extension.get_distributed_runtime_service(
+        service = _jax.get_distributed_runtime_service(
             bind,
             cfg.num_processes,
             # the watchdog owns liveness — see the docstring
-            heartbeat_interval=3600,
-            max_missing_heartbeats=1_000_000,
+            heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
         )
         state.service = service
-    client = xla_extension.get_distributed_runtime_client(
+    client = _jax.get_distributed_runtime_client(
         cfg.coordinator,
         cfg.process_id,
         init_timeout=int(cfg.init_timeout_s),
-        heartbeat_interval=3600,
-        max_missing_heartbeats=1_000_000,
+        heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
         shutdown_on_destruction=False,
     )
     client.connect()

@@ -1281,7 +1281,21 @@ class ShardedReplica:
             "dss_shard_members": len(
                 {d.process_index for d in self.mesh.devices.flat}
             ),
+            "dss_shard_devices": self._shard_devices(),
         }
+
+    def _shard_devices(self) -> int:
+        """Distinct devices that HOLD a postings shard of the live ops
+        snapshot, read off the array itself — a mesh that names four
+        devices proves nothing if every shard landed on device 0.
+        0 before the first ops snapshot."""
+        snap = self._snapshots["ops"]
+        dar = None if snap is None else (snap.base or snap.delta)
+        if dar is None:
+            return 0
+        return len(
+            {sh.device.id for sh in dar.post_key.addressable_shards}
+        )
 
     def stats(self) -> dict:
         out = {

@@ -29,23 +29,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental location
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# the "skip the replication check" kwarg was renamed check_rep ->
-# check_vma across jax versions; resolve the supported name once
-import inspect as _inspect
-
-_SHMAP_NOCHECK = {
-    (
-        "check_vma"
-        if "check_vma" in _inspect.signature(shard_map).parameters
-        else "check_rep"
-    ): False
-}
 
 from dss_tpu.dar import oracle
 from dss_tpu.dar.oracle import Record
@@ -414,7 +399,7 @@ def sharded_conflict_query_batch(
             qspec,  # owner
         ),
         out_specs=out_specs,
-        **_SHMAP_NOCHECK,
+        check_vma=False,
     )(
         post_key,
         post_ent,

@@ -3,9 +3,8 @@
 The PR 5/6 cost models converge online, but a fresh process pays the
 winsorized-EWMA learning window under live traffic: until enough
 batches have been observed, the router runs on the compiled-in
-defaults, which can be 10-100x off on a given host (a tunneled dev box
-vs an attached TPU differ by ~3 orders of magnitude on the dispatch
-floor).  The mapper papers in PAPERS.md (GOMA; data-placement
+defaults, which can be far off on a given host (the dispatch floor
+is a property of the backend, not of this code).  The mapper papers in PAPERS.md (GOMA; data-placement
 evaluation of spatial accelerators) frame route x tile x batch choice
 as a *searched mapping* over an analytical cost model — and a
 searchable mapping can be tuned offline.

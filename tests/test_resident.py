@@ -458,11 +458,12 @@ def test_loop_ring_backpressure_and_delivery():
 
     try:
         assert loop.enqueue(_payload(), done)
-        # feeder is stalled in the gated submit; ring holds the rest
+        # wait until the feeder has TAKEN the first batch (it then
+        # stalls in the gated submit); the ring holds the rest.  Racing
+        # ahead while the batch still sits in the ring would fill the
+        # cap one enqueue early.
         deadline = time.time() + 5.0
-        while loop.stats()["ring_depth"] == 0 and loop.stats()[
-            "submitted"
-        ] == 0 and time.time() < deadline:
+        while loop.stats()["ring_depth"] != 0 and time.time() < deadline:
             time.sleep(0.005)
         assert loop.enqueue(_payload(), done)
         assert loop.enqueue(_payload(), done)
