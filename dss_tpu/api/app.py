@@ -303,8 +303,11 @@ async def _call(fn, *args, request=None):
     lag_bound = _request_lag_bound(request)
     th = _trace_handle(request)
     t0 = time.perf_counter()
+    ran_s = 0.0  # what run() itself took, on the executor thread
 
     def run():
+        nonlocal ran_s
+        t_run = time.perf_counter()
         if sink is not None:
             _stages.set_sink(sink)
         if route_dl is not None:
@@ -335,14 +338,23 @@ async def _call(fn, *args, request=None):
                 _stages.set_sink(None)
             if route_dl is not None:
                 _deadline.set_route_deadline(None)
+            ran_s = time.perf_counter() - t_run
 
     try:
         return await loop.run_in_executor(None, run)
     finally:
         if sink is not None:
-            sink["service_ms"] = round(
-                (time.perf_counter() - t0) * 1000, 3
-            )
+            awaited = time.perf_counter() - t0
+            sink["service_ms"] = round(awaited * 1000, 3)
+            # both hops of run_in_executor: the wait for a pool thread
+            # and the loop's wake-up after it finished.  Only a request
+            # that leaves the loop pays it (a run() that never started
+            # — cancelled in the pool's queue — marks nothing)
+            if ran_s > 0.0:
+                sink["exec_wait_ms"] = round(
+                    sink.get("exec_wait_ms", 0.0)
+                    + max(0.0, awaited - ran_s) * 1000, 3
+                )
 
 
 async def _call_r(request, fn, *args):
@@ -810,6 +822,36 @@ def build_app(
     app.router.add_get("/aux/v1/debug/traces", debug_traces)
 
     if metrics is not None:
+        from dss_tpu.obs.metrics import LOOP_ROUTE
+
+        async def loop_lag(_app):
+            """The event loop's own gauge: a 10 Hz self-timer whose
+            lateness is the stage `loop_lag_ms` under the fixed route
+            LOOP_ROUTE, so it merges across the front like every other
+            stage.  A late timer is what a request's callbacks see too:
+            the loop was busy with (or queued behind) other work.  An
+            idle loop still reads up to a millisecond: the selector
+            rounds its timeout up to one."""
+
+            async def tick():
+                loop = asyncio.get_running_loop()
+                while True:
+                    due = loop.time() + 0.1
+                    await asyncio.sleep(0.1)
+                    metrics.observe_stage(
+                        LOOP_ROUTE, "loop_lag_ms",
+                        max(0.0, loop.time() - due),
+                    )
+
+            task = asyncio.get_running_loop().create_task(tick())
+            yield
+            task.cancel()
+            try:
+                await task
+            except asyncio.CancelledError:
+                pass
+
+        app.cleanup_ctx.append(loop_lag)
 
         async def metrics_handler(request):
             if stats_fn is not None:
@@ -878,20 +920,37 @@ def build_app(
             if not profile_lock.acquire(blocking=False):
                 raise errors.unavailable("a profile capture is running")
 
+            # python=1: also hook every Python call of every thread
+            # (the profiler's Python tracer).  Off by default: under it
+            # the capture measures the capture (PERF.md section 6), and
+            # the program's own seams are on the timeline without it
+            # (obs/trace.annotate: dss.* events)
+            python = request.query.get("python", "0") == "1"
+
             def capture():
+                from dss_tpu.obs import trace as _trace
+
                 try:
                     import jax
 
-                    with jax.profiler.trace(profile_dir):
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 1 if python else 0
+                    opts.host_tracer_level = 2
+                    _trace.set_capture(True)
+                    with jax.profiler.trace(
+                        profile_dir, profiler_options=opts
+                    ):
                         time.sleep(seconds)
                 finally:
+                    _trace.set_capture(False)
                     profile_lock.release()
 
             await asyncio.get_running_loop().run_in_executor(
                 profile_pool, capture
             )
             return web.json_response(
-                {"profile_dir": profile_dir, "seconds": seconds}
+                {"profile_dir": profile_dir, "seconds": seconds,
+                 "python": python}
             )
 
         app.router.add_post("/debug/profile", debug_profile)

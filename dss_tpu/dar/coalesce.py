@@ -381,6 +381,9 @@ class QueryCoalescer:
         self._stat_batches = 0
         self._stat_items = 0
         self._stat_inline = 0
+        self._stat_inline_device = 0
+        self._stat_inline_submit_ms = 0.0
+        self._stat_inline_collect_ms = 0.0
         self._stat_shed = 0
         self._stat_deadline_shed = 0
         self._stat_route_host = 0  # batches fully served on the host
@@ -791,9 +794,16 @@ class QueryCoalescer:
             if item.deadline is not None and not item.allow_stale:
                 hr = max(0.0, (item.deadline - self._clock()) * 1000.0)
             try:
-                self._execute([item], headroom_ms=hr)
+                ran = self._execute([item], headroom_ms=hr)
                 with self._slock:
                     self._stat_inline += 1
+                    if ran is not None:
+                        # what the inline caller's thread itself paid:
+                        # the pipeline's pack/collect totals never see it
+                        used_device, submit_ms, collect_ms = ran
+                        self._stat_inline_device += bool(used_device)
+                        self._stat_inline_submit_ms += submit_ms
+                        self._stat_inline_collect_ms += collect_ms
             finally:
                 with self._cond:
                     self._busy = False
@@ -984,7 +994,8 @@ class QueryCoalescer:
                 # also wait while an inline batch is executing: its
                 # arrivals should form ONE next batch, not race it
                 while (not self._queue or self._busy) and not self._closed:
-                    self._cond.wait()
+                    with _trace.annotate("coalesce.idle"):
+                        self._cond.wait()
                 if self._closed and not self._queue:
                     break
                 batch, expired, headroom_ms = self._drain_locked()
@@ -1023,6 +1034,8 @@ class QueryCoalescer:
             kind = "exec"
             host_route = False
             used_device = False
+            pack_ann = _trace.annotate("coalesce.pack")
+            pack_ann.__enter__()
             try:
                 submit = getattr(self._table, "query_many_submit", None)
                 if submit is not None:
@@ -1033,7 +1046,8 @@ class QueryCoalescer:
                     # local fallback re-plans inline)
                     if traced:
                         tp_w, tp0 = time.time_ns(), time.perf_counter()
-                    route = self._plan_batch(batch, headroom_ms).route
+                    with _trace.annotate("plan"):
+                        route = self._plan_batch(batch, headroom_ms).route
                     if traced:
                         tr_spans.append((
                             "plan", tp_w,
@@ -1047,6 +1061,7 @@ class QueryCoalescer:
                             # stream, its collector delivers + feeds
                             # the resident cost key.  Nothing goes
                             # through the collect stage.
+                            pack_ann.__exit__(None, None, None)
                             with self._cond:
                                 self._packing = False
                                 self._cond.notify_all()
@@ -1074,12 +1089,13 @@ class QueryCoalescer:
                                 td0 = time.perf_counter()
                             try:
                                 # chaos seam: the cold fused dispatch
-                                chaos.fault_point("device.dispatch")
-                                pq = submit(
-                                    keys, lo, hi, t0s, t1s,
-                                    now=now, owner_ids=owners,
-                                    host_route=False,
-                                )
+                                with _trace.annotate("device.dispatch"):
+                                    chaos.fault_point("device.dispatch")
+                                    pq = submit(
+                                        keys, lo, hi, t0s, t1s,
+                                        now=now, owner_ids=owners,
+                                        host_route=False,
+                                    )
                             except BaseException as e:
                                 if not self._absorb_device_loss(e):
                                     raise
@@ -1102,6 +1118,7 @@ class QueryCoalescer:
                                         {"used_device": used_device},
                                     ))
             except BaseException as e:  # noqa: BLE001 — deliver to callers
+                pack_ann.__exit__(None, None, None)
                 self._deliver_error(batch, e)
                 with self._cond:
                     self._packing = False
@@ -1109,6 +1126,7 @@ class QueryCoalescer:
                     self._inflight_items -= len(batch)
                     self._cond.notify_all()
                 continue
+            pack_ann.__exit__(None, None, None)
             pack_ms = (time.perf_counter() - t0) * 1000
             if traced:
                 tr_spans.append(("coalesce.pack", t0_w, pack_ms, None))
@@ -1143,7 +1161,8 @@ class QueryCoalescer:
         """Stage 2: wait for the device, decode, deliver results, and
         feed the batch-size controller + the route cost models."""
         while True:
-            handoff = self._inflight_q.get()
+            with _trace.annotate("coalesce.idle"):
+                handoff = self._inflight_q.get()
             if handoff is _DONE:
                 return
             (batch, kind, pq, pack_ms, host_route, used_device,
@@ -1157,10 +1176,12 @@ class QueryCoalescer:
             observed_device = used_device
             try:
                 if kind == "table":
-                    pq.wait_device()
+                    with _trace.annotate("device.wait"):
+                        pq.wait_device()
                     t1 = time.perf_counter()
                     device_ms = (t1 - t0) * 1000
-                    results = self._table.query_many_collect(pq)
+                    with _trace.annotate("collect"):
+                        results = self._table.query_many_collect(pq)
                     if tr_spans is not None:
                         coll_ms = (time.perf_counter() - t1) * 1000
                         now_w = time.time_ns()
@@ -1187,12 +1208,13 @@ class QueryCoalescer:
                     if tr_spans is not None:
                         th_w = time.time_ns()
                         th0 = time.perf_counter()
-                    pq = self._table.query_many_submit(
-                        keys, lo, hi, t0s, t1s,
-                        now=now, owner_ids=owners, host_route=True,
-                    )
-                    observed_device = self._pq_used_device(pq)
-                    results = self._table.query_many_collect(pq)
+                    with _trace.annotate("host.scan"):
+                        pq = self._table.query_many_submit(
+                            keys, lo, hi, t0s, t1s,
+                            now=now, owner_ids=owners, host_route=True,
+                        )
+                        observed_device = self._pq_used_device(pq)
+                        results = self._table.query_many_collect(pq)
                     if tr_spans is not None:
                         self._stamp_spans(batch, tr_spans + [
                             ("host.scan", th_w,
@@ -1417,6 +1439,9 @@ class QueryCoalescer:
 
     def _execute(self, batch: List[_Item], headroom_ms=None,
                  record_plan: bool = True):
+        """-> (used_device, submit_ms, collect_ms) of a batch run
+        through the table's split halves on this thread, else None
+        (mesh-served, a submit-less table, or an error delivered)."""
         try:
             b = len(batch)
             traced = any(it.tctx is not None for it in batch)
@@ -1429,13 +1454,14 @@ class QueryCoalescer:
             # at pack time.
             if traced:
                 tp_w, tp0 = time.time_ns(), time.perf_counter()
-            plan = self._planner.plan(
-                self._shape_of(batch, inline=True),
-                self._capture_state(host_only=budget.is_host_only()),
-                headroom_ms,
-                allow_resident=False,
-                record=record_plan,
-            )
+            with _trace.annotate("plan"):
+                plan = self._planner.plan(
+                    self._shape_of(batch, inline=True),
+                    self._capture_state(host_only=budget.is_host_only()),
+                    headroom_ms,
+                    allow_resident=False,
+                    record=record_plan,
+                )
             plan_span = None
             if traced:
                 plan_span = (
@@ -1470,7 +1496,7 @@ class QueryCoalescer:
                             it.result = res
                             it.event.set()
                     self.mesh_offloads += 1
-                    return
+                    return None
                 except Exception:  # noqa: BLE001 — fall back local
                     import logging
 
@@ -1488,30 +1514,37 @@ class QueryCoalescer:
             t0 = time.perf_counter()
             t0_w = time.time_ns() if traced else 0
             used_device = None
+            disp_ms = coll_ms = 0.0
             if submit is not None:
                 # run the split halves so the chosen route is
                 # observable: inline traffic must feed the cost models
                 # too, or a low-load deployment would route on the
-                # boot seed forever
+                # boot seed forever.  The split is timed always: the
+                # inline counters (stats: co_inline_*) read it
                 try:
-                    if not host_route:
-                        chaos.fault_point("device.dispatch")
-                    pq = submit(
-                        keys, lo, hi, t0s, t1s, now=now,
-                        owner_ids=owners, host_route=host_route,
-                    )
+                    with _trace.annotate(
+                        "host.scan" if host_route else "device.dispatch"
+                    ):
+                        if not host_route:
+                            chaos.fault_point("device.dispatch")
+                        pq = submit(
+                            keys, lo, hi, t0s, t1s, now=now,
+                            owner_ids=owners, host_route=host_route,
+                        )
                     used_device = self._pq_used_device(pq)
-                    if traced:
-                        disp_ms = (time.perf_counter() - t0) * 1000
-                        tc_w, tc0 = time.time_ns(), time.perf_counter()
-                    results = self._table.query_many_collect(pq)
+                    tc0 = time.perf_counter()
+                    disp_ms = (tc0 - t0) * 1000
+                    tc_w = time.time_ns() if traced else 0
+                    with _trace.annotate(
+                        "host.scan" if host_route else "collect"
+                    ):
+                        results = self._table.query_many_collect(pq)
+                    coll_ms = (time.perf_counter() - tc0) * 1000
                     if traced:
                         spans = [plan_span]
                         if host_route:
                             spans.append((
-                                "host.scan", t0_w,
-                                disp_ms
-                                + (time.perf_counter() - tc0) * 1000,
+                                "host.scan", t0_w, disp_ms + coll_ms,
                                 None,
                             ))
                         else:
@@ -1523,9 +1556,7 @@ class QueryCoalescer:
                                 {"used_device": bool(used_device)},
                             ))
                             spans.append((
-                                "collect", tc_w,
-                                (time.perf_counter() - tc0) * 1000,
-                                None,
+                                "collect", tc_w, coll_ms, None,
                             ))
                         self._stamp_spans(batch, spans)
                 except BaseException as e:
@@ -1539,11 +1570,16 @@ class QueryCoalescer:
                     )
                     used_device = False
                     results = self._table.query_many_collect(pq)
+                    # the whole of it, the lost attempt included, was
+                    # this caller's submit-side time
+                    disp_ms = (time.perf_counter() - t0) * 1000
+                    coll_ms = 0.0
             else:
-                results = self._table.query_many(
-                    keys, lo, hi, t0s, t1s, now=now, owner_ids=owners,
-                    host_route=host_route,
-                )
+                with _trace.annotate("host.scan"):
+                    results = self._table.query_many(
+                        keys, lo, hi, t0s, t1s, now=now,
+                        owner_ids=owners, host_route=host_route,
+                    )
                 if traced:
                     self._stamp_spans(batch, [plan_span, (
                         "host.scan", t0_w,
@@ -1557,8 +1593,12 @@ class QueryCoalescer:
                     elif host_route or b >= self._cost.chunk:
                         self._cost.observe_host(b, total_ms)
             self._deliver_results(batch, results)
+            if used_device is None:
+                return None
+            return used_device, disp_ms, coll_ms
         except BaseException as e:  # noqa: BLE001 — deliver to callers
             self._deliver_error(batch, e)
+            return None
 
     # -- introspection --------------------------------------------------------
 
@@ -1582,6 +1622,18 @@ class QueryCoalescer:
                 co_batches=self._stat_batches,
                 co_items=self._stat_items,
                 co_inline=self._stat_inline,
+                # inline executions: how many launched the kernel, and
+                # the caller's own submit (pack + dispatch) / collect
+                # (device wait + decode) time — new names: co_pack_ms_
+                # total / co_collect_ms_total / co_batches stay the
+                # pipeline's alone
+                co_inline_device=self._stat_inline_device,
+                co_inline_submit_ms_total=round(
+                    self._stat_inline_submit_ms, 3
+                ),
+                co_inline_collect_ms_total=round(
+                    self._stat_inline_collect_ms, 3
+                ),
                 co_shed=self._stat_shed,
                 co_deadline_shed=self._stat_deadline_shed,
                 co_route_host_batches=self._stat_route_host,

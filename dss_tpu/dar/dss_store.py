@@ -1968,9 +1968,13 @@ class DSSStore:
             # XLA compiles of this process (count, seconds, persistent
             # cache hits) — after boot warm each one is a compile on a
             # request or fold path
-            from dss_tpu.ops import compile_stats
+            from dss_tpu.ops import compile_stats, device_memory_stats
 
             out.update(compile_stats())
+            # the allocator's bytes in use and peak, read at scrape
+            # time; series only where the backend reports them (the
+            # leader's chip — a worker's CPU backend reports none)
+            out.update(device_memory_stats())
         if self.region is not None:
             out.update(self.region.stats())
         return out

@@ -191,7 +191,9 @@ class ShmSearchFront:
             raise ShmFallback(plan.reason)
         client.stat_add(shmring.WS_PLAN_SHM)
 
-        t0 = time.perf_counter()
+        # the worker's own two ends of the round trip, on the clock the
+        # owner stamps the response with (shmring._STAMPS)
+        t0 = time.perf_counter_ns()
         t0_w = time.time_ns() if th is not None else 0
         try:
             resp = client.call(
@@ -214,6 +216,7 @@ class ShmSearchFront:
                 shmring.RingTimeout, chaos.FaultError) as e:
             client.stat_add(shmring.WS_PROXY_FALLBACKS)
             raise ShmFallback(type(e).__name__)
+        t_seen = time.perf_counter_ns()
         if resp.status == shmring.ST_OVERLOADED:
             # the owner's admission verdict rides the slot: same 429 +
             # Retry-After the leader would have returned in-process
@@ -228,9 +231,20 @@ class ShmSearchFront:
         if resp.status != shmring.ST_OK:
             client.stat_add(shmring.WS_PROXY_FALLBACKS)
             raise ShmFallback(f"status-{resp.status}")
-        rtt_ms = (time.perf_counter() - t0) * 1000.0
+        rtt_ms = (t_seen - t0) / 1e6
         self.costs.observe_shm(rtt_ms)
         _stages.mark("shm_ring_ms", rtt_ms, span=False)
+        t_claim, t_pickup, t_write = resp.stamps
+        if t_claim:
+            # the round trip at its seams; the four sum to shm_ring_ms
+            # by construction (consecutive differences of one clock)
+            for name, a, b in (
+                ("ring_pickup_ms", t0, t_claim),
+                ("ring_queue_ms", t_claim, t_pickup),
+                ("ring_serve_ms", t_pickup, t_write),
+                ("ring_return_ms", t_write, t_seen),
+            ):
+                _stages.mark(name, (b - a) / 1e6, span=False)
         if th is not None:
             # ONE stitched trace across the process boundary: the ring
             # round trip is a span, and the owner's span-slot
@@ -241,15 +255,13 @@ class ShmSearchFront:
                 attrs={"cls": cls, "worker": client.worker},
             )
             if resp.trace_ns and ring_sid is not None:
-                off_ns = t0_w
-                for idx, ns in enumerate(resp.trace_ns):
-                    if ns <= 0:
-                        continue
-                    _trace.add_span(
-                        th, _trace.OWNER_SLOTS[idx], off_ns,
-                        ns / 1e6, parent=ring_sid,
-                        attrs={"proc": "owner"},
-                    )
+                # the owner's instants on this process's wall clock
+                # (an owner that stamped nothing: all at the enqueue)
+                self._stitch_owner_spans(
+                    th, ring_sid, resp.trace_ns,
+                    t0_w + (t_claim - t0 if t_claim else 0),
+                    t0_w + (t_pickup - t0 if t_claim else 0),
+                )
         client.stat_add(shmring.WS_SERVED)
         if resp.wal_seq:
             # replica catchup: assemble records at least as new as the
@@ -277,6 +289,40 @@ class ShmSearchFront:
         rcache.note_search(cls, epoch or self.fence_view.epoch(),
                            resp.gen, False)
         return resp.ids
+
+    @staticmethod
+    def _stitch_owner_spans(th, ring_sid, trace_ns, claim_w: int,
+                            pickup_w: int) -> None:
+        """The owner's span-slot durations as children of the ring
+        span, in order on one axis: the queue wait from the claim, the
+        serve envelope from the pickup, and under it the serve path's
+        own slots laid end to end from the pickup (a slot holds a
+        duration, not a start: the order is the vocabulary's)."""
+        slots = _trace.OWNER_SLOTS
+        attrs = {"proc": "owner"}
+        ns_of = dict(zip(slots, trace_ns))
+        if ns_of["owner.queue_wait"] > 0:
+            _trace.add_span(
+                th, "owner.queue_wait", claim_w,
+                ns_of["owner.queue_wait"] / 1e6, parent=ring_sid,
+                attrs=attrs,
+            )
+        serve_sid = ring_sid
+        if ns_of["owner.serve"] > 0:
+            serve_sid = _trace.add_span(
+                th, "owner.serve", pickup_w, ns_of["owner.serve"] / 1e6,
+                parent=ring_sid, attrs=attrs,
+            )
+        off_ns = pickup_w
+        for name in slots:
+            ns = ns_of[name]
+            if ns <= 0 or name in ("owner.queue_wait", "owner.serve"):
+                continue
+            _trace.add_span(
+                th, name, off_ns, ns / 1e6, parent=serve_sid,
+                attrs=attrs,
+            )
+            off_ns += ns
 
     def assemble(self, ids: List[str], recs: dict) -> list:
         """Order-preserving record assembly from the worker replica's

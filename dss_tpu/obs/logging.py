@@ -176,6 +176,10 @@ def make_access_log_middleware(metrics=None, dump_requests: bool = False,
                     else "(unmatched)"
                 )
                 metrics.observe_request(request.method, route, status, dur)
+                # the same interval as a stage too: the per-process
+                # request histogram cannot be read across a front whose
+                # scrapes land on whichever worker the kernel picks
+                metrics.observe_stage(route, "handler_ms", dur)
                 for st, ms in stages.items():
                     metrics.observe_stage(route, st, ms / 1000.0)
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import re
 import threading
+from bisect import bisect_left
 from typing import Dict, Tuple
 
 _UUID = re.compile(
@@ -48,15 +49,32 @@ STAGE_BUCKETS = (
 
 # bounded stage-label cardinality: sink keys outside this set collapse
 # to "other" (a service adding a new stage name cannot mint unbounded
-# series; add it here AND — for the shm whole-front blocks — keep
-# parallel/shmring.STAGE_SLOTS in lockstep)
+# series).  Adding a name here is all it takes: the shm whole-front
+# blocks are laid out from this tuple (parallel/shmring._SHIST_WORDS,
+# _STAGE_IDX), and a region made under another layout is refused by
+# its VERSION.
 STAGE_NAMES = (
     "auth_ms", "covering_ms", "store_ms", "serialize_ms", "service_ms",
     "coalesce_wait_ms", "shm_ring_ms", "proxy_ms", "catchup_ms",
     "push_match_ms", "push_deliver_ms",
+    # the ring round trip at its seams (dar/shmfront.py): enqueue ->
+    # claim -> pickup -> response write -> seen; they sum to shm_ring_ms
+    "ring_pickup_ms", "ring_queue_ms", "ring_serve_ms", "ring_return_ms",
+    # both hops of run_in_executor around a service call (api/app._call)
+    "exec_wait_ms",
+    # lateness of the event loop's 10 Hz self-timer, under LOOP_ROUTE
+    "loop_lag_ms",
+    # the whole handler, outermost middleware in to response out: what
+    # dss_request_duration_seconds times per process, here merged
+    # across the front (obs/logging.py access_log)
+    "handler_ms",
     "other",
 )
 _STAGE_SET = frozenset(STAGE_NAMES)
+
+# the fixed route label of a process's own event-loop observations
+# (no request owns them); collapses to the "other" route class
+LOOP_ROUTE = "(loop)"
 
 # bounded route-class cardinality for the fixed-layout shm stage
 # blocks (the per-process /metrics keeps full route templates; the
@@ -128,8 +146,6 @@ class MetricsRegistry:
         self._gauge_vecs: Dict[str, Tuple[str, Dict[str, float]]] = {}
         self._scalar_counters: Dict[str, float] = {}
         self._infos: Dict[str, Dict[str, str]] = {}
-        self._stage_sum: Dict[Tuple[str, str], float] = {}
-        self._stage_cnt: Dict[Tuple[str, str], int] = {}
         # dss_stage_duration_seconds{stage,route}: (route, stage) ->
         # [bucket counts..., sum_s, count]
         self._shist: Dict[Tuple[str, str], list] = {}
@@ -164,22 +180,20 @@ class MetricsRegistry:
 
     def observe_stage(self, route: str, stage: str, duration_s: float) -> None:
         """Per-stage serving-time accounting (parse/auth/covering/
-        store/serialize) so the p50 breakdown is measured, not guessed.
-        Feeds both the legacy dss_request_stage_seconds summary and the
-        dss_stage_duration_seconds{stage,route} histogram — tail
-        percentiles per stage, which a sum/count pair cannot give."""
+        store/serialize) so the p50 breakdown is measured, not guessed:
+        the dss_stage_duration_seconds{stage,route} histogram — tail
+        percentiles per stage, and _sum / _count for the mean."""
         rt = route_template(route)
         with self._lock:
-            k = (rt, stage)
-            self._stage_sum[k] = self._stage_sum.get(k, 0.0) + duration_s
-            self._stage_cnt[k] = self._stage_cnt.get(k, 0) + 1
             hk = (rt, stage_name(stage))
             row = self._shist.get(hk)
             if row is None:
                 row = self._shist[hk] = [0] * (len(STAGE_BUCKETS) + 2)
-            for i, b in enumerate(STAGE_BUCKETS):
-                if duration_s <= b:
-                    row[i] += 1
+            # cumulative buckets: every edge at or past the duration
+            for i in range(
+                bisect_left(STAGE_BUCKETS, duration_s), len(STAGE_BUCKETS)
+            ):
+                row[i] += 1
             row[-2] += duration_s
             row[-1] += 1
         if self._stage_writer is not None:
@@ -355,21 +369,6 @@ class MetricsRegistry:
                     lines.append(
                         f"dss_stage_duration_seconds_count{{{l}}} "
                         f"{scnt}"
-                    )
-            if self._stage_cnt:
-                lines.append("# TYPE dss_request_stage_seconds summary")
-                for k in sorted(self._stage_cnt):
-                    r, st = k
-                    l = lab(
-                        f'route="{_esc_label(r)}",stage="{_esc_label(st)}"'
-                    )
-                    lines.append(
-                        f"dss_request_stage_seconds_sum{{{l}}} "
-                        f"{self._stage_sum[k]:.6f}"
-                    )
-                    lines.append(
-                        f"dss_request_stage_seconds_count{{{l}}} "
-                        f"{self._stage_cnt[k]}"
                     )
             for name, v in sorted(self._scalar_counters.items()):
                 lines.append(f"# TYPE {name} counter")

@@ -55,14 +55,16 @@ def mark(name: str, duration_ms: float, span: bool = True) -> None:
 
 @contextmanager
 def stage(name: str):
-    """Time a block into the current sink (no-op without a sink).
-    Repeated stages accumulate.  When a trace is recording on this
-    thread the block is also a span — service phases (covering/store/
-    serialize) become tree nodes for free, with real nesting (spans
-    opened inside the block parent under it)."""
+    """Time a block into the current sink (without a sink: only an
+    annotation of a running capture).  Repeated stages accumulate.
+    When a trace is recording on this thread the block is also a span
+    — service phases (covering/store/serialize) become tree nodes for
+    free, with real nesting (spans opened inside the block parent
+    under it)."""
     sink = getattr(_tls, "sink", None)
     if sink is None:
-        yield
+        with trace.annotate(name):
+            yield
         return
     sp = trace.span(name)
     t0 = time.perf_counter()

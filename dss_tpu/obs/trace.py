@@ -72,6 +72,8 @@ __all__ = [
     "stats",
     "OWNER_SLOTS",
     "owner_slot_vector",
+    "annotate",
+    "set_capture",
 ]
 
 # The fixed owner-side span vocabulary carried back across the shm
@@ -409,6 +411,7 @@ class TraceRecorder:
                 "dss_trace_ring_depth": len(self._ring),
                 "dss_trace_ring_cap": self.capacity,
                 "dss_trace_allocs_total": self.allocs,
+                "dss_trace_annotations_total": _annotations,
             }
 
 
@@ -563,12 +566,50 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+# -- the third sink: the profiler's own timeline -----------------------------
+#
+# One vocabulary, three sinks: a seam's name lands in the flight
+# recorder (a sampled request), in dss_stage_duration_seconds (always,
+# for the seams that are stages) and — while POST /debug/profile is
+# capturing in THIS process — on the capture's host timeline as a
+# `dss.<name>` TraceAnnotation, on the same clock as the device ops.
+# The capture's gate is its own: it does not need DSS_TRACE_SAMPLE, and
+# it records nothing in the flight recorder.
+
+_CAPTURE = False  # a /debug/profile capture is running in this process
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, resolved at first capture
+_annotations = 0  # TraceAnnotation objects constructed (the off-path
+#                   contract is counter-verified: dss_trace_annotations_total)
+
+
+def set_capture(on: bool) -> None:
+    """Raised and cleared by /debug/profile's capture() (api/app.py)
+    around the profiler session."""
+    global _CAPTURE, _ANNOTATION
+    if on and _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    _CAPTURE = bool(on)
+
+
+def annotate(name: str):
+    """Context manager putting `dss.<name>` on the running capture's
+    timeline; the shared no-op (one global read, no allocation) when no
+    capture runs."""
+    if not _CAPTURE:
+        return _NOOP
+    global _annotations
+    _annotations += 1  # a diagnostic count: a lost update is harmless
+    return _ANNOTATION("dss." + name)
+
+
 class _Span:
     """A live span: context manager measuring its own duration and
     parenting children opened on the same thread while it is open."""
 
     __slots__ = ("name", "span_id", "_parent", "_ctx", "_attrs",
-                 "_t0", "_start_ns", "_prev_parent")
+                 "_t0", "_start_ns", "_prev_parent", "_ann")
 
     def __init__(self, ctx, parent, name, attrs):
         self._ctx = ctx
@@ -578,6 +619,8 @@ class _Span:
         self.span_id = _next_span_id()
 
     def __enter__(self):
+        self._ann = annotate(self.name)
+        self._ann.__enter__()
         self._start_ns = time.time_ns()
         self._t0 = time.perf_counter()
         self._prev_parent = getattr(_tls, "parent", None)
@@ -597,17 +640,19 @@ class _Span:
             self._ctx, self.span_id, self._parent, self.name,
             self._start_ns, dur_ms, self._attrs,
         )
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(name: str, **attrs):
-    """Open a child span of this thread's current span.  A reusable
-    no-op when tracing is inactive here (one branch, no allocation)."""
+    """Open a child span of this thread's current span.  When tracing
+    is inactive here the seam is only an annotation of a running
+    capture, else the reusable no-op (two branches, no allocation)."""
     if not _ENABLED:
-        return _NOOP
+        return annotate(name)
     ctx = getattr(_tls, "ctx", None)
     if ctx is None or not ctx.recording:
-        return _NOOP
+        return annotate(name)
     return _Span(
         ctx, getattr(_tls, "parent", None) or ctx.root_span_id,
         name, attrs or None,

@@ -78,6 +78,25 @@ jax.monitoring.register_event_duration_secs_listener(_COMPILES.on_duration)
 jax.monitoring.register_event_listener(_COMPILES.on_event)
 compile_stats = _COMPILES.stats
 
+
+def device_memory_stats() -> dict:
+    """The allocator's own view of this process's devices at this
+    instant (device.memory_stats()): bytes in use summed over them, and
+    the highest peak any one of them has seen since the process
+    started.  A backend that reports nothing (the CPU) or lacks a key
+    gives no series — never a 0 that would read as "empty"."""
+    found = [d.memory_stats() or {} for d in jax.local_devices()]
+    out = {}
+    in_use = [s["bytes_in_use"] for s in found if "bytes_in_use" in s]
+    peaks = [
+        s["peak_bytes_in_use"] for s in found if "peak_bytes_in_use" in s
+    ]
+    if in_use:
+        out["dss_device_bytes_in_use"] = int(sum(in_use))
+    if peaks:
+        out["dss_device_peak_bytes_in_use"] = int(max(peaks))
+    return out
+
 from dss_tpu.ops.conflict import (  # noqa: F401,E402
     EntityTable,
     Postings,

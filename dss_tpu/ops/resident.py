@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 
 from dss_tpu.chaos import fault_point
+from dss_tpu.obs import trace as _trace
 from dss_tpu.ops import conflict as _conflict  # noqa: F401 — enables
 #   x64 before the first jax array touch (the kernel's i64 columns)
 from dss_tpu.ops import fastpath
@@ -481,10 +482,11 @@ class ResidentLoop:
                 # callback, which absorbs it (host re-run + ladder)
                 fault_point("resident.submit")
                 keys, lo, hi, t0s, t1s, now, owners = payload
-                pq = self._table.query_many_submit(
-                    keys, lo, hi, t0s, t1s, now=now, owner_ids=owners,
-                    kernel=self.kernel,
-                )
+                with _trace.annotate("resident.submit"):
+                    pq = self._table.query_many_submit(
+                        keys, lo, hi, t0s, t1s, now=now,
+                        owner_ids=owners, kernel=self.kernel,
+                    )
             except BaseException as e:  # noqa: BLE001 — deliver, don't die
                 self._inflight_q.put((None, done, t_sub, e))
                 continue
@@ -503,15 +505,16 @@ class ResidentLoop:
             used_device = False
             if err is None:
                 try:
-                    if pq is not None:
-                        pq.wait_device()
-                        # the shared predicate (dar/snapshot.py
-                        # _PendingQuery.used_device) — cost attribution
-                        # here must agree with the coalescer's
-                        # pressure accounting
-                        fn = getattr(pq, "used_device", None)
-                        used_device = bool(fn()) if fn else False
-                    results = self._table.query_many_collect(pq)
+                    with _trace.annotate("resident.collect"):
+                        if pq is not None:
+                            pq.wait_device()
+                            # the shared predicate (dar/snapshot.py
+                            # _PendingQuery.used_device) — cost
+                            # attribution here must agree with the
+                            # coalescer's pressure accounting
+                            fn = getattr(pq, "used_device", None)
+                            used_device = bool(fn()) if fn else False
+                        results = self._table.query_many_collect(pq)
                 except BaseException as e:  # noqa: BLE001
                     err = e
             t_done = time.perf_counter()

@@ -65,12 +65,14 @@ import os
 import struct
 import threading
 import time
+from bisect import bisect_left
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from dss_tpu import chaos
 from dss_tpu.dar.readcache import _env_int
+from dss_tpu.obs import trace as _trace
 from dss_tpu.obs.metrics import (
     ROUTE_CLASSES,
     STAGE_BUCKETS,
@@ -102,8 +104,10 @@ __all__ = [
 SHM_CLASSES = ("isa", "rid_sub", "op", "scd_sub", "constraint")
 
 MAGIC = 0x4453_5353_484D_5231  # "DSSSHMR1"
-VERSION = 2  # v2: trace words in the slot header + the per-process
+VERSION = 3  # v2: trace words in the slot header + the per-process
 #              stage-histogram segment (distributed tracing PR)
+#              v3: three clock stamps in every response + the stage
+#              blocks' new names (the ring split at its seams)
 
 HEADER_BYTES = 4096
 WSTAT_BYTES = 256  # 32 i64 counters per worker
@@ -192,7 +196,16 @@ _TRACE_REQ = struct.Struct("<QQQ")  # tid_hi, tid_lo, flags
 _TRACE_RESP_WORDS = 8  # one i64 duration (ns) per OWNER_SLOTS entry
 _TRACE_RESP = struct.Struct("<" + "q" * _TRACE_RESP_WORDS)
 _TRACE_RESP_OFF = _TRACE_OFF + _TRACE_REQ.size
-_TRACE_BYTES = 96  # 3 + 8 words, padded to 8-word alignment
+# Every response, sampled or not, also carries three clock stamps of
+# the owner (ns): the scan loop's claim, the serve thread's pickup, the
+# response's write.  time.perf_counter_ns() and time.monotonic_ns() are
+# both CLOCK_MONOTONIC on Linux — ONE clock across the processes of a
+# host (tests/test_shmring.py asserts it) — so the worker subtracts
+# its own enqueue and seen instants from them and marks the ring's
+# four stages (dar/shmfront.py): pickup, queue, serve, return.
+_STAMPS = struct.Struct("<qqq")  # claim, pickup, write
+_STAMPS_OFF = _TRACE_RESP_OFF + _TRACE_RESP.size
+_TRACE_BYTES = 128  # 3 + 8 + 3 words, padded to 8-word alignment
 TRACE_F_SAMPLED = 1
 TRACE_F_PRESENT = 2
 
@@ -343,10 +356,10 @@ class ShmResponse:
     """A decoded response slot (worker side)."""
 
     __slots__ = ("status", "ids", "t1s", "wal_seq", "gen",
-                 "retry_after_s", "flags", "trace_ns")
+                 "retry_after_s", "flags", "trace_ns", "stamps")
 
     def __init__(self, status, ids, t1s, wal_seq, gen, retry_after_s,
-                 flags=0, trace_ns=None):
+                 flags=0, trace_ns=None, stamps=(0, 0, 0)):
         self.status = status
         self.ids = ids
         self.t1s = t1s
@@ -359,6 +372,9 @@ class ShmResponse:
         # trace; the worker stitches them into its own trace as child
         # spans of the ring round trip
         self.trace_ns = trace_ns
+        # the owner's (claim, pickup, write) clock stamps, ns on the
+        # host's CLOCK_MONOTONIC; zeros from an owner that gave none
+        self.stamps = stamps
 
     @property
     def mesh_served(self) -> bool:
@@ -696,12 +712,15 @@ class ShmRegion:
                        wal_seq: int = 0, gen: int = 0,
                        retry_after_s: float = 0.0,
                        flags: int = 0,
-                       trace_ns: Optional[Sequence[int]] = None) -> None:
+                       trace_ns: Optional[Sequence[int]] = None,
+                       stamps: Tuple[int, int] = (0, 0)) -> None:
         """Encode the response over the request payload, then publish
         state=RESP.  An answer that cannot fit publishes ST_OVERFLOW
         instead (the worker re-asks over the loopback proxy).
         `trace_ns` carries the owner's span-slot durations (one int64
-        ns per obs/trace.OWNER_SLOTS entry) for sampled requests."""
+        ns per obs/trace.OWNER_SLOTS entry) for sampled requests;
+        `stamps` the owner's (claim, pickup) instants, to which the
+        write's own is added just before the publish."""
         off = self._slot_off(worker, slot)
         mm = self._mm
         if trace_ns is not None:
@@ -730,6 +749,10 @@ class ShmRegion:
             mm[p:p + 8 * n] = t1arr.tobytes()
             p += 8 * n
             mm[p:p + len(id_blob)] = id_blob
+        _STAMPS.pack_into(
+            mm, off + _STAMPS_OFF, int(stamps[0]), int(stamps[1]),
+            time.perf_counter_ns(),
+        )
         self._states[worker * self.depth + slot] = RESP
 
     def read_response(self, worker: int, slot: int) -> ShmResponse:
@@ -752,6 +775,7 @@ class ShmRegion:
         return ShmResponse(
             status, ids, t1s, wal_seq, gen, retry_after_s, flags,
             trace_ns=_TRACE_RESP.unpack_from(mm, off + _TRACE_RESP_OFF),
+            stamps=_STAMPS.unpack_from(mm, off + _STAMPS_OFF),
         )
 
 
@@ -843,9 +867,11 @@ class StageHistWriter:
             + _STAGE_IDX[stage_name(stage)]
         ) * _SHIST_ROW
         row = self._row
-        for i, b in enumerate(STAGE_BUCKETS):
-            if duration_s <= b:
-                row[base + i] += 1
+        # cumulative buckets: every edge at or past the duration, in
+        # one slice increment (this runs for every stage of every
+        # request, on the event loop for an inline read)
+        first = bisect_left(STAGE_BUCKETS, duration_s)
+        row[base + first:base + len(STAGE_BUCKETS)] += 1
         row[base + _SHIST_ROW - 2] += int(duration_s * 1e9)
         row[base + _SHIST_ROW - 1] += 1
 
@@ -993,7 +1019,8 @@ class ShmOwner:
                         self._qcond.notify_all()
                 idle_sleep = 0.0002
             else:
-                time.sleep(idle_sleep)
+                with _trace.annotate("owner.scan_idle"):
+                    time.sleep(idle_sleep)
                 idle_sleep = min(idle_sleep * 2, 0.002)
             # sweep RESP slots of dead workers + heartbeat-based TTL
             now = time.monotonic()
@@ -1025,7 +1052,8 @@ class ShmOwner:
         while True:
             with self._qcond:
                 while not self._queue and not self._stop.is_set():
-                    self._qcond.wait(0.1)
+                    with _trace.annotate("owner.idle"):
+                        self._qcond.wait(0.1)
                 if self._stop.is_set() and not self._queue:
                     return
                 w, s, t_claim = self._queue.pop(0)
@@ -1033,11 +1061,13 @@ class ShmOwner:
             status = ST_ERROR
             try:
                 req = r.read_request(w, s)
-                status = self._serve_one(req, queue_wait_ns=t0 - t_claim)
+                status = self._serve_one(req, stamps=(t_claim, t0))
             except Exception:  # noqa: BLE001 — a bad slot must not kill the pool
                 self._count(OH_ERRORS)
                 try:
-                    r.write_response(w, s, status=ST_ERROR)
+                    r.write_response(
+                        w, s, status=ST_ERROR, stamps=(t_claim, t0)
+                    )
                 except Exception:  # noqa: BLE001
                     r.set_slot_state(w, s, FREE)
             finally:
@@ -1051,16 +1081,18 @@ class ShmOwner:
                         r._ohdr[OH_SERVED] += 1
                     r._ohdr[OH_SERVE_NS] += time.perf_counter_ns() - t0
 
-    def _serve_one(self, req: ShmRequest, queue_wait_ns: int = 0) -> int:
+    def _serve_one(self, req: ShmRequest,
+                   stamps: Tuple[int, int] = (0, 0)) -> int:
+        """`stamps`: the scan loop's claim and this thread's pickup
+        (perf_counter_ns); every response carries them back."""
         from dss_tpu import errors as _errors
         from dss_tpu.dar import deadline as _deadline
-        from dss_tpu.obs import trace as _trace
 
         r = self._region
         if req.deadline_ns and time.monotonic_ns() >= req.deadline_ns:
             self._count(OH_DEADLINE_DROPS)
             r.write_response(
-                req.worker, req.slot, status=ST_DEADLINE,
+                req.worker, req.slot, status=ST_DEADLINE, stamps=stamps,
             )
             return ST_DEADLINE
         route_dl = (
@@ -1079,7 +1111,8 @@ class ShmOwner:
         if req.trace_id and req.trace_sampled:
             tok = _trace.begin_collect(req.trace_id)
         try:
-            out = self._serve_fn(req)
+            with _trace.annotate("owner.serve"):
+                out = self._serve_fn(req)
             # (ids, t1s, gen) or (ids, t1s, gen, flags): the store
             # adds flags (RESP_F_MESH_SERVED); simple serve fns don't
             ids, t1s, gen = out[0], out[1], out[2]
@@ -1088,7 +1121,7 @@ class ShmOwner:
             self._count(OH_OVERLOADED)
             r.write_response(
                 req.worker, req.slot, status=ST_OVERLOADED,
-                retry_after_s=e.retry_after_s,
+                retry_after_s=e.retry_after_s, stamps=stamps,
             )
             return ST_OVERLOADED
         except _errors.StatusError as e:
@@ -1097,7 +1130,9 @@ class ShmOwner:
                 if e.code == _errors.Code.DEADLINE_EXCEEDED
                 else ST_ERROR
             )
-            r.write_response(req.worker, req.slot, status=status)
+            r.write_response(
+                req.worker, req.slot, status=status, stamps=stamps
+            )
             return status
         finally:
             if route_dl is not None:
@@ -1106,7 +1141,7 @@ class ShmOwner:
                 trace_vec = _trace.owner_slot_vector(
                     _trace.end_collect(tok),
                     extra={
-                        "owner.queue_wait": queue_wait_ns / 1e6,
+                        "owner.queue_wait": (stamps[1] - stamps[0]) / 1e6,
                         "owner.serve": (
                             (time.perf_counter_ns() - t_serve0) / 1e6
                         ),
@@ -1115,7 +1150,7 @@ class ShmOwner:
         r.write_response(
             req.worker, req.slot, status=ST_OK, ids=ids, t1s=t1s,
             wal_seq=self._wal_seq_fn(), gen=gen, flags=flags,
-            trace_ns=trace_vec,
+            trace_ns=trace_vec, stamps=stamps,
         )
         return ST_OK
 

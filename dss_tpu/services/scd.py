@@ -262,16 +262,18 @@ class SCDService:
         # allow_stale: public search may ride the mesh replica for
         # oversized batches (the conflict-response listing at :117 must
         # NOT — it feeds the OVN key the client will retry with)
-        ops = self.store.search_operations(
-            cells, sv.altitude_lo, sv.altitude_hi, vol4.start_time,
-            vol4.end_time, allow_stale=True,
-        )
-        out = []
-        for op in ops:
-            if op.owner != owner:
-                op.ovn = ""
-            out.append(ser.op_to_json(op))
-        return {"operation_references": out}
+        with stages.stage("store_ms"):
+            ops = self.store.search_operations(
+                cells, sv.altitude_lo, sv.altitude_hi, vol4.start_time,
+                vol4.end_time, allow_stale=True,
+            )
+        with stages.stage("serialize_ms"):
+            out = []
+            for op in ops:
+                if op.owner != owner:
+                    op.ovn = ""
+                out.append(ser.op_to_json(op))
+            return {"operation_references": out}
 
     # -- Subscriptions -------------------------------------------------------
 

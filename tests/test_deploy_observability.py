@@ -34,7 +34,6 @@ def _exported_metric_names() -> set:
     names = {
         "dss_requests_total",
         "dss_request_duration_seconds",
-        "dss_request_stage_seconds",
         "dss_stage_duration_seconds",
         "dss_build_info",
     }

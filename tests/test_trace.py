@@ -225,6 +225,50 @@ def test_disabled_path_zero_recorder_allocations():
     assert st["dss_trace_started_total"] == 0
 
 
+def test_annotate_is_the_shared_noop_without_a_capture():
+    """The third sink's off-path contract, counter-verified as the
+    recorder's is: with no /debug/profile capture running every
+    annotated seam gets the ONE shared no-op object and no
+    TraceAnnotation is constructed."""
+    from dss_tpu.obs import stages
+
+    before = trace.stats()["dss_trace_annotations_total"]
+    a = trace.annotate("owner.serve")
+    assert a is trace.annotate("collect")
+    assert a is trace.span("service")  # tracing off: span is annotate
+    with a:
+        pass
+    with stages.stage("covering_ms"):  # no sink on this thread
+        pass
+    st = trace.stats()
+    assert st["dss_trace_annotations_total"] == before
+    assert st["dss_trace_allocs_total"] == 0
+
+
+def test_annotate_under_a_capture_needs_no_sampling():
+    """The capture's gate is its own: with DSS_TRACE_SAMPLE 0 (every
+    benchmark run) and a capture on, span and stage still annotate —
+    and record nothing in the flight recorder."""
+    from dss_tpu.obs import stages
+
+    before = trace.stats()["dss_trace_annotations_total"]
+    trace.set_capture(True)
+    try:
+        ann = trace.annotate("owner.serve")
+        assert type(ann).__name__ == "TraceAnnotation"
+        with ann:
+            pass
+        with trace.span("service"), stages.stage("covering_ms"):
+            pass
+    finally:
+        trace.set_capture(False)
+    st = trace.stats()
+    assert st["dss_trace_annotations_total"] == before + 3
+    assert st["dss_trace_allocs_total"] == 0
+    assert st["dss_trace_started_total"] == 0
+    assert trace.annotate("x") is trace.annotate("y")  # off again
+
+
 # -- cross-thread handoff through a real coalescer ---------------------------
 
 
@@ -294,6 +338,39 @@ def test_untraced_coalescer_query_stays_unrecorded():
         assert out == ["r0"]
     finally:
         co.close()
+    assert trace.stats()["dss_trace_allocs_total"] == 0
+
+
+def test_inline_execution_counts_its_own_split_and_device_use():
+    """An inline execution (the lone caller's own thread) feeds
+    co_inline, co_inline_device (the device stub says it launched) and
+    both co_inline_*_ms_total — always, not only when traced — and
+    leaves the pipeline's co_pack_ms_total / co_batches alone, because
+    pack_collect_ms_per_batch reads those."""
+    from dss_tpu.dar.coalesce import QueryCoalescer
+
+    class _SlowTable(_FakeTable):
+        def query_many_submit(self, *a, **kw):
+            time.sleep(0.002)
+            return super().query_many_submit(*a, **kw)
+
+        def query_many_collect(self, pq):
+            time.sleep(0.003)
+            return pq.results
+
+    co = QueryCoalescer(_SlowTable(), inline=True)
+    try:
+        for _ in range(3):
+            assert co.query(np.asarray([5], np.int32), now=123) == ["r0"]
+        st = co.stats()
+    finally:
+        co.close()
+    assert st["co_inline"] == 3
+    assert st["co_inline_device"] == 3
+    assert st["co_inline_submit_ms_total"] >= 3 * 2.0
+    assert st["co_inline_collect_ms_total"] >= 3 * 3.0
+    assert st["co_batches"] == 0
+    assert st["co_pack_ms_total"] == 0 and st["co_collect_ms_total"] == 0
     assert trace.stats()["dss_trace_allocs_total"] == 0
 
 
@@ -457,10 +534,33 @@ def test_stitched_trace_across_two_processes(tmp_path):
                 break
             stack.extend(n["children"])
         assert ring is not None, tree
+        # the queue wait and the serve envelope under the ring span,
+        # the serve path's own slots under the envelope
         owner_spans = {c["name"]: c for c in ring["children"]}
-        for needed in ("owner.queue_wait", "owner.serve", "admission",
-                       "plan", "device.dispatch", "collect"):
+        assert sorted(owner_spans) == ["owner.queue_wait", "owner.serve"]
+        inner = owner_spans["owner.serve"]["children"]
+        owner_spans.update({c["name"]: c for c in inner})
+        for needed in ("admission", "plan", "device.dispatch", "collect"):
             assert needed in owner_spans, (needed, sorted(owner_spans))
+        # on one axis, in order and non-overlapping: enqueue <= claim
+        # (queue wait) <= pickup (serve), and inside the serve envelope
+        # the slots end to end from the pickup stamp — none at the ring
+        # span's own start any more
+        def end(c):
+            return c["start_ns"] + int(c["duration_ms"] * 1e6)
+
+        qw, sv = owner_spans["owner.queue_wait"], owner_spans["owner.serve"]
+        slack = 2_000  # duration_ms is rounded to the microsecond
+        assert ring["start_ns"] <= qw["start_ns"]
+        assert end(qw) <= sv["start_ns"] + slack
+        assert end(sv) <= end(ring) + slack
+        assert [c["name"] for c in inner] == [
+            n for n in trace.OWNER_SLOTS if n in {c["name"] for c in inner}
+        ]
+        assert inner[0]["start_ns"] == sv["start_ns"]
+        for a, b in zip(inner, inner[1:]):
+            assert end(a) <= b["start_ns"] + slack, (a, b)
+        assert end(inner[-1]) <= end(sv) + slack
         # the injected 3ms dispatch sleep dominates the owner slots
         assert owner_spans["device.dispatch"]["duration_ms"] >= 2.5
         assert (
@@ -482,6 +582,76 @@ def test_stitched_trace_across_two_processes(tmp_path):
             child.wait(timeout=10)
         except subprocess.TimeoutExpired:
             child.kill()
+        region.close()
+
+
+# -- the ring split at its seams ---------------------------------------------
+
+
+def test_ring_stamps_share_one_clock_across_processes():
+    """The ring's four stages subtract instants taken in two processes:
+    sound only because perf_counter_ns and monotonic_ns are both the
+    host's CLOCK_MONOTONIC (Linux), one clock for every process."""
+    for name in ("perf_counter", "monotonic"):
+        info = time.get_clock_info(name)
+        assert info.implementation == "clock_gettime(CLOCK_MONOTONIC)"
+        assert info.monotonic
+    before = time.perf_counter_ns()
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import time; print(time.perf_counter_ns(), time.monotonic_ns())"],
+        capture_output=True, text=True, check=True, timeout=60,
+    ).stdout.split()
+    after = time.monotonic_ns()
+    assert before <= int(out[0]) <= int(out[1]) <= after
+
+
+def test_ring_stages_sum_to_the_round_trip(tmp_path):
+    """Every response — sampled or not — carries the owner's claim,
+    pickup and write stamps; the worker marks four stages from them
+    that are >= 0 and sum to shm_ring_ms (consecutive differences of
+    one clock; the sink rounds each to the microsecond)."""
+    from dss_tpu.dar.shmfront import ShmSearchFront
+    from dss_tpu.obs import stages
+    from dss_tpu.parallel import shmring
+
+    region = shmring.ShmRegion.create(
+        str(tmp_path / "ring.shm"), nworkers=1, depth=8
+    )
+
+    def serve(req):
+        time.sleep(0.002)
+        return ["an-id"], [1 << 60], 7
+
+    owner = shmring.ShmOwner(region, serve, wal_seq_fn=lambda: 0)
+    owner.start()
+    client = shmring.ShmWorkerClient(region, 0)
+    try:
+        front = ShmSearchFront(region, client, _NoFollower(), _FakeClock())
+        names = ("ring_pickup_ms", "ring_queue_ms", "ring_serve_ms",
+                 "ring_return_ms")
+        for k in range(5):
+            sink = {}
+            stages.set_sink(sink)
+            try:
+                ids = front.serve(
+                    "isa", np.asarray([1000 + k], np.uint64),
+                    qkey=(None,), now_ns=1, t0_ns=1, allow_stale=False,
+                )
+            finally:
+                stages.set_sink(None)
+            assert ids == ["an-id"]
+            assert set(names) < set(sink), sink
+            assert all(sink[n] >= 0.0 for n in names), sink
+            assert sink["ring_serve_ms"] >= 2.0
+            assert sum(sink[n] for n in names) == pytest.approx(
+                sink["shm_ring_ms"], abs=0.003
+            )
+        # unsampled: no trace was started, nothing was allocated
+        assert trace.stats()["dss_trace_allocs_total"] == 0
+    finally:
+        client.close()
+        owner.close()
         region.close()
 
 
