@@ -51,13 +51,42 @@ MAX_RID_SUBSCRIPTIONS_PER_AREA = 10  # DSS0030
 MAX_SCD_SUBSCRIPTIONS_PER_AREA = 10
 
 
+# a record with no end time never expires: int64 max keeps every
+# t_end >= now refilter (the read cache's, a worker cache's) a no-op
+_NEVER_NS = int(np.iinfo(np.int64).max)
+
+
+def _t_ends(ids, recs: dict) -> Tuple[List[str], List[int]]:
+    """-> (the ids that `recs` still holds, each one's end time in ns):
+    one lookup and one conversion per id.  An id whose record vanished
+    between the index query and this pass is left out — a concurrent
+    remove's clock bump fences a cache entry without it out, and the
+    fresh path would leave it out right now."""
+    out_ids: List[str] = []
+    t1s: List[int] = []
+    for i in ids:
+        rec = recs.get(i)
+        if rec is None:
+            continue
+        end = rec.end_time
+        out_ids.append(i)
+        t1s.append(_NEVER_NS if end is None else to_nanos(end))
+    return out_ids, t1s
+
+
 def _copy_rec(rec):
     """Shallow defensive copy for search-result assembly: callers may
     mutate the returned object (e.g. the SCD service blanks `ovn` for
     non-owners) without touching the shared stored record.  Equivalent
     to `dataclasses.replace(rec)` for these pure-data records but
     ~1.5x cheaper — per-record assembly is the read path's largest
-    single cost at poll-heavy hit rates."""
+    single cost at poll-heavy hit rates.
+
+    Only the RECORD-level depth of a search copies (`_assemble`: the
+    callers that hand records to a service or a precheck).  The
+    id-level depth (`_id_answer`: what DSSStore.shm_serve puts in a
+    ring slot) never does — a slot carries ids and end times, and the
+    worker that asked assembles the records from its own replica."""
     return copy.copy(rec)
 
 
@@ -182,7 +211,13 @@ class _CachedSearchMixin:
     Retry-After backlog contribution, no device.  Misses populate on
     the way out (the coalescer's collect path has already resolved by
     then) unless the answer came from the bounded-stale mesh replica,
-    which must never be stamped as fresh."""
+    which must never be stamped as fresh.
+
+    One search, two depths.  Each class's `_<cls>_ids` defines the
+    search once and returns what `_cached_ids` found; the record level
+    (`_assemble`) copies the records out for a service or a precheck,
+    the id level (`_id_answer`) pairs each id with its end time for a
+    shared-memory ring slot.  Neither depth knows who calls it."""
 
     _cache: Optional[rcache.ReadCache] = None
     _epoch_fn = staticmethod(lambda: "")
@@ -222,9 +257,14 @@ class _CachedSearchMixin:
         now_ns: int,  # the query's `now` (its only time-variant input)
         allow_stale: bool,
         run,  # () -> List[str], the fresh path (index.query_ids)
-        t_end_of,  # id -> t_end ns (from the record dict) or None
+        recs: dict,  # the class's records by id (end times come from it)
         owner_id: Optional[int] = None,
-    ) -> List[str]:
+    ) -> Tuple[List[str], Optional[List[int]]]:
+        """-> (ids, t_end ns per id).  The end times are those the
+        miss path read to populate the cache (ids whose record had
+        already vanished are then left out of both lists), or None
+        where no such pass ran — a fenced hit, a mesh answer, a cache
+        that is off — so a record-level hit never pays for one."""
         cache = self._cache
         clock_fence = getattr(index, "clock_fence", None)
         if (
@@ -239,7 +279,7 @@ class _CachedSearchMixin:
             rcache.take_mesh_served()
             ids = run()
             rcache.note_last_search_meshed(rcache.take_mesh_served())
-            return ids
+            return ids, None
         th = trace.current()
         t_cl_w = t_cl0 = 0
         if th is not None:
@@ -259,36 +299,55 @@ class _CachedSearchMixin:
         if ids is not None:
             rcache.note_search(cls, epoch, fence[2], True)
             rcache.note_last_search_meshed(False)
-            return ids
+            return ids, None
         rcache.take_mesh_served()  # clear any stale flag before running
         ids = run()
         meshed = rcache.take_mesh_served()
         rcache.note_last_search_meshed(meshed)
+        t1s: Optional[List[int]] = None
         if not meshed:
-            pairs_ids: List[str] = []
-            t1s: List[int] = []
-            for i in ids:
-                t1 = t_end_of(i)
-                if t1 is None:
-                    # record vanished between query and assembly: the
-                    # concurrent remove's clock bump will fence this
-                    # entry out; omitting the id matches what the
-                    # fresh path would return right now
-                    continue
-                pairs_ids.append(i)
-                t1s.append(t1)
+            ids, t1s = _t_ends(ids, recs)
             try:
                 # chaos seam: population is best-effort by contract —
                 # an injected failure here leaves the next poll a
                 # miss, never a wrong answer
                 chaos.fault_point("cache.populate", detail=cls)
                 cache.insert(
-                    cls, key, fence, epoch, int(now_ns), pairs_ids, t1s
+                    cls, key, fence, epoch, int(now_ns), ids, t1s
                 )
             except chaos.FaultError:
                 pass
         rcache.note_search(cls, epoch, fence[2], False)
-        return ids
+        return ids, t1s
+
+    @staticmethod
+    def _id_answer(
+        found, recs: dict, *, by_id: bool
+    ) -> Tuple[List[str], List[int]]:
+        """The id-level depth: what `_cached_ids` found as a ring slot
+        carries it, (ids, t_end ns per id).  Each id's end time is read
+        once: by the miss path's cache-populating pass where it ran,
+        else here.  `by_id` re-sorts the answer by id (the SCD classes
+        answer in id order, the RID classes in the index's)."""
+        ids, t1s = found
+        if t1s is None:
+            return _t_ends(sorted(ids) if by_id else ids, recs)
+        if by_id and ids:
+            ids, t1s = map(list, zip(*sorted(zip(ids, t1s))))
+        return ids, t1s
+
+    @staticmethod
+    def _assemble(ids, recs: dict) -> list:
+        """The record-level depth: a defensive copy of each found id's
+        record, in the order given.  .get(): a concurrent delete
+        between the index query and this assembly must skip, not
+        KeyError (reads are lock-free)."""
+        out = []
+        for i in ids:
+            rec = recs.get(i)
+            if rec is not None:
+                out.append(_copy_rec(rec))
+        return out
 
 
 class TimestampOracle:
@@ -461,7 +520,7 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
             self._journal(rec)
             return dataclasses.replace(old)
 
-    def search_isas(self, cells, earliest, latest, *, allow_stale=False):
+    def _isa_ids(self, cells, earliest, latest, *, allow_stale=False):
         # lock-free read against the index's published snapshot;
         # allow_stale additionally permits a fresh mesh-replica answer
         # for oversized coalesced batches (service SEARCH paths only —
@@ -480,29 +539,27 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
         # re-applies at now_ns on every hit.  Keying it would stamp
         # the wall clock into the key and make every repeat poll a
         # unique, never-hit line; only `latest` shapes the entry.
-        ids = self._cached_ids(
+        return self._cached_ids(
             "isa", self._isa_index, cells,
             qkey=(l_ns,), now_ns=e_ns, allow_stale=allow_stale,
             run=lambda: self._isa_index.query_ids(
                 cells, t_start=e_ns, t_end=l_ns, now=e_ns,
                 allow_stale=allow_stale,
             ),
-            t_end_of=self._isa_t_end,
+            recs=self._isas,
         )
-        out = []
-        for i in ids:
-            isa = self._isas.get(i)
-            if isa is not None:
-                out.append(_copy_rec(isa))
-        return out
 
-    def _isa_t_end(self, i) -> Optional[int]:
-        isa = self._isas.get(i)
-        return None if isa is None else to_nanos(isa.end_time)
+    def search_isa_ids(self, cells, earliest, latest, *, allow_stale=False):
+        return self._id_answer(
+            self._isa_ids(cells, earliest, latest, allow_stale=allow_stale),
+            self._isas, by_id=False,
+        )
 
-    def _rid_sub_t_end(self, i) -> Optional[int]:
-        sub = self._subs.get(i)
-        return None if sub is None else to_nanos(sub.end_time)
+    def search_isas(self, cells, earliest, latest, *, allow_stale=False):
+        ids, _ = self._isa_ids(
+            cells, earliest, latest, allow_stale=allow_stale
+        )
+        return self._assemble(ids, self._isas)
 
     # -- Subscriptions -------------------------------------------------------
 
@@ -567,45 +624,36 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
             self._journal(rec)
             return dataclasses.replace(old)
 
-    def search_subscriptions(self, cells):
+    def _rid_sub_ids(self, cells, owner=None):
+        """Live subscriptions intersecting cells; of `owner` alone
+        where one is given (the owner scope is part of the cache key)."""
         if len(np.asarray(cells).ravel()) == 0:
             raise errors.bad_request("no location provided")
         cells = canonical_cells(cells)
         now = self._now_ns()
-        ids = self._cached_ids(
-            "rid_sub", self._sub_index, cells,
-            qkey=(), now_ns=now, allow_stale=False,
-            run=lambda: self._sub_index.query_ids(cells, now=now),
-            t_end_of=self._rid_sub_t_end,
-        )
-        out = []
-        for i in ids:
-            sub = self._subs.get(i)
-            if sub is not None:
-                out.append(_copy_rec(sub))
-        return out
-
-    def search_subscriptions_by_owner(self, cells, owner):
-        if len(np.asarray(cells).ravel()) == 0:
-            raise errors.bad_request("no location provided")
-        cells = canonical_cells(cells)
-        now = self._now_ns()
-        oid = self._owners.intern(owner)
-        ids = self._cached_ids(
+        oid = None if owner is None else self._owners.intern(owner)
+        return self._cached_ids(
             "rid_sub", self._sub_index, cells,
             qkey=(), now_ns=now, allow_stale=False,
             run=lambda: self._sub_index.query_ids(
                 cells, now=now, owner_id=oid
             ),
-            t_end_of=self._rid_sub_t_end,
+            recs=self._subs,
             owner_id=oid,
         )
-        out = []
-        for i in ids:
-            sub = self._subs.get(i)
-            if sub is not None:
-                out.append(_copy_rec(sub))
-        return out
+
+    def search_subscription_ids(self, cells, owner=None):
+        return self._id_answer(
+            self._rid_sub_ids(cells, owner), self._subs, by_id=False
+        )
+
+    def search_subscriptions(self, cells):
+        ids, _ = self._rid_sub_ids(cells)
+        return self._assemble(ids, self._subs)
+
+    def search_subscriptions_by_owner(self, cells, owner):
+        ids, _ = self._rid_sub_ids(cells, owner)
+        return self._assemble(ids, self._subs)
 
     def max_subscription_count_in_cells_by_owner(self, cells, owner):
         return self._sub_index.max_owner_count(
@@ -808,40 +856,25 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
             self._owners.intern(cst.owner),
         )
 
-    def _op_t_end(self, i) -> Optional[int]:
-        op = self._ops.get(i)
-        return None if op is None else to_nanos(op.end_time)
-
-    def _scd_sub_t_end(self, i) -> Optional[int]:
-        sub = self._subs.get(i)
-        return None if sub is None else to_nanos(sub.end_time)
-
-    def _cst_t_end(self, i) -> Optional[int]:
-        cst = self._csts.get(i)
-        return None if cst is None else to_nanos(cst.end_time)
-
-    def _search_ops(
-        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    def _volume_ids(
+        self, cls, index, recs, cells, alt_lo, alt_hi, earliest, latest,
+        allow_stale,
     ):
-        # ONE cached integration point for every operation search:
-        # public SEARCH, OVN-conflict prechecks, dependent-operation
-        # resolution.  A fenced hit is bit-identical to the fresh path
-        # (the precheck runs under the pinned txn timestamp, which is
-        # exactly the `now` the cache re-filters at), so serving
-        # write-safety checks from it is sound.
+        """The 4D-volume search the `op` and `constraint` classes
+        share, defined once for both depths."""
         cells = canonical_cells(cells)
         t0_ns = None if earliest is None else to_nanos(earliest)
         t1_ns = None if latest is None else to_nanos(latest)
         now = self._now_ns()
-        ids = self._cached_ids(
-            "op", self._op_index, cells,
+        return self._cached_ids(
+            cls, index, cells,
             qkey=(
                 None if alt_lo is None else float(alt_lo),
                 None if alt_hi is None else float(alt_hi),
                 t0_ns, t1_ns,
             ),
             now_ns=now, allow_stale=allow_stale,
-            run=lambda: self._op_index.query_ids(
+            run=lambda: index.query_ids(
                 cells,
                 alt_lo=alt_lo,
                 alt_hi=alt_hi,
@@ -850,16 +883,43 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
                 now=now,
                 allow_stale=allow_stale,
             ),
-            t_end_of=self._op_t_end,
+            recs=recs,
         )
-        # .get(): a concurrent delete between the index query and this
-        # assembly must skip, not KeyError (reads are lock-free)
-        out = []
-        for i in sorted(ids):
-            op = self._ops.get(i)
-            if op is not None:
-                out.append(_copy_rec(op))
-        return out
+
+    def _op_ids(
+        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    ):
+        # ONE cached integration point for every operation search:
+        # public SEARCH, OVN-conflict prechecks, dependent-operation
+        # resolution, the shm ring.  A fenced hit is bit-identical to
+        # the fresh path (the precheck runs under the pinned txn
+        # timestamp, which is exactly the `now` the cache re-filters
+        # at), so serving write-safety checks from it is sound.
+        return self._volume_ids(
+            "op", self._op_index, self._ops,
+            cells, alt_lo, alt_hi, earliest, latest, allow_stale,
+        )
+
+    def _search_ops(
+        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    ):
+        ids, _ = self._op_ids(
+            cells, alt_lo, alt_hi, earliest, latest, allow_stale=allow_stale
+        )
+        return self._assemble(sorted(ids), self._ops)
+
+    def search_operation_ids(
+        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    ):
+        if len(np.asarray(cells).ravel()) == 0:
+            raise errors.bad_request("missing cell IDs for query")
+        return self._id_answer(
+            self._op_ids(
+                cells, alt_lo, alt_hi, earliest, latest,
+                allow_stale=allow_stale,
+            ),
+            self._ops, by_id=True,
+        )
 
     def search_operations(
         self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
@@ -870,43 +930,39 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
             cells, alt_lo, alt_hi, earliest, latest, allow_stale=allow_stale
         )
 
-    def _search_csts(
+    def _cst_ids(
         self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
     ):
         """ONE cached integration point for every constraint search
-        (public QUERY + the constraint-aware OVN precheck), the mirror
-        of _search_ops: fenced hits are bit-identical to the fresh
-        path, so serving write-safety checks from the cache is sound
-        for the fifth class exactly as for the other four."""
-        cells = canonical_cells(cells)
-        t0_ns = None if earliest is None else to_nanos(earliest)
-        t1_ns = None if latest is None else to_nanos(latest)
-        now = self._now_ns()
-        ids = self._cached_ids(
-            "constraint", self._cst_index, cells,
-            qkey=(
-                None if alt_lo is None else float(alt_lo),
-                None if alt_hi is None else float(alt_hi),
-                t0_ns, t1_ns,
-            ),
-            now_ns=now, allow_stale=allow_stale,
-            run=lambda: self._cst_index.query_ids(
-                cells,
-                alt_lo=alt_lo,
-                alt_hi=alt_hi,
-                t_start=t0_ns,
-                t_end=t1_ns,
-                now=now,
+        (public QUERY + the constraint-aware OVN precheck + the shm
+        ring), the mirror of _op_ids: fenced hits are bit-identical to
+        the fresh path, so serving write-safety checks from the cache
+        is sound for the fifth class exactly as for the other four."""
+        return self._volume_ids(
+            "constraint", self._cst_index, self._csts,
+            cells, alt_lo, alt_hi, earliest, latest, allow_stale,
+        )
+
+    def _search_csts(
+        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    ):
+        ids, _ = self._cst_ids(
+            cells, alt_lo, alt_hi, earliest, latest, allow_stale=allow_stale
+        )
+        return self._assemble(sorted(ids), self._csts)
+
+    def search_constraint_ids(
+        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    ):
+        if len(np.asarray(cells).ravel()) == 0:
+            raise errors.bad_request("missing cell IDs for query")
+        return self._id_answer(
+            self._cst_ids(
+                cells, alt_lo, alt_hi, earliest, latest,
                 allow_stale=allow_stale,
             ),
-            t_end_of=self._cst_t_end,
+            self._csts, by_id=True,
         )
-        out = []
-        for i in sorted(ids):
-            cst = self._csts.get(i)
-            if cst is not None:
-                out.append(_copy_rec(cst))
-        return out
 
     def search_constraints(
         self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
@@ -1295,8 +1351,9 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
             self._journal(rec)
             return dataclasses.replace(old)
 
-    def search_subscriptions(self, cells, owner):
-        """Live subscriptions of `owner` intersecting cells.
+    def _scd_sub_ids(self, cells, oid):
+        """Live subscriptions of the interned owner `oid` intersecting
+        cells.
 
         The reference's SQL uses a LEFT JOIN (subscriptions.go:500-521)
         which in effect ignores the cell filter; we implement the
@@ -1306,26 +1363,32 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
             raise errors.bad_request("no location provided")
         cells = canonical_cells(cells)
         now = self._now_ns()
-        oid = self._owners.intern(owner)
-        ids = self._cached_ids(
+        return self._cached_ids(
             "scd_sub", self._sub_index, cells,
             qkey=(), now_ns=now, allow_stale=False,
             run=lambda: self._sub_index.query_ids(
                 cells, now=now, owner_id=oid
             ),
-            t_end_of=self._scd_sub_t_end,
+            recs=self._subs,
             owner_id=oid,
         )
-        out = []
-        for i in sorted(ids):
-            sub = self._subs.get(i)
-            if sub is None:
-                continue
-            s = _copy_rec(sub)
+
+    def search_subscription_ids(self, cells, owner):
+        # id level: the worker that asked resolves each sub's
+        # dependent operations itself (through its own cached op
+        # path), so a ring slot never carries nested lists
+        oid = None if owner is None else self._owners.intern(owner)
+        return self._id_answer(
+            self._scd_sub_ids(cells, oid), self._subs, by_id=True
+        )
+
+    def search_subscriptions(self, cells, owner):
+        ids, _ = self._scd_sub_ids(cells, self._owners.intern(owner))
+        out = self._assemble(sorted(ids), self._subs)
+        for s in out:
             # dependent ops resolve fresh each time (and their inner
             # _search_ops calls ride the cache themselves)
-            s.dependent_operations = self._dependent_ops(sub)
-            out.append(s)
+            s.dependent_operations = self._dependent_ops(s)
         return out
 
     # -- WAL replay ----------------------------------------------------------
@@ -1618,13 +1681,17 @@ class DSSStore:
 
     def shm_serve(self, req) -> Tuple[List[str], List[int], int, int]:
         """Serve one shared-memory ring request (shmring.ShmRequest)
-        through the SAME search paths HTTP requests take — admission,
+        through the SAME searches HTTP requests take — admission,
         deadline routing, the planner, and the owner's read cache all
-        apply — returning (ids, t_end ns per id, class generation,
-        response flags).  The flags carry RESP_F_MESH_SERVED when the
-        answer came from the bounded-stale mesh replica: the leader
-        refuses to populate its own cache from such answers
-        (_cached_ids), and the requesting worker must refuse too.
+        apply — at their id-level depth, returning (ids, t_end ns per
+        id, class generation, response flags).  A slot carries ids and
+        end times, never records, and the worker that asked assembles
+        the records from its own replica: so the owner copies no
+        record and reads each id's end time once.  The flags carry
+        RESP_F_MESH_SERVED when the answer came from the bounded-stale
+        mesh replica: the leader refuses to populate its own cache
+        from such answers (_cached_ids), and the requesting worker
+        must refuse too.
 
         Visibility is pinned to the WORKER's `now`: the request's
         clock instant rides the txn-time thread-local, so the answer
@@ -1638,82 +1705,38 @@ class DSSStore:
         cls = req.cls
         cells = canonical_cells(req.cells)
         sub = self.rid if cls in ("isa", "rid_sub") else self.scd
+        t0 = None if req.t0_ns is None else from_nanos(req.t0_ns)
+        t1 = None if req.t1_ns is None else from_nanos(req.t1_ns)
         tl = sub._txn_time
         pinned = getattr(tl, "now", None) is None
         if pinned:
             tl.now = int(req.now_ns)
         try:
             if cls == "isa":
-                recs = sub.search_isas(
-                    cells, from_nanos(req.t0_ns),
-                    None if req.t1_ns is None else from_nanos(req.t1_ns),
-                    allow_stale=req.allow_stale,
+                ids, t1s = sub.search_isa_ids(
+                    cells, t0, t1, allow_stale=req.allow_stale
                 )
-            elif cls == "rid_sub":
-                recs = (
-                    sub.search_subscriptions_by_owner(cells, req.owner)
-                    if req.owner
-                    else sub.search_subscriptions(cells)
+            elif cls in ("rid_sub", "scd_sub"):
+                ids, t1s = sub.search_subscription_ids(
+                    cells, req.owner or None
                 )
             elif cls == "op":
-                recs = sub.search_operations(
-                    cells, req.alt_lo, req.alt_hi,
-                    None if req.t0_ns is None else from_nanos(req.t0_ns),
-                    None if req.t1_ns is None else from_nanos(req.t1_ns),
+                ids, t1s = sub.search_operation_ids(
+                    cells, req.alt_lo, req.alt_hi, t0, t1,
                     allow_stale=req.allow_stale,
                 )
             elif cls == "constraint":
-                recs = sub.search_constraints(
-                    cells, req.alt_lo, req.alt_hi,
-                    None if req.t0_ns is None else from_nanos(req.t0_ns),
-                    None if req.t1_ns is None else from_nanos(req.t1_ns),
+                ids, t1s = sub.search_constraint_ids(
+                    cells, req.alt_lo, req.alt_hi, t0, t1,
                     allow_stale=req.allow_stale,
                 )
-            elif cls == "scd_sub":
-                # id-level serve: the worker resolves each sub's
-                # dependent operations itself (through its own cached
-                # op path), so the slot never carries nested lists
-                now = int(req.now_ns)
-                oid = (
-                    sub._owners.intern(req.owner)
-                    if req.owner else None
-                )
-                ids = sub._cached_ids(
-                    "scd_sub", sub._sub_index, cells,
-                    qkey=(), now_ns=now, allow_stale=False,
-                    run=lambda: sub._sub_index.query_ids(
-                        cells, now=now, owner_id=oid
-                    ),
-                    t_end_of=sub._scd_sub_t_end,
-                    owner_id=oid,
-                )
-                out_ids, t1s = [], []
-                for i in sorted(ids):
-                    t1 = sub._scd_sub_t_end(i)
-                    if t1 is None:
-                        continue
-                    out_ids.append(i)
-                    t1s.append(t1)
-                gen = sub._sub_index.cell_clock.generation
-                return out_ids, t1s, gen, self._shm_resp_flags()
             else:
                 raise errors.bad_request(f"unknown shm class {cls!r}")
         finally:
             if pinned:
                 tl.now = None
         gen = self._class_index(cls).cell_clock.generation
-        _never = np.iinfo(np.int64).max
-        return (
-            [r.id for r in recs],
-            # a record with no end time never expires: int64 max keeps
-            # the worker cache's t_end-refilter a no-op for it
-            [
-                _never if r.end_time is None else to_nanos(r.end_time)
-                for r in recs
-            ],
-            gen,
-            self._shm_resp_flags(),
-        )
+        return ids, t1s, gen, self._shm_resp_flags()
 
     @staticmethod
     def _shm_resp_flags() -> int:

@@ -182,6 +182,7 @@ OH_OVERLOADED = 3
 OH_RECLAIMED = 4
 OH_SERVE_NS = 5
 OH_DEAD_WORKERS = 6
+OH_ANSWER_IDS = 7  # ids in the answers of successful serves
 
 # struct layouts (little-endian, 8-aligned).  state + req_id live at
 # offsets 0/8; the TRACE block at 16 carries the W3C trace id +
@@ -284,6 +285,7 @@ def empty_stats() -> dict:
         "dss_shm_dead_workers": 0,
         "dss_shm_slots_in_flight": 0,
         "dss_shm_served_total": 0,
+        "dss_shm_answer_ids_total": 0,
         "dss_shm_errors_total": 0,
         "dss_shm_deadline_drops_total": 0,
         "dss_shm_overloaded_total": 0,
@@ -313,6 +315,9 @@ def front_stats(region: "ShmRegion") -> dict:
         "dss_shm_dead_workers": int(oh[OH_DEAD_WORKERS]),
         "dss_shm_slots_in_flight": in_flight,
         "dss_shm_served_total": int(oh[OH_SERVED]),
+        # what the owner's serve path scales with: every id of an
+        # answer costs it one record lookup and one end-time read
+        "dss_shm_answer_ids_total": int(oh[OH_ANSWER_IDS]),
         "dss_shm_errors_total": int(oh[OH_ERRORS]),
         "dss_shm_deadline_drops_total": int(oh[OH_DEADLINE_DROPS]),
         "dss_shm_overloaded_total": int(oh[OH_OVERLOADED]),
@@ -1147,6 +1152,7 @@ class ShmOwner:
                         ),
                     },
                 )
+        self._count(OH_ANSWER_IDS, len(ids))
         r.write_response(
             req.worker, req.slot, status=ST_OK, ids=ids, t1s=t1s,
             wal_seq=self._wal_seq_fn(), gen=gen, flags=flags,

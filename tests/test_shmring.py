@@ -24,6 +24,7 @@ The correctness story under test:
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 import uuid
@@ -529,6 +530,8 @@ def test_concurrent_callers_share_one_ring(tmp_path):
         assert not errs
         assert out == {i: [f"op-{i}"] for i in range(32)}
         assert client.in_flight() == 0  # every slot returned
+        # the drain threads' counts lose no update (one id an answer)
+        assert owner.stats()["dss_shm_answer_ids_total"] == 32
     finally:
         client.close()
         owner.close()
@@ -1305,6 +1308,268 @@ def test_front_stats_key_set(front):
     for k in ("shm_cache_hits", "shm_cache_misses", "shm_est_rtt_ms",
               "shm_enqueued", "shm_served", "shm_ring_full"):
         assert k in st, k
+
+
+# ---------------------------------------------------------------------------
+# the owner serves ids: DSSStore.shm_serve answers at the id-level depth
+# of the SAME search whose record-level depth HTTP handlers and
+# prechecks call — equal id for id and end time for end time, no record
+# copied, each id's end time read once
+# ---------------------------------------------------------------------------
+
+_DEPTH_CELLS = _cells(4000, 4024)
+_DEPTH_OWNER = "ua"
+_NEVER = int(np.iinfo(np.int64).max)
+
+
+def _rid_sub(i: int, cells, *, owner="u1", end=None):
+    return ridm.Subscription(
+        id=_uuid(i),
+        owner=owner,
+        url="https://uss.example/s",
+        cells=np.asarray(cells, np.uint64),
+        start_time=T0,
+        end_time=end or (T0 + timedelta(hours=8)),
+        altitude_lo=0.0,
+        altitude_hi=3000.0,
+    )
+
+
+def _depth_store(storage: str):
+    """A leader store holding seven entities of every ring class over
+    one area, inserted in an order that is not the ids' order, with
+    end times that all differ (the seventh ends first)."""
+    store = DSSStore(storage=storage, clock=FakeClock(T0))
+    for n, i in enumerate((5, 2, 7, 1, 6, 3, 4)):
+        end = T0 + timedelta(hours=1 + n)
+        cells = _DEPTH_CELLS[n % 3:]
+        store.rid.insert_isa(_isa(100 + i, cells, end=end))
+        store.rid.insert_subscription(
+            _rid_sub(200 + i, cells, owner=_DEPTH_OWNER, end=end)
+        )
+        store.scd.upsert_subscription(
+            dataclasses.replace(
+                _scd_sub(400 + i, cells, owner=_DEPTH_OWNER), end_time=end
+            )
+        )
+        store.scd.upsert_operation(
+            dataclasses.replace(
+                _op(300 + i, cells, owner=_DEPTH_OWNER,
+                    sub_id=_uuid(400 + i)),
+                end_time=end,
+            ),
+            key=[], key_checked=True,
+        )
+        store.scd.upsert_constraint(
+            dataclasses.replace(_cst(500 + i, cells), end_time=end)
+        )
+    return store
+
+
+def _ring_req(cls: str, now: datetime, *, allow_stale=False):
+    """The request a worker would put in a slot for `cls` over the
+    depth area at its instant `now`."""
+    windowed = cls in ("isa", "op", "constraint")
+    return shmring.ShmRequest(
+        cls=cls, cells=_DEPTH_CELLS,
+        alt_lo=0.0 if cls in ("op", "constraint") else None,
+        alt_hi=400.0 if cls in ("op", "constraint") else None,
+        t0_ns=to_nanos(now) if windowed else None,
+        t1_ns=to_nanos(now + timedelta(hours=12)) if windowed else None,
+        now_ns=to_nanos(now), deadline_ns=0,
+        owner=_DEPTH_OWNER if cls in ("rid_sub", "scd_sub") else "",
+        allow_stale=allow_stale, worker=0, slot=0, req_id=1,
+    )
+
+
+def _record_level(store, req) -> tuple:
+    """(ids, end times) of the record-level search for the same
+    request, at the same pinned `now`."""
+    from dss_tpu.clock import from_nanos
+
+    cls = req.cls
+    sub = store.rid if cls in ("isa", "rid_sub") else store.scd
+    t0 = None if req.t0_ns is None else from_nanos(req.t0_ns)
+    t1 = None if req.t1_ns is None else from_nanos(req.t1_ns)
+    sub._txn_time.now = int(req.now_ns)
+    try:
+        if cls == "isa":
+            recs = sub.search_isas(req.cells, t0, t1)
+        elif cls == "rid_sub":
+            recs = sub.search_subscriptions_by_owner(req.cells, req.owner)
+        elif cls == "scd_sub":
+            recs = sub.search_subscriptions(req.cells, req.owner)
+        elif cls == "op":
+            recs = sub.search_operations(
+                req.cells, req.alt_lo, req.alt_hi, t0, t1
+            )
+        else:
+            recs = sub.search_constraints(
+                req.cells, req.alt_lo, req.alt_hi, t0, t1
+            )
+    finally:
+        sub._txn_time.now = None
+    return (
+        [r.id for r in recs],
+        [_NEVER if r.end_time is None else to_nanos(r.end_time)
+         for r in recs],
+    )
+
+
+def _served(store, req) -> tuple:
+    ids, t1s, gen, flags = store.shm_serve(req)
+    assert gen == store._class_index(req.cls).cell_clock.generation
+    return (list(ids), [int(t) for t in t1s]), flags
+
+
+@pytest.mark.parametrize("storage", ["memory", "tpu"])
+@pytest.mark.parametrize("cls", shmring.SHM_CLASSES)
+def test_shm_serve_equals_record_level_search(cls, storage):
+    store = _depth_store(storage)
+    try:
+        req = _ring_req(cls, T0 + timedelta(minutes=5))
+        cache = store.cache
+        entries0 = cache.stats()["entries"]  # the writes' prechecks
+        miss, flags = _served(store, req)  # fills the owner's cache
+        assert flags == 0
+        assert cache.stats()["entries"] == entries0 + 1
+        hits0 = cache.class_stats(cls)["co_cache_hits"]
+        hit, _ = _served(store, req)  # a fenced hit: the same pairs
+        assert cache.class_stats(cls)["co_cache_hits"] == hits0 + 1
+        want = _record_level(store, req)
+        assert len(want[0]) == 7 and len(set(want[1])) == 7
+        assert miss == want and hit == want
+        if cls in ("op", "scd_sub", "constraint"):
+            # these classes answer in id order whatever the index's
+            assert want[0] == sorted(want[0])
+        store.configure_serving(cache=False)
+        fresh, _ = _served(store, req)
+        assert fresh == want == _record_level(store, req)
+    finally:
+        store.close()
+
+
+@pytest.mark.parametrize("cached", [True, False], ids=["cache", "nocache"])
+@pytest.mark.parametrize(
+    "case", ["vanished", "no_end", "expired", "mesh"]
+)
+def test_shm_serve_id_level_edges(case, cached, monkeypatch):
+    store = _depth_store("memory")
+    try:
+        store.configure_serving(cache=cached)
+        ops = store.scd._ops
+        now = T0 + timedelta(minutes=5)
+        if case == "vanished":
+            # the record went between the index query and the end-time
+            # pass: skipped, as the fresh path would skip it right now
+            del ops[_uuid(303)]
+            (ids, t1s), _ = _served(store, _ring_req("op", now))
+            assert _uuid(303) not in ids and len(ids) == len(t1s) == 6
+        elif case == "no_end":
+            ops[_uuid(303)] = dataclasses.replace(ops[_uuid(303)], end_time=None)
+            (ids, t1s), _ = _served(store, _ring_req("op", now))
+            assert t1s[ids.index(_uuid(303))] == _NEVER
+            assert sum(t == _NEVER for t in t1s) == 1 and len(ids) == 7
+        elif case == "expired":
+            # id 305 ends at T0 + 1 h: in the answer before, out after,
+            # whether the later answer is a refiltered hit or fresh
+            (ids, _), _ = _served(store, _ring_req("op", now))
+            assert _uuid(305) in ids
+            now = T0 + timedelta(minutes=90)
+            (ids, t1s), _ = _served(store, _ring_req("op", now))
+            assert _uuid(305) not in ids and len(ids) == 6
+            assert min(t1s) >= to_nanos(now)
+        else:
+            # a bounded-stale mesh answer: the flag crosses the ring
+            # and the owner's cache takes nothing from it
+            index = store.scd._op_index
+            real = index.query_ids
+
+            def meshed(*a, **kw):
+                rcache.note_mesh_served()
+                return real(*a, **kw)
+
+            monkeypatch.setattr(index, "query_ids", meshed)
+            entries0 = store.cache.stats()["entries"]
+            (ids, t1s), flags = _served(
+                store, _ring_req("op", now, allow_stale=True)
+            )
+            assert flags == shmring.RESP_F_MESH_SERVED
+            assert store.cache.stats()["entries"] == entries0
+            assert len(ids) == len(t1s) == 7
+            monkeypatch.undo()
+        req = _ring_req("op", now)
+        assert _served(store, req)[0] == _record_level(store, req)
+    finally:
+        store.close()
+
+
+def test_shm_serve_copies_no_record(monkeypatch):
+    from dss_tpu.dar import dss_store as ds
+
+    copies = []
+    real = ds._copy_rec
+    monkeypatch.setattr(
+        ds, "_copy_rec", lambda rec: copies.append(rec.id) or real(rec)
+    )
+    store = _depth_store("memory")
+    try:
+        copies.clear()  # the writes' own prechecks copied records
+        now = T0 + timedelta(minutes=5)
+        for cls in shmring.SHM_CLASSES:
+            req = _ring_req(cls, now)
+            for _ in range(2):  # a miss, then a hit
+                (ids, _), _ = _served(store, req)
+                assert len(ids) == 7
+        assert copies == []
+        # the record level still hands out copies, never the store's own
+        req = _ring_req("op", now)
+        recs = store.scd.search_operations(
+            req.cells, req.alt_lo, req.alt_hi, None, None
+        )
+        assert sorted(copies) == [r.id for r in recs] and len(recs) == 7
+        assert all(r is not store.scd._ops[r.id] for r in recs)
+    finally:
+        store.close()
+
+
+def test_answer_ids_counted_on_successful_serves_only(tmp_path):
+    def serve(req):
+        if req.owner == "shed":
+            raise errors.OverloadedError("queue full", retry_after_s=1.0)
+        if req.owner == "boom":
+            raise RuntimeError("boom")
+        return [f"id-{k}" for k in range(len(req.cells))], \
+            [10 ** 18] * len(req.cells), 3
+
+    r_o, owner, r_w = _owner_region_pair(tmp_path, serve)
+    client = shmring.ShmWorkerClient(r_w, 0, wait_s=5.0)
+    try:
+        want = 0
+        for n, who, status in (
+            (5, "", shmring.ST_OK),
+            (3, "shed", shmring.ST_OVERLOADED),
+            (4, "boom", shmring.ST_ERROR),
+            (0, "", shmring.ST_OK),
+            (2, "", shmring.ST_OK),
+        ):
+            resp = client.call(
+                cls="op", cells=np.arange(1, n + 1, dtype=np.uint64),
+                now_ns=to_nanos(T0), owner=who,
+            )
+            assert resp.status == status
+            if status == shmring.ST_OK:
+                assert len(resp.ids) == n
+                want += n
+            assert owner.stats()["dss_shm_answer_ids_total"] == want
+        # any process mapping the region renders the same family
+        assert shmring.front_stats(r_w)["dss_shm_answer_ids_total"] == 7
+        assert "dss_shm_answer_ids_total" in shmring.empty_stats()
+    finally:
+        client.close()
+        owner.close()
+        r_w.close()
+        r_o.close()
 
 
 # ---------------------------------------------------------------------------
