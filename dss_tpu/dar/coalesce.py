@@ -120,7 +120,8 @@ from dss_tpu.plan.planner import state_of as _plan_state_of
 class _Item:
     __slots__ = ("keys", "alt_lo", "alt_hi", "t_start", "t_end", "now",
                  "owner_id", "allow_stale", "deadline", "event", "result",
-                 "error", "via_mesh", "tctx", "tspans", "enq_ns")
+                 "error", "via_mesh", "via_device", "tctx", "tspans",
+                 "enq_ns")
 
     def __init__(self, keys, alt_lo, alt_hi, t_start, t_end, now, owner_id,
                  allow_stale=False, deadline=None):
@@ -142,6 +143,10 @@ class _Item:
         # answered by the sharded mesh replica (bounded-stale): the
         # read cache must not stamp this result as fresh
         self.via_mesh = False
+        # answered by the fused kernel (its own candidates over the
+        # host scan's cap, or a drain-mate's): the shm owner accounts
+        # its serve time by this (readcache.note_device_served)
+        self.via_device = False
         # cross-thread span handoff (obs/trace.py): the caller's trace
         # handle captured at admission; the pipeline threads STAMP
         # measured (name, start_ns, dur_ms, attrs) tuples here and the
@@ -351,7 +356,10 @@ class QueryCoalescer:
 
             chunk = _FT.HOST_MAX_BATCH
         except Exception:  # pragma: no cover
+            _FT = None
             chunk = 64
+        # whose HOST_MAX_CANDIDATES the accounts read (_account_paths)
+        self._fast_cls = _FT
         # the planner owns ALL cost models (dss_tpu/plan): every route
         # decision, the drain sizing, and the Retry-After throughput
         # read the same estimates through it.  self._cost stays as the
@@ -384,6 +392,15 @@ class QueryCoalescer:
         self._stat_inline_device = 0
         self._stat_inline_submit_ms = 0.0
         self._stat_inline_collect_ms = 0.0
+        # by the path that answered, inline and drained alike
+        # (_account_paths)
+        self._stat_host_scans = 0
+        self._stat_host_scan_ms = 0.0
+        self._stat_host_scan_candidates = 0
+        self._stat_host_members = 0
+        self._stat_device_members = 0
+        self._stat_device_members_under_cap = 0
+        self._stat_drains_mixed = 0
         self._stat_shed = 0
         self._stat_deadline_shed = 0
         self._stat_route_host = 0  # batches fully served on the host
@@ -821,12 +838,17 @@ class QueryCoalescer:
             self._record_item_spans(item, th)
         if item.error is not None:
             raise item.error
-        if item.via_mesh:
-            # tell the store's cache layer (same thread) this answer
-            # is bounded-stale mesh output, not fresh-path output
+        if item.via_mesh or item.via_device:
             from dss_tpu.dar import readcache as _readcache
 
-            _readcache.note_mesh_served()
+            if item.via_mesh:
+                # tell the store's cache layer (same thread) this
+                # answer is bounded-stale mesh output, not fresh-path
+                # output
+                _readcache.note_mesh_served()
+            else:
+                # and the shm owner (same thread) which path answered
+                _readcache.note_device_served()
         return item.result
 
     def close(self, join: bool = True, timeout: float = 30.0):
@@ -1182,6 +1204,7 @@ class QueryCoalescer:
                     device_ms = (t1 - t0) * 1000
                     with _trace.annotate("collect"):
                         results = self._table.query_many_collect(pq)
+                    self._mark_via_device(batch, used_device)
                     if tr_spans is not None:
                         coll_ms = (time.perf_counter() - t1) * 1000
                         now_w = time.time_ns()
@@ -1208,13 +1231,15 @@ class QueryCoalescer:
                     if tr_spans is not None:
                         th_w = time.time_ns()
                         th0 = time.perf_counter()
-                    with _trace.annotate("host.scan"):
-                        pq = self._table.query_many_submit(
-                            keys, lo, hi, t0s, t1s,
-                            now=now, owner_ids=owners, host_route=True,
-                        )
-                        observed_device = self._pq_used_device(pq)
+                    # the table opens dss.host.scan around the scan
+                    pq = self._table.query_many_submit(
+                        keys, lo, hi, t0s, t1s,
+                        now=now, owner_ids=owners, host_route=True,
+                    )
+                    observed_device = self._pq_used_device(pq)
+                    with _trace.annotate("collect"):
                         results = self._table.query_many_collect(pq)
+                    self._mark_via_device(batch, observed_device)
                     if tr_spans is not None:
                         self._stamp_spans(batch, tr_spans + [
                             ("host.scan", th_w,
@@ -1225,9 +1250,12 @@ class QueryCoalescer:
                 else:
                     # mesh-planned (or submit-less table): the full
                     # synchronous path, mesh-first with local fallback
-                    # (plan already recorded at pack time)
+                    # (plan already recorded at pack time; it keeps
+                    # its own accounts by path)
+                    pq = None
                     self._execute(batch, record_plan=False)
             except BaseException as e:  # noqa: BLE001 — deliver to callers
+                pq = None  # no answer of this attempt: no account
                 if self._absorb_device_loss(e):
                     # device lost while this batch was in flight:
                     # re-serve it on the pure host path — callers pay
@@ -1238,6 +1266,8 @@ class QueryCoalescer:
                     self._deliver_error(batch, e)
             collect_ms = (time.perf_counter() - t1) * 1000
             total_ms = pack_ms + device_ms + collect_ms
+            if pq is not None:
+                self._account_paths(batch, pq, observed_device, total_ms)
             with self._slock:
                 self._stat_batches += 1
                 self._stat_items += len(batch)
@@ -1363,7 +1393,11 @@ class QueryCoalescer:
                         {"gap_ms": round(gap_ms, 3),
                          "used_device": bool(used_device)},
                     )])
+                self._mark_via_device(_batch, used_device)
                 self._deliver_results(_batch, results)
+                # the stream hands back no handle: the members'
+                # candidates are counted again from their keys
+                self._account_paths(_batch, None, used_device, lat_ms)
             with self._slock:
                 self._stat_batches += 1
                 self._stat_items += len(_batch)
@@ -1404,6 +1438,58 @@ class QueryCoalescer:
         with self._cond:
             self._inflight_resident -= 1
         return False
+
+    @staticmethod
+    def _mark_via_device(batch: List[_Item], used_device) -> None:
+        """Before the answers go out (event.set releases the caller,
+        who reads it on its own thread)."""
+        if used_device:
+            for it in batch:
+                it.via_device = True
+
+    def _account_paths(self, batch: List[_Item], pq, on_device: bool,
+                       ms: float) -> None:
+        """One answered execution, inline or drained, on the accounts
+        of the path that answered it; after the answers are out.  The
+        host scan's count, time and candidate postings; the members by
+        path; and of a device-served batch the members the fused
+        kernel answered only because of their drain-mates: those whose
+        own candidates stay at or under the host scan's cap in every
+        tier (the gate sums the batch: dar/snapshot.py
+        query_many_submit).  A lone member on the device is over the
+        cap by that gate, so only drains pay the range lookup there.
+        `pq` None: a table without the split halves, or the resident
+        stream, which keeps its handle (the keys are looked up again).
+        """
+        b = len(batch)
+        cand = None
+        if not on_device or b > 1:
+            # getattr: the tests' stand-in tables and handles have none
+            of_pq = getattr(pq, "candidates", None)
+            of_keys = getattr(self._table, "candidates_many", None)
+            try:
+                if of_pq is not None:
+                    cand = of_pq()
+                elif of_keys is not None:
+                    cand = of_keys([it.keys for it in batch])
+            except Exception:  # noqa: BLE001 — metrics-only path
+                cand = None
+        under = mixed = 0
+        if on_device and cand is not None and cand.size:
+            cap = self._fast_cls.HOST_MAX_CANDIDATES
+            under = int((cand.max(axis=0) <= cap).sum())
+            mixed = int(0 < under < b)
+        with self._slock:
+            if on_device:
+                self._stat_device_members += b
+                self._stat_device_members_under_cap += under
+                self._stat_drains_mixed += mixed
+            else:
+                self._stat_host_scans += 1
+                self._stat_host_scan_ms += ms
+                self._stat_host_members += b
+                if cand is not None:
+                    self._stat_host_scan_candidates += int(cand.sum())
 
     @staticmethod
     def _pq_used_device(pq) -> bool:
@@ -1522,9 +1608,10 @@ class QueryCoalescer:
                 # boot seed forever.  The split is timed always: the
                 # inline counters (stats: co_inline_*) read it
                 try:
-                    with _trace.annotate(
-                        "host.scan" if host_route else "device.dispatch"
-                    ):
+                    # the submit half under its pipeline name; inside
+                    # it the table opens dss.host.scan around its host
+                    # attempt, which under the cap is the whole answer
+                    with _trace.annotate("device.dispatch"):
                         if not host_route:
                             chaos.fault_point("device.dispatch")
                         pq = submit(
@@ -1535,9 +1622,7 @@ class QueryCoalescer:
                     tc0 = time.perf_counter()
                     disp_ms = (tc0 - t0) * 1000
                     tc_w = time.time_ns() if traced else 0
-                    with _trace.annotate(
-                        "host.scan" if host_route else "collect"
-                    ):
+                    with _trace.annotate("collect"):
                         results = self._table.query_many_collect(pq)
                     coll_ms = (time.perf_counter() - tc0) * 1000
                     if traced:
@@ -1575,6 +1660,7 @@ class QueryCoalescer:
                     disp_ms = (time.perf_counter() - t0) * 1000
                     coll_ms = 0.0
             else:
+                pq = None
                 with _trace.annotate("host.scan"):
                     results = self._table.query_many(
                         keys, lo, hi, t0s, t1s, now=now,
@@ -1585,14 +1671,17 @@ class QueryCoalescer:
                         "host.scan", t0_w,
                         (time.perf_counter() - t0) * 1000, None,
                     )])
+            total_ms = (time.perf_counter() - t0) * 1000
             if used_device is not None:
-                total_ms = (time.perf_counter() - t0) * 1000
                 with self._slock:
                     if used_device:
                         self._cost.observe_device(b, total_ms)
                     elif host_route or b >= self._cost.chunk:
                         self._cost.observe_host(b, total_ms)
+            self._mark_via_device(batch, used_device)
             self._deliver_results(batch, results)
+            # a table without the split halves has no device to go to
+            self._account_paths(batch, pq, bool(used_device), total_ms)
             if used_device is None:
                 return None
             return used_device, disp_ms, coll_ms
@@ -1634,6 +1723,22 @@ class QueryCoalescer:
                 co_inline_collect_ms_total=round(
                     self._stat_inline_collect_ms, 3
                 ),
+                # by the path that answered, inline and drained alike:
+                # the host scan's executions, time and candidate
+                # postings; members by path; the under-cap members a
+                # device-served drain carried; the drains that held
+                # members on both sides of the cap
+                co_host_scans=self._stat_host_scans,
+                co_host_scan_ms_total=round(self._stat_host_scan_ms, 3),
+                co_host_scan_candidates_total=(
+                    self._stat_host_scan_candidates
+                ),
+                co_host_members=self._stat_host_members,
+                co_device_members=self._stat_device_members,
+                co_device_members_under_cap=(
+                    self._stat_device_members_under_cap
+                ),
+                co_drains_mixed=self._stat_drains_mixed,
                 co_shed=self._stat_shed,
                 co_deadline_shed=self._stat_deadline_shed,
                 co_route_host_batches=self._stat_route_host,

@@ -1711,6 +1711,7 @@ class DSSStore:
         pinned = getattr(tl, "now", None) is None
         if pinned:
             tl.now = int(req.now_ns)
+        rcache.take_device_served()  # a note no serve of this thread took
         try:
             if cls == "isa":
                 ids, t1s = sub.search_isa_ids(
@@ -1745,6 +1746,9 @@ class DSSStore:
         return (
             shmring.RESP_F_MESH_SERVED
             if rcache.take_last_search_meshed() else 0
+        ) | (
+            shmring.RESP_F_DEVICE_SERVED
+            if rcache.take_device_served() else 0
         )
 
     def attach_shm_front(self, region, *, threads: int = None,

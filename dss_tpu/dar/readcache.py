@@ -387,6 +387,21 @@ def take_mesh_served() -> bool:
     return bool(m)
 
 
+def note_device_served() -> None:
+    """Set by the coalescer when the fused kernel answered this
+    thread's query (its own candidates over the host scan's cap, or a
+    drain-mate's).  Only the shm owner takes it (DSSStore.shm_serve),
+    to account its serve time by the path that answered; no answer and
+    no cache decision reads it."""
+    _tls.device = True
+
+
+def take_device_served() -> bool:
+    d = getattr(_tls, "device", False)
+    _tls.device = False
+    return bool(d)
+
+
 def note_last_search_meshed(meshed: bool) -> None:
     """Sticky per-thread record of whether the MOST RECENT search on
     this thread was mesh-served (bounded-stale).  take_mesh_served is

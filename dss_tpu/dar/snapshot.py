@@ -62,6 +62,7 @@ from dss_tpu.dar import tiers as tiersmod
 from dss_tpu.dar.oracle import Record
 from dss_tpu.dar.pack import pow2_at_least
 from dss_tpu.dar.tiers import EMPTY_SNAPSHOT, Tier, TierSnapshot
+from dss_tpu.obs import trace as _trace
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
 from dss_tpu.ops import fastpath
 
@@ -247,6 +248,38 @@ def _overlay_search(
     return cq[keep].astype(np.int64), cand[keep].astype(np.int64)
 
 
+def _pad_keys(keys_list) -> np.ndarray:
+    """i32[B, W] query keys, one row a query, W a power of two, pad
+    -1, each row free of repeats."""
+    width = max(16, pow2_at_least(max(len(k) for k in keys_list), lo=16))
+    qkeys = np.full((len(keys_list), width), -1, np.int32)
+    for i, k in enumerate(keys_list):
+        k = np.asarray(k, np.int32)
+        qkeys[i, : len(k)] = k
+    # row-dedup in one vectorized pass instead of per-item
+    # np.unique (a third of the submit's host cost at batch 32):
+    # sort each row, then blank repeats to the -1 pad key.  Key
+    # order within a row is irrelevant (set semantics) and pads
+    # find empty postings ranges wherever they sit.
+    qkeys.sort(axis=1)
+    dup = qkeys[:, 1:] == qkeys[:, :-1]
+    if dup.any():
+        qkeys[:, 1:][dup] = -1
+    return qkeys
+
+
+def _tier_candidates(st, qkeys: np.ndarray) -> np.ndarray:
+    """i64[tiers, B]: each row's candidate postings in each tier that
+    has postings."""
+    rows = [
+        t.snap.fast.candidates(qkeys)
+        for t in st.tiers if t.snap.fast is not None
+    ]
+    if not rows:
+        return np.zeros((0, len(qkeys)), np.int64)
+    return np.stack(rows)
+
+
 class _PendingQuery:
     """One in-flight query_many batch: the immutable state it runs
     against plus either ready host-path hits or a device PendingBatch.
@@ -291,6 +324,15 @@ class _PendingQuery:
         resident loop's cost attribution both consume — keep it here
         so tier-accounting changes can't desync them."""
         return any(p is not None for p in self.tier_pending)
+
+    def candidates(self) -> np.ndarray:
+        """i64[tiers, B]: each member's candidate postings in each
+        tier of the state this batch ran against.  The host gate sums
+        a row (FastTable.HOST_MAX_CANDIDATES, per tier, over the whole
+        batch), so a member whose column stays under the cap would
+        have been scanned on the host had it come alone.  Read by the
+        coalescer's accounts after the answers are out."""
+        return _tier_candidates(self.st, self.qkeys)
 
 
 class DarTable:
@@ -758,20 +800,7 @@ class DarTable:
         if b == 0:
             return None
         now_arr = np.broadcast_to(np.asarray(now, np.int64), (b,))
-        width = max(16, pow2_at_least(max(len(k) for k in keys_list), lo=16))
-        qkeys = np.full((b, width), -1, np.int32)
-        for i, k in enumerate(keys_list):
-            k = np.asarray(k, np.int32)
-            qkeys[i, : len(k)] = k
-        # row-dedup in one vectorized pass instead of per-item
-        # np.unique (a third of this function's host cost at batch 32):
-        # sort each row, then blank repeats to the -1 pad key.  Key
-        # order within a row is irrelevant (set semantics) and pads
-        # find empty postings ranges wherever they sit.
-        qkeys.sort(axis=1)
-        dup = qkeys[:, 1:] == qkeys[:, :-1]
-        if dup.any():
-            qkeys[:, 1:][dup] = -1
+        qkeys = _pad_keys(keys_list)
 
         # per-tier answers, host path first: small batches answer from
         # each tier's host postings copy (exact, native C++ when built)
@@ -779,21 +808,26 @@ class DarTable:
         # almost always stays on the host even when L0 needs the device
         tier_host: List = []
         need_device: List[int] = []
-        for ti, tier in enumerate(st.tiers):
-            if tier.snap.fast is None:
-                tier_host.append(None)
-                continue
-            if host_route:
-                host = tier.snap.fast.query_host_chunked(
-                    qkeys, alt_lo, alt_hi, t_start, t_end, now=now_arr
-                )
-            else:
-                host = tier.snap.fast.query_host_auto(
-                    qkeys, alt_lo, alt_hi, t_start, t_end, now=now_arr
-                )
-            tier_host.append(host)
-            if host is None:
-                need_device.append(ti)
+        # the span covers the gate's walk too: a batch over the cap
+        # leaves a short one before its device.dispatch
+        with _trace.annotate("host.scan"):
+            for ti, tier in enumerate(st.tiers):
+                if tier.snap.fast is None:
+                    tier_host.append(None)
+                    continue
+                if host_route:
+                    host = tier.snap.fast.query_host_chunked(
+                        qkeys, alt_lo, alt_hi, t_start, t_end,
+                        now=now_arr,
+                    )
+                else:
+                    host = tier.snap.fast.query_host_auto(
+                        qkeys, alt_lo, alt_hi, t_start, t_end,
+                        now=now_arr,
+                    )
+                tier_host.append(host)
+                if host is None:
+                    need_device.append(ti)
         if need_device and budget.is_host_only():
             # caller is on the event loop: re-run via executor
             raise budget.NeedsDevice()
@@ -807,6 +841,12 @@ class DarTable:
             st, b, qkeys, alt_lo, alt_hi, t_start, t_end, now_arr,
             owner_ids, tier_host, tier_pending,
         )
+
+    def candidates_many(self, keys_list) -> np.ndarray:
+        """_PendingQuery.candidates for a caller that holds the keys
+        and no handle (the resident stream's batches), against the
+        state published now."""
+        return _tier_candidates(self._state, _pad_keys(keys_list))
 
     def query_many_collect(self, pq: Optional[_PendingQuery]) -> List[List[str]]:
         """The collect/decode half of query_many: resolve the device

@@ -844,6 +844,15 @@ class FastTable:
             return None
         return lo, hi
 
+    def candidates(self, qkeys: np.ndarray) -> np.ndarray:
+        """i64[B]: the candidate postings under each row of `qkeys`:
+        what the host gate sums over the batch and a scan walks (pad
+        keys find none).  For the serving accounts (dar/coalesce.py),
+        never for routing."""
+        qk = np.ascontiguousarray(qkeys, np.int32)
+        lo, hi = self._range_lookup(qk.ravel())
+        return (hi - lo).reshape(qk.shape).sum(axis=1)
+
     def query_host_chunked(
         self, qkeys, alt_lo, alt_hi, t_start, t_end, *, now,
         chunk: Optional[int] = None,

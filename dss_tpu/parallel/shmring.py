@@ -127,6 +127,11 @@ ST_OVERFLOW = 507  # answer larger than the slot: re-ask over loopback
 RESP_F_MESH_SERVED = 1  # bounded-stale mesh answer: worker must NOT
 #                         populate its cache from it (the leader's
 #                         _cached_ids refuses for the same reason)
+RESP_F_DEVICE_SERVED = 2  # the fused kernel answered (the coalescer's
+#                           device route, for the request's own
+#                           candidates or a drain-mate's): the owner
+#                           accounts its serve time by it; a worker
+#                           decides nothing on it
 
 # request flags
 F_ALLOW_STALE = 1
@@ -183,6 +188,14 @@ OH_RECLAIMED = 4
 OH_SERVE_NS = 5
 OH_DEAD_WORKERS = 6
 OH_ANSWER_IDS = 7  # ids in the answers of successful serves
+# successful serves and their time (pickup -> response written, the
+# stage ring_serve_ms) by the path that answered: the fused kernel
+# (RESP_F_DEVICE_SERVED), or the host (the host scan, the owner's
+# cache, an empty covering).  The two counts sum to OH_SERVED.
+OH_HOST_SERVED = 8
+OH_HOST_SERVE_NS = 9
+OH_DEVICE_SERVED = 10
+OH_DEVICE_SERVE_NS = 11
 
 # struct layouts (little-endian, 8-aligned).  state + req_id live at
 # offsets 0/8; the TRACE block at 16 carries the W3C trace id +
@@ -286,6 +299,10 @@ def empty_stats() -> dict:
         "dss_shm_slots_in_flight": 0,
         "dss_shm_served_total": 0,
         "dss_shm_answer_ids_total": 0,
+        "dss_shm_host_served_total": 0,
+        "dss_shm_host_serve_ms_total": 0.0,
+        "dss_shm_device_served_total": 0,
+        "dss_shm_device_serve_ms_total": 0.0,
         "dss_shm_errors_total": 0,
         "dss_shm_deadline_drops_total": 0,
         "dss_shm_overloaded_total": 0,
@@ -318,6 +335,15 @@ def front_stats(region: "ShmRegion") -> dict:
         # what the owner's serve path scales with: every id of an
         # answer costs it one record lookup and one end-time read
         "dss_shm_answer_ids_total": int(oh[OH_ANSWER_IDS]),
+        # successful serves and their time by the path that answered
+        "dss_shm_host_served_total": int(oh[OH_HOST_SERVED]),
+        "dss_shm_host_serve_ms_total": round(
+            int(oh[OH_HOST_SERVE_NS]) / 1e6, 3
+        ),
+        "dss_shm_device_served_total": int(oh[OH_DEVICE_SERVED]),
+        "dss_shm_device_serve_ms_total": round(
+            int(oh[OH_DEVICE_SERVE_NS]) / 1e6, 3
+        ),
         "dss_shm_errors_total": int(oh[OH_ERRORS]),
         "dss_shm_deadline_drops_total": int(oh[OH_DEADLINE_DROPS]),
         "dss_shm_overloaded_total": int(oh[OH_OVERLOADED]),
@@ -1158,6 +1184,15 @@ class ShmOwner:
             wal_seq=self._wal_seq_fn(), gen=gen, flags=flags,
             trace_ns=trace_vec, stamps=stamps,
         )
+        # pickup (where the serve loop stamped one) -> response
+        # written: what the worker marks as ring_serve_ms
+        took = time.perf_counter_ns() - (stamps[1] or t_serve0)
+        on_device = bool(flags & RESP_F_DEVICE_SERVED)
+        with self._lock:
+            oh = r._ohdr
+            oh[OH_DEVICE_SERVED if on_device else OH_HOST_SERVED] += 1
+            oh[OH_DEVICE_SERVE_NS if on_device
+               else OH_HOST_SERVE_NS] += took
         return ST_OK
 
     # -- introspection -------------------------------------------------------

@@ -1572,6 +1572,121 @@ def test_answer_ids_counted_on_successful_serves_only(tmp_path):
         r_o.close()
 
 
+def _until(cond, timeout_s=5.0):
+    t_end = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < t_end, "never happened"
+        time.sleep(0.002)
+
+
+def test_serves_are_counted_by_the_path_that_answered(tmp_path):
+    """Successful serves and their time land on the host's or the
+    device's pair of owner header words, by the flag the serve fn
+    hands back; the two counts sum to the served count, and any
+    process mapping the region renders the same families."""
+    def serve(req):
+        if req.owner == "boom":
+            raise RuntimeError("boom")
+        time.sleep(0.002)
+        flags = shmring.RESP_F_DEVICE_SERVED if req.owner == "dev" else 0
+        return ["id-1"], [10 ** 18], 3, flags
+
+    r_o, owner, r_w = _owner_region_pair(tmp_path, serve)
+    client = shmring.ShmWorkerClient(r_w, 0, wait_s=5.0)
+    try:
+        for who in ("", "dev", "", "boom", "dev", ""):
+            resp = client.call(
+                cls="op", cells=np.asarray([1], np.uint64),
+                now_ns=to_nanos(T0), owner=who,
+            )
+            assert resp.status == (
+                shmring.ST_ERROR if who == "boom" else shmring.ST_OK)
+            # the flag rides the response; a worker decides nothing on it
+            assert resp.flags == (
+                shmring.RESP_F_DEVICE_SERVED if who == "dev" else 0)
+            assert not resp.mesh_served
+        # the per-path words are written after the response is out
+        _until(lambda: owner.stats()["dss_shm_served_total"] == 5
+               and owner.stats()["dss_shm_host_served_total"]
+               + owner.stats()["dss_shm_device_served_total"] == 5)
+        for st in (owner.stats(), shmring.front_stats(r_w)):
+            assert st["dss_shm_host_served_total"] == 3
+            assert st["dss_shm_device_served_total"] == 2
+            assert st["dss_shm_host_serve_ms_total"] >= 3 * 2.0
+            assert st["dss_shm_device_serve_ms_total"] >= 2 * 2.0
+            assert (st["dss_shm_host_serve_ms_total"]
+                    + st["dss_shm_device_serve_ms_total"]
+                    <= st["dss_shm_serve_ms_total"])
+        for name in ("host_served_total", "host_serve_ms_total",
+                     "device_served_total", "device_serve_ms_total"):
+            assert f"dss_shm_{name}" in shmring.empty_stats()
+    finally:
+        client.close()
+        owner.close()
+        r_w.close()
+        r_o.close()
+
+
+def test_the_store_tells_the_owner_which_path_answered(monkeypatch):
+    """shm_serve flags an answer of the fused kernel and no other: a
+    host scan, a hit in the owner's cache and the next request on the
+    thread all read 0.  The coalescer's accounts by path and the
+    owner's per-path families come out on /metrics."""
+    from dss_tpu.obs.metrics import MetricsRegistry
+    from dss_tpu.ops.fastpath import FastTable
+
+    store = _depth_store("tpu")
+    try:
+        store.scd._op_index.table.fold()  # postings, not only overlay
+        now = T0 + timedelta(minutes=5)
+        st0 = store.stats()  # the writes' own prechecks scanned too
+
+        def moved(name):
+            return store.stats()[name] - st0[name]
+
+        req = _ring_req("op", now)
+        (ids, _), flags = _served(store, req)  # 7 ops: a host scan
+        assert len(ids) == 7 and flags == 0
+        assert moved("dss_dar_op_co_host_scans") == 1
+        assert moved("dss_dar_op_co_host_members") == 1
+        assert moved("dss_dar_op_co_host_scan_candidates_total") >= 7
+        assert moved("dss_dar_op_co_device_members") == 0
+
+        monkeypatch.setattr(FastTable, "HOST_MAX_CANDIDATES", 3)
+        later = _ring_req("op", now + timedelta(seconds=1))  # a miss
+        (ids, _), flags = _served(store, later)
+        assert len(ids) == 7
+        assert flags == shmring.RESP_F_DEVICE_SERVED
+        (ids, _), flags = _served(store, later)  # the owner's cache
+        assert len(ids) == 7 and flags == 0
+        monkeypatch.setattr(FastTable, "HOST_MAX_CANDIDATES", 1 << 16)
+        _, flags = _served(store, _ring_req("isa", now))
+        assert flags == 0
+        assert moved("dss_dar_op_co_device_members") == 1
+        assert moved("dss_dar_op_co_device_members_under_cap") == 0
+        assert moved("dss_dar_op_co_drains_mixed") == 0
+        assert moved("dss_dar_op_co_host_scans") == 1
+        st = store.stats()
+
+        reg = MetricsRegistry()
+        for name, val in {**st, **shmring.empty_stats()}.items():
+            if not isinstance(val, dict):
+                reg.set_gauge(name, val)
+        text = reg.render()
+        for name in (
+            "dss_dar_op_co_host_scans", "dss_dar_op_co_host_scan_ms_total",
+            "dss_dar_op_co_host_scan_candidates_total",
+            "dss_dar_op_co_host_members", "dss_dar_op_co_device_members",
+            "dss_dar_op_co_device_members_under_cap",
+            "dss_dar_op_co_drains_mixed", "dss_shm_host_served_total",
+            "dss_shm_host_serve_ms_total", "dss_shm_device_served_total",
+            "dss_shm_device_serve_ms_total",
+        ):
+            assert f"\n{name} " in text, name
+    finally:
+        store.close()
+
+
 # ---------------------------------------------------------------------------
 # differential: worker == leader across folds / compactions / tombstones
 # (tpu backend: the tier machinery is what the folds exercise)
