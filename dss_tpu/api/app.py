@@ -35,6 +35,7 @@ from dss_tpu.auth.authorizer import (
     require_all_scopes,
     require_any_scope,
 )
+from dss_tpu.parallel.shmring import WSTAT_NAMES
 
 RID_READ = "dss.read.identification_service_areas"
 RID_WRITE = "dss.write.identification_service_areas"
@@ -431,14 +432,7 @@ _GAUGE_VEC_LABELS = {
     # shared-memory front per-worker counters (parallel/shmring.py):
     # the leader aggregates every worker's shm stats block so ONE
     # scrape sees the whole front, keyed by the worker's process id
-    **{
-        f"dss_shm_worker_{name}": "process"
-        for name in (
-            "enqueued", "served", "cache_hits", "cache_misses",
-            "ring_full", "timeouts", "oversize", "proxy_fallbacks",
-            "assembly_misses", "errors", "plan_shm", "plan_proxy",
-        )
-    },
+    **{f"dss_shm_worker_{name}": "process" for name in WSTAT_NAMES.values()},
 }
 
 

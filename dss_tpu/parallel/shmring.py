@@ -10,7 +10,7 @@ undo" pitfall the pjit guidance in SNIPPETS.md warns about).  This
 module is the placement fix: N request workers share ONE device-owner
 process over an mmap'd region, and the hot search path crosses the
 process boundary as fixed-layout binary slots — no JSON, no pickle,
-no sockets, no syscalls beyond the page faults.
+no sockets, and no syscall but the wake-ups of the two waits.
 
 One region file, four segments:
 
@@ -43,6 +43,16 @@ One region file, four segments:
                 without locks (the only ISA this repo's build hosts
                 run; an acquire/release port is a TODO for ARM).
 
+                Neither side polls for the other's store: the owner's
+                scanner blocks on the header's doorbell word, which
+                every published request changes and wakes, and a
+                waiting worker blocks on its slot's state word, which
+                the owner wakes when it publishes RESP or frees the
+                slot (futex(2) on the shared mapping; see "the waits"
+                below).  Each wait's time limit is the cap of the
+                sleep it replaces, so a wake that is lost or late
+                costs what polling cost, never more.
+
 Request payload: canonical covering cells as a raw uint64 run +
 time/altitude window + class/owner scope + deadline.  Response: the
 (id, t_end) hit pairs, the WAL sequence at answer time (the worker's
@@ -60,9 +70,13 @@ rather than ever serving across a missed bump).
 
 from __future__ import annotations
 
+import ctypes
+import errno
 import mmap
 import os
+import platform
 import struct
+import sys
 import threading
 import time
 from bisect import bisect_left
@@ -104,10 +118,13 @@ __all__ = [
 SHM_CLASSES = ("isa", "rid_sub", "op", "scd_sub", "constraint")
 
 MAGIC = 0x4453_5353_484D_5231  # "DSSSHMR1"
-VERSION = 3  # v2: trace words in the slot header + the per-process
+VERSION = 4  # v2: trace words in the slot header + the per-process
 #              stage-histogram segment (distributed tracing PR)
 #              v3: three clock stamps in every response + the stage
 #              blocks' new names (the ring split at its seams)
+#              v4: the owner's doorbell word in the header (a worker
+#              that never rings it would leave every request to the
+#              scanner's backstop)
 
 HEADER_BYTES = 4096
 WSTAT_BYTES = 256  # 32 i64 counters per worker
@@ -157,6 +174,11 @@ WS_WAIT_NS = 10
 WS_ERRORS = 11
 WS_PLAN_SHM = 12
 WS_PLAN_PROXY = 13
+# how this worker's waits for an answer ended (ShmWorkerClient.call):
+# by the owner's wake-up, or with the answer there and no wake-up
+# until the wait's backstop ran out (a lost or late wake)
+WS_WAKES = 14
+WS_WAKE_BACKSTOPS = 15
 WSTAT_NAMES = {
     WS_ENQUEUED: "enqueued",
     WS_SERVED: "served",
@@ -170,6 +192,8 @@ WSTAT_NAMES = {
     WS_ERRORS: "errors",
     WS_PLAN_SHM: "plan_shm",
     WS_PLAN_PROXY: "plan_proxy",
+    WS_WAKES: "wakes",
+    WS_WAKE_BACKSTOPS: "wake_backstops",
 }
 
 _OWNER_MAX = 120  # bytes of utf-8 owner scope a slot can carry
@@ -196,6 +220,15 @@ OH_HOST_SERVED = 8
 OH_HOST_SERVE_NS = 9
 OH_DEVICE_SERVED = 10
 OH_DEVICE_SERVE_NS = 11
+# scans that found requests (ShmOwner._scan_loop), by how the wait
+# before them ended: woken by a worker's doorbell (or no wait at all),
+# or only when the wait's backstop ran out (a lost or late wake)
+OH_WAKES = 12
+OH_WAKE_BACKSTOPS = 13
+# the owner's doorbell: one 32-bit word on a cache line of its own,
+# written by every worker (ShmRegion.write_request), waited on by the
+# owner's scanner
+_DOORBELL_OFF = 256
 
 # struct layouts (little-endian, 8-aligned).  state + req_id live at
 # offsets 0/8; the TRACE block at 16 carries the W3C trace id +
@@ -219,7 +252,12 @@ _TRACE_RESP_OFF = _TRACE_OFF + _TRACE_REQ.size
 # four stages (dar/shmfront.py): pickup, queue, serve, return.
 _STAMPS = struct.Struct("<qqq")  # claim, pickup, write
 _STAMPS_OFF = _TRACE_RESP_OFF + _TRACE_RESP.size
-_TRACE_BYTES = 128  # 3 + 8 + 3 words, padded to 8-word alignment
+# and every request one of the worker's: the instant it was published,
+# by which the owner's scanner tells a request that sat through a wait
+# unwoken from one that arrived as the wait ran out
+_PUBLISHED = struct.Struct("<q")
+_PUBLISHED_OFF = _STAMPS_OFF + _STAMPS.size
+_TRACE_BYTES = 128  # 3 + 8 + 3 + 1 words, padded to 8-word alignment
 TRACE_F_SAMPLED = 1
 TRACE_F_PRESENT = 2
 
@@ -274,6 +312,114 @@ class RingOversize(RuntimeError):
     """Request (covering) or response (hits) exceeds the slot."""
 
 
+# -- the waits ---------------------------------------------------------------
+#
+# A round trip has two cross-process waits: the owner's scanner waits
+# for a request, a worker's request thread for its answer.  Both block
+# on a 32-bit word of the shared mapping until the other side has
+# changed it and woken it: futex(2), which compares the word with the
+# value the waiter last read and blocks in ONE step, so a store that
+# lands between the waiter's read and its wait makes the wait return
+# at once.  A mapped FILE is keyed by inode and offset (no
+# FUTEX_PRIVATE_FLAG), so the two mappings of two processes meet.
+# The waiting thread sits in a system call with the GIL released.
+#
+# Every wait carries a time limit, the BACKSTOP: the cap of the sleep
+# that the wait replaced.  The slot's state word cannot lose a wake
+# (one writer, the store before the wake); the doorbell can, because
+# workers change it by a plain read-modify-write and two of them can
+# between them leave the value the scanner read.  Then, and when a
+# waker is descheduled between its store and its wake, the backstop
+# finds the work, as late as polling would have and no later.
+#
+# Where the platform has no futex (not Linux, an unlisted machine, or
+# a kernel that refuses the call) the same two loops sleep in growing
+# steps up to the same caps and nobody wakes anybody.  Which of the
+# two bodies runs is what the platform answered at import; no knob.
+
+_OWNER_BACKSTOP_S = 0.002  # the scanner's wait for a request
+_WORKER_BACKSTOP_S = 0.001  # a request thread's wait for its answer
+_POLL_FIRST_S = 0.0002  # the no-futex body's first sleep
+
+_FUTEX_WAIT, _FUTEX_WAKE = 0, 1
+_SYS_FUTEX = {"x86_64": 202, "aarch64": 98}
+
+
+class _Timespec(ctypes.Structure):
+    _fields_ = [("tv_sec", ctypes.c_long), ("tv_nsec", ctypes.c_long)]
+
+
+def _futex_syscall():
+    """-> libc's syscall(), bound for futex(2) and tried once on a
+    word of this process's own; None where the platform has none."""
+    nr = _SYS_FUTEX.get(platform.machine())
+    if not sys.platform.startswith("linux") or nr is None:
+        return None
+    try:
+        call = ctypes.CDLL(None, use_errno=True).syscall
+    except (OSError, AttributeError):
+        return None
+    call.restype = ctypes.c_long
+    call.argtypes = [
+        ctypes.c_long, ctypes.c_void_p, ctypes.c_int, ctypes.c_uint32,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint32,
+    ]
+    word = ctypes.c_uint32(0)
+    addr = ctypes.addressof(word)
+    # nobody waits on the word: a kernel that has the call wakes 0,
+    # and refuses to wait for a value the word does not hold
+    if call(nr, addr, _FUTEX_WAKE, 1, None, None, 0) != 0:
+        return None
+    if (call(nr, addr, _FUTEX_WAIT, 1, None, None, 0) != -1
+            or ctypes.get_errno() != errno.EAGAIN):
+        return None
+    return lambda addr, op, val, ts: call(nr, addr, op, val, ts, None, 0)
+
+
+_futex = _futex_syscall()
+
+
+def _futex_wait(addr: int, expected: int, limit_s: float,
+                turn: int) -> bool:
+    """Block while the word at `addr` holds `expected`, `limit_s` at
+    most.  -> True when woken or when the word had changed already,
+    False when the limit ran out."""
+    ts = _Timespec(int(limit_s), int(limit_s % 1.0 * 1e9))
+    if _futex(addr, _FUTEX_WAIT, expected, ctypes.byref(ts)) == 0:
+        return True
+    err = ctypes.get_errno()
+    if err in (errno.EAGAIN, errno.EINTR):
+        return True
+    if err != errno.ETIMEDOUT:
+        # an error the probe at import did not meet: wait this turn as
+        # the no-futex body does, never let the caller's loop spin
+        return _poll_wait(addr, expected, limit_s, turn)
+    return False
+
+
+def _futex_wake(addr: int) -> None:
+    _futex(addr, _FUTEX_WAKE, 0x7FFFFFFF, None)
+
+
+def _poll_wait(addr: int, expected: int, limit_s: float,
+               turn: int) -> bool:
+    """The no-futex body: sleep the `turn`th step of a doubling
+    back-off, `limit_s` at most, and let the caller look again.
+    Never woken, so always False."""
+    time.sleep(min(limit_s, _POLL_FIRST_S * (1 << min(turn, 8))))
+    return False
+
+
+def _poll_wake(addr: int) -> None:
+    pass
+
+
+_wait_word, _wake_word = (
+    (_futex_wait, _futex_wake) if _futex is not None
+    else (_poll_wait, _poll_wake)
+)
+
+
 def env_knobs() -> dict:
     """ShmRegion geometry from DSS_SHM_* env vars (docs/OPERATIONS.md;
     DSS_SHM_DEPTH / DSS_SHM_SLOT_BYTES are autotune-swept knobs)."""
@@ -308,6 +454,8 @@ def empty_stats() -> dict:
         "dss_shm_overloaded_total": 0,
         "dss_shm_reclaimed_total": 0,
         "dss_shm_serve_ms_total": 0.0,
+        "dss_shm_owner_wakes_total": 0,
+        "dss_shm_owner_wake_backstops_total": 0,
         "dss_shm_saturation": 0.0,
         "dss_shm_ring_full_total": 0,
     }
@@ -349,6 +497,10 @@ def front_stats(region: "ShmRegion") -> dict:
         "dss_shm_overloaded_total": int(oh[OH_OVERLOADED]),
         "dss_shm_reclaimed_total": int(oh[OH_RECLAIMED]),
         "dss_shm_serve_ms_total": round(int(oh[OH_SERVE_NS]) / 1e6, 3),
+        # scans that found requests: after a worker's wake-up (or no
+        # wait), against only after the wait's backstop ran out
+        "dss_shm_owner_wakes_total": int(oh[OH_WAKES]),
+        "dss_shm_owner_wake_backstops_total": int(oh[OH_WAKE_BACKSTOPS]),
         # fraction of the whole front's slots in flight — the
         # DssShmRingSaturated alert input
         "dss_shm_saturation": round(
@@ -467,6 +619,13 @@ class ShmRegion:
         self._ohdr = np.ndarray(
             (16,), dtype=np.int64, buffer=mm, offset=_OHDR_OFF,
         )
+        # the owner's doorbell, and where this mapping starts in this
+        # process's memory: the waits name their words by address
+        self._bell = np.ndarray(
+            (1,), dtype=np.uint32, buffer=mm, offset=_DOORBELL_OFF,
+        )
+        self._base = self._states.ctypes.data - self.rings_off
+        self.bell_addr = self._base + _DOORBELL_OFF
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -536,6 +695,7 @@ class ShmRegion:
         self._fence_stamps = []
         self._states = None
         self._ohdr = None
+        self._bell = None
         self._buf.release()
         self._mm.close()
 
@@ -634,6 +794,27 @@ class ShmRegion:
     def set_slot_state(self, worker: int, slot: int, state: int) -> None:
         self._states[worker * self.depth + slot] = state
 
+    def slot_addr(self, worker: int, slot: int) -> int:
+        """Where the slot's state word lies in this process: the low
+        32 bits of the little-endian i64 are what a waiter waits on."""
+        return self._base + self._slot_off(worker, slot)
+
+    def free_slot(self, worker: int, slot: int) -> None:
+        """Owner side: take a slot back unanswered, and wake whoever
+        waits on it (it raises RingTimeout at once)."""
+        self._states[worker * self.depth + slot] = FREE
+        _wake_word(self.slot_addr(worker, slot))
+
+    def published_ns(self, worker: int, slot: int) -> int:
+        """When the slot's request was published (perf_counter_ns of
+        the worker, the host's one clock)."""
+        return _PUBLISHED.unpack_from(
+            self._mm, self._slot_off(worker, slot) + _PUBLISHED_OFF
+        )[0]
+
+    def doorbell(self) -> int:
+        return int(self._bell[0])
+
     def req_capacity_cells(self, owner_len: int) -> int:
         return (
             self.slot_bytes - _REQ_FIXED - _pad8(owner_len)
@@ -698,8 +879,16 @@ class ShmRegion:
         if n:
             mm[p:p + 8 * n] = cells.tobytes()
         struct.pack_into("<q", mm, off + 8, req_id)
+        _PUBLISHED.pack_into(
+            mm, off + _PUBLISHED_OFF, time.perf_counter_ns()
+        )
         # publish LAST: one aligned 8-byte store
         self._states[worker * self.depth + slot] = REQ
+        # then ring the owner's doorbell.  A plain read-modify-write:
+        # two workers can leave the value the scanner read, which is
+        # why its wait keeps the old sleep's cap as a backstop
+        self._bell += 1
+        _wake_word(self.bell_addr)
 
     def read_request(self, worker: int, slot: int) -> ShmRequest:
         off = self._slot_off(worker, slot)
@@ -785,6 +974,7 @@ class ShmRegion:
             time.perf_counter_ns(),
         )
         self._states[worker * self.depth + slot] = RESP
+        _wake_word(self._base + off)
 
     def read_response(self, worker: int, slot: int) -> ShmResponse:
         off = self._slot_off(worker, slot)
@@ -1010,7 +1200,7 @@ class ShmOwner:
         for s in range(r.depth):
             st = r.slot_state(worker, s)
             if st in (REQ, RESP):
-                r.set_slot_state(worker, s, FREE)
+                r.free_slot(worker, s)
                 freed += 1
         self._count(OH_RECLAIMED, freed)
         with self._lock:
@@ -1027,19 +1217,29 @@ class ShmOwner:
 
     def _scan_loop(self) -> None:
         r = self._region
-        idle_sleep = 0.0002
+        turn = 0  # waits since the last claim
+        t_out = 0  # when the last wait's backstop ran out, if it did
         last_ttl_check = 0.0
         while not self._stop.is_set():
             r.set_owner_heartbeat()
+            # the doorbell BEFORE the scan: a request published after
+            # this read has changed it, and the wait below, which
+            # expects this value, then returns at once
+            bell = r.doorbell()
             states = r._states
             req_idx = np.nonzero(states == REQ)[0]
             if len(req_idx):
                 claimed = []
+                late = False
                 t_claim = time.perf_counter_ns()
                 for flat in req_idx.tolist():
                     w, s = divmod(flat, r.depth)
+                    # a backstop found it: published before the last
+                    # wait ran out, and no wake-up came
+                    if t_out and not late:
+                        late = r.published_ns(w, s) < t_out
                     if w in self._dead_workers:
-                        r.set_slot_state(w, s, FREE)
+                        r.free_slot(w, s)
                         self._count(OH_RECLAIMED)
                         continue
                     r.set_slot_state(w, s, BUSY)
@@ -1048,11 +1248,18 @@ class ShmOwner:
                     with self._qcond:
                         self._queue.extend(claimed)
                         self._qcond.notify_all()
-                idle_sleep = 0.0002
+                self._count(OH_WAKE_BACKSTOPS if late else OH_WAKES)
+                turn, t_out = 0, 0
             else:
+                # the backstop also paces the heartbeat above and the
+                # sweep below
+                t_in = time.perf_counter_ns()
                 with _trace.annotate("owner.scan_idle"):
-                    time.sleep(idle_sleep)
-                idle_sleep = min(idle_sleep * 2, 0.002)
+                    woken = _wait_word(
+                        r.bell_addr, bell, _OWNER_BACKSTOP_S, turn
+                    )
+                t_out = 0 if woken else t_in + int(_OWNER_BACKSTOP_S * 1e9)
+                turn += 1
             # sweep RESP slots of dead workers + heartbeat-based TTL
             now = time.monotonic()
             if now - last_ttl_check > 1.0:
@@ -1067,7 +1274,7 @@ class ShmOwner:
                         continue
                     for s in range(r.depth):
                         if r.slot_state(w, s) == RESP:
-                            r.set_slot_state(w, s, FREE)
+                            r.free_slot(w, s)
                             self._count(OH_RECLAIMED)
                 if self._worker_ttl_s > 0:
                     for w in range(r.nworkers):
@@ -1100,7 +1307,7 @@ class ShmOwner:
                         w, s, status=ST_ERROR, stamps=(t_claim, t0)
                     )
                 except Exception:  # noqa: BLE001
-                    r.set_slot_state(w, s, FREE)
+                    r.free_slot(w, s)
             finally:
                 with self._lock:
                     # served counts SUCCESSFUL serves only — an
@@ -1320,11 +1527,14 @@ class ShmWorkerClient:
             )
             wrote = True
             self._region.stat_add(self.worker, WS_ENQUEUED)
-            # spin-then-sleep wait: first ~200us busy (the common
-            # owner turnaround), then short sleeps up to the bound
+            # block on the slot's state word until the owner wakes it
+            # (RESP, or FREE for a reclaimed slot), expecting the state
+            # last read: the owner's REQ -> BUSY wakes nobody, the next
+            # wait then expects BUSY
             t_end = time.monotonic_ns() + int(wait_s * 1e9)
-            spin_until = time.monotonic_ns() + 200_000
-            sleep_s = 0.0
+            addr = r.slot_addr(self.worker, slot)
+            turn = 0
+            t_out = 0  # when the last wait ran its backstop out, if it did
             while True:
                 st = r.slot_state(self.worker, slot)
                 if st == RESP:
@@ -1342,18 +1552,25 @@ class ShmWorkerClient:
                         "owner reclaimed the slot (worker marked dead)"
                     )
                 now = time.monotonic_ns()
-                if now >= t_end:
+                left_ns = t_end - now
+                if left_ns <= 0:
                     with self._alloc_lock:
                         self._abandoned.add(slot)
                     self._region.stat_add(self.worker, WS_TIMEOUTS)
                     raise RingTimeout(
                         f"owner did not answer within {wait_s:g}s"
                     )
-                if now < spin_until:
-                    continue
-                sleep_s = min(sleep_s + 0.00005, 0.001)
-                time.sleep(sleep_s)
+                limit_ns = min(int(_WORKER_BACKSTOP_S * 1e9), left_ns)
+                woken = _wait_word(addr, st, limit_ns / 1e9, turn)
+                # monotonic_ns and perf_counter_ns: one clock (above)
+                t_out = 0 if woken else now + limit_ns
+                turn += 1
             resp = r.read_response(self.worker, slot)
+            # a backstop found it: the answer was written (the owner's
+            # stamp, on the host's one clock) before the last wait ran
+            # out, and no wake-up came
+            late = resp.stamps[2] < t_out
+            self.stat_add(WS_WAKE_BACKSTOPS if late else WS_WAKES)
             r.set_slot_state(self.worker, slot, FREE)
             self._release(slot)
             slot = None

@@ -25,6 +25,11 @@ The correctness story under test:
 from __future__ import annotations
 
 import dataclasses
+import json
+import os
+import re
+import subprocess
+import sys
 import threading
 import time
 import uuid
@@ -1685,6 +1690,418 @@ def test_the_store_tells_the_owner_which_path_answered(monkeypatch):
             assert f"\n{name} " in text, name
     finally:
         store.close()
+
+
+# ---------------------------------------------------------------------------
+# the waits: a round trip's two cross-process waits block on words of
+# the shared mapping until the other side wakes them (futex), with the
+# old sleeps' caps as the backstop of a lost wake; where the platform
+# has no futex the same loops sleep
+# ---------------------------------------------------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_needs_futex = pytest.mark.skipif(
+    shmring._futex is None, reason="this platform answered: no futex"
+)
+
+
+@pytest.fixture(params=["futex", "poll"])
+def wait_body(request, monkeypatch):
+    """Both bodies of the wait, the polling one forced where the
+    platform chose the other at import."""
+    if request.param == "futex":
+        if shmring._futex is None:
+            pytest.skip("this platform answered: no futex")
+    else:
+        monkeypatch.setattr(shmring, "_wait_word", shmring._poll_wait)
+        monkeypatch.setattr(shmring, "_wake_word", shmring._poll_wake)
+    return request.param
+
+
+def _echo(req):
+    return [str(int(c)) for c in req.cells], [7] * len(req.cells), 1
+
+
+def _reclaimed_under_a_waiter(r, client, reclaim) -> float:
+    """A request thread blocks on slot (0, 1) of an owner that never
+    scans; `reclaim()` takes the slot back.  -> seconds from the
+    reclaim to the waiter's RingTimeout."""
+    res = {}
+
+    def go():
+        try:
+            client.call(
+                cls="isa", cells=np.asarray([1], np.uint64), now_ns=0
+            )
+        except shmring.RingTimeout:
+            res["at"] = time.monotonic()
+
+    th = threading.Thread(target=go)
+    th.start()
+    _until(lambda: r.slot_state(0, 1) == shmring.REQ)
+    time.sleep(0.02)  # the waiter is inside its wait
+    t_free = time.monotonic()
+    reclaim()
+    th.join(timeout=4)
+    assert not th.is_alive()
+    assert client.in_flight() == 0  # the slot is back in the local pool
+    return res["at"] - t_free
+
+
+def _wake_counts(owner, client):
+    st, ws = owner.stats(), client.stats()
+    return (st["dss_shm_owner_wakes_total"],
+            st["dss_shm_owner_wake_backstops_total"],
+            ws["wakes"], ws["wake_backstops"])
+
+
+def test_round_trips_under_either_wait_body(tmp_path, wait_body):
+    """The same round-trip cases whichever body waits: answers, the
+    owner's verdicts, a timed-out waiter's abandoned slot coming back,
+    and the count of how each wait ended."""
+    gate = threading.Event()
+    gate.set()
+
+    def serve(req):
+        if req.owner == "shed":
+            raise errors.OverloadedError("queue full", retry_after_s=1.5)
+        if req.owner == "boom":
+            raise RuntimeError("boom")
+        if req.owner == "slow":
+            gate.wait(10)
+        return _echo(req)
+
+    r_o, owner, r_w = _owner_region_pair(tmp_path, serve, depth=4)
+    client = shmring.ShmWorkerClient(r_w, 0, wait_s=5.0)
+    try:
+        for k in range(20):
+            time.sleep(0.003 * (k % 3))  # an idle scanner, and a busy one
+            resp = client.call(
+                cls="op", cells=np.asarray([k, k + 1], np.uint64), now_ns=0
+            )
+            assert resp.status == shmring.ST_OK
+            assert resp.ids == [str(k), str(k + 1)]
+            assert resp.stamps[0] <= resp.stamps[1] <= resp.stamps[2]
+        for who, status in (("shed", shmring.ST_OVERLOADED),
+                            ("boom", shmring.ST_ERROR)):
+            resp = client.call(
+                cls="op", cells=np.asarray([1], np.uint64), now_ns=0,
+                owner=who,
+            )
+            assert resp.status == status
+        ow, ob, ww, wb = _wake_counts(owner, client)
+        assert ww + wb == 22  # one count for every answer met
+        assert 1 <= ow + ob <= 22  # one for every scan that found work
+        if wait_body == "futex":
+            # woken, or there before any wait; a loaded machine may
+            # deschedule a waker between its store and its wake
+            assert ww >= 18 and ow >= 18
+        # a waiter gives up at its deadline; its slot comes back once
+        # the owner has answered
+        gate.clear()
+        with pytest.raises(shmring.RingTimeout):
+            client.call(
+                cls="op", cells=np.asarray([1], np.uint64), now_ns=0,
+                owner="slow", deadline_s=0.05,
+            )
+        assert client.in_flight() == 1 and client.stats()["timeouts"] == 1
+        gate.set()
+
+        def swept():
+            try:
+                client._release(client._alloc())
+            except shmring.RingFull:
+                pass
+            return client.in_flight() == 0
+
+        _until(swept)
+    finally:
+        gate.set()
+        client.close()
+        owner.close()
+        r_w.close()
+        r_o.close()
+
+
+def test_reclaimed_slot_frees_its_waiter_under_either_body(
+        tmp_path, wait_body):
+    """FREE means reclaimed, under either body: the waiter takes its
+    slot back and raises RingTimeout long before its deadline."""
+    r = shmring.ShmRegion.create(
+        str(tmp_path / "r.shm"), nworkers=1, depth=2
+    )
+    owner = shmring.ShmOwner(r, _echo)  # never started: nothing scans
+    client = shmring.ShmWorkerClient(r, 0, wait_s=5.0)
+    try:
+        took = _reclaimed_under_a_waiter(
+            r, client, lambda: owner.reclaim_worker(0)
+        )
+        assert took < 1.0  # nowhere near the 5 s bound
+        assert client.stats()["timeouts"] == 1
+        assert owner.stats()["dss_shm_reclaimed_total"] == 1
+    finally:
+        client.close()
+        r.close()
+
+
+@_needs_futex
+def test_idle_owner_round_trip_is_well_under_the_old_scan_cap(tmp_path):
+    """An idle scanner used to sleep at its 2 ms cap and a waiter at up
+    to 1 ms, so pickup + return read ~1.4-1.8 ms a round trip; woken,
+    both are wake-up latencies (~0.3 ms together).  A wake-up is as
+    late as the machine is loaded, so each attempt also reads how late
+    a plain 0.5 ms timer fires, and allows the median that much more;
+    three attempts, and a machine too loaded to time anything skips."""
+    r_o, owner, r_w = _owner_region_pair(tmp_path, _echo)
+    client = shmring.ShmWorkerClient(r_w, 0, wait_s=5.0)
+    try:
+        seen = []
+        for attempt in range(3):
+            waits, lates = [], []
+            for k in range(40):
+                t = time.perf_counter_ns()
+                time.sleep(0.0005)
+                lates.append((time.perf_counter_ns() - t) / 1e6 - 0.5)
+                time.sleep(0.01)  # the scanner is idle, blocked
+                t0 = time.perf_counter_ns()
+                resp = client.call(
+                    cls="op", cells=np.asarray([k], np.uint64), now_ns=0
+                )
+                t1 = time.perf_counter_ns()
+                assert resp.ids == [str(k)]
+                waits.append(
+                    (resp.stamps[0] - t0 + t1 - resp.stamps[2]) / 1e6
+                )
+            wait, late = sorted(waits)[20], sorted(lates)[20]
+            seen.append((round(wait, 3), round(late, 3)))
+            if wait < 1.0 + 2 * late:
+                break
+        else:
+            if min(late for _, late in seen) > 0.3:
+                pytest.skip(f"too loaded to time a wake-up: {seen}")
+            pytest.fail(f"(median wait, timer lateness) ms: {seen}")
+        n = 40 * len(seen)
+        ow, ob, ww, wb = _wake_counts(owner, client)
+        assert (ow + ob, ww + wb) == (n, n)
+        # woken, not found by a backstop
+        assert ow >= n * 0.85 and ww >= n * 0.85
+    finally:
+        client.close()
+        owner.close()
+        r_w.close()
+        r_o.close()
+
+
+@pytest.mark.parametrize("lost", ["doorbell", "response", "both"])
+def test_a_lost_wake_is_survived_by_the_backstop(tmp_path, monkeypatch, lost):
+    """Suppress one side's wake-ups (or both: the polling body, where
+    nobody wakes anybody): every request is still served, within that
+    wait's backstop, and the backstop's counter moves, not the woken
+    one.  The backstops are stretched to 50 ms here and each store
+    waits until its waiter has begun a wait, so whatever the machine's
+    load the store lands inside a wait that only its limit can end."""
+    if lost != "both" and shmring._futex is None:
+        pytest.skip("this platform answered: no futex")
+    r_o, r_w = None, None
+    in_bell_wait, in_slot_wait = threading.Event(), threading.Event()
+    real_wait, real_wake = (
+        (shmring._poll_wait, shmring._poll_wake) if lost == "both"
+        else (shmring._futex_wait, shmring._futex_wake)
+    )
+
+    def is_bell(addr):
+        return addr in (r_o.bell_addr, r_w.bell_addr)
+
+    def wait(addr, expected, limit_s, turn):
+        (in_bell_wait if is_bell(addr) else in_slot_wait).set()
+        return real_wait(addr, expected, limit_s, turn)
+
+    def wake(addr):
+        if is_bell(addr) != (lost == "doorbell"):
+            real_wake(addr)
+
+    def inside(entered):
+        entered.clear()
+        assert entered.wait(2)
+        time.sleep(0.0003)
+
+    def serve(req):
+        inside(in_slot_wait)
+        return _echo(req)
+
+    monkeypatch.setattr(shmring, "_OWNER_BACKSTOP_S", 0.05)
+    monkeypatch.setattr(shmring, "_WORKER_BACKSTOP_S", 0.05)
+    r_o, owner, r_w = _owner_region_pair(tmp_path, serve)
+    client = shmring.ShmWorkerClient(r_w, 0, wait_s=5.0)
+    monkeypatch.setattr(shmring, "_wait_word", wait)
+    monkeypatch.setattr(shmring, "_wake_word", wake)
+    try:
+        n = 8
+        for k in range(n):
+            inside(in_bell_wait)
+            t0 = time.perf_counter_ns()
+            resp = client.call(
+                cls="op", cells=np.asarray([k], np.uint64), now_ns=0
+            )
+            t1 = time.perf_counter_ns()
+            assert resp.status == shmring.ST_OK and resp.ids == [str(k)]
+            # late by a backstop at most, with room for a loaded machine
+            assert (resp.stamps[0] - t0) / 1e6 < 500
+            assert (t1 - resp.stamps[2]) / 1e6 < 500
+        ow, ob, ww, wb = _wake_counts(owner, client)
+        assert (ow + ob, ww + wb) == (n, n)
+        if lost in ("doorbell", "both"):
+            assert ob >= n - 1
+        else:
+            assert ow >= n - 1
+        if lost in ("response", "both"):
+            assert wb >= n - 1
+        else:
+            assert ww >= n - 1
+        assert client.stats()["timeouts"] == 0
+    finally:
+        client.close()
+        owner.close()
+        r_w.close()
+        r_o.close()
+
+
+@_needs_futex
+def test_a_reclaimed_waiter_is_woken_not_found(tmp_path, monkeypatch):
+    """Every FREE the owner stores into a slot it reclaims wakes the
+    slot's waiter: with the backstop out of the way (5 s) only the
+    wake-up can end the wait early."""
+    monkeypatch.setattr(shmring, "_WORKER_BACKSTOP_S", 5.0)
+    r = shmring.ShmRegion.create(
+        str(tmp_path / "r.shm"), nworkers=1, depth=2
+    )
+    owner = shmring.ShmOwner(r, _echo)  # never started: nothing scans
+    client = shmring.ShmWorkerClient(r, 0, wait_s=5.0)
+    try:
+        for reclaim in (lambda: r.free_slot(0, 1),
+                        lambda: owner.reclaim_worker(0)):
+            assert _reclaimed_under_a_waiter(r, client, reclaim) < 1.0
+    finally:
+        client.close()
+        r.close()
+
+
+_HAMMER = """
+import json, sys, threading
+import numpy as np
+from dss_tpu.parallel import shmring
+
+path, worker, threads, calls = sys.argv[1], *map(int, sys.argv[2:5])
+region = shmring.ShmRegion.open_existing(path)
+client = shmring.ShmWorkerClient(region, worker, wait_s=20.0)
+bad = []
+
+def run(t):
+    for k in range(calls):
+        cells = np.asarray([worker, t, k], np.uint64)
+        try:
+            resp = client.call(cls="op", cells=cells, now_ns=0)
+        except Exception as e:
+            bad.append(repr(e))
+            continue
+        if (resp.status != shmring.ST_OK
+                or resp.ids != [str(worker), str(t), str(k)]):
+            bad.append((resp.status, resp.ids, worker, t, k))
+
+ts = [threading.Thread(target=run, args=(t,)) for t in range(threads)]
+[t.start() for t in ts]
+[t.join() for t in ts]
+print(json.dumps({"bad": bad[:5], "n_bad": len(bad), **client.stats()}))
+"""
+
+
+def test_two_worker_processes_hammer_one_owner(tmp_path):
+    """Two worker PROCESSES, three request threads each, 3,000 requests
+    at one owner as fast as they go: none is lost, none times out, none
+    gets another's answer, and every answer's wait is counted once."""
+    threads, calls = 3, 500
+    path = str(tmp_path / "ring.shm")
+    r_o = shmring.ShmRegion.create(path, nworkers=2, depth=8)
+    owner = shmring.ShmOwner(r_o, _echo, threads=4, worker_ttl_s=0)
+    owner.start()
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_ROOT)
+    try:
+        procs = [
+            subprocess.Popen(
+                [sys.executable, "-c", _HAMMER, path, str(w),
+                 str(threads), str(calls)],
+                cwd=_ROOT, env=env, stdout=subprocess.PIPE,
+                stderr=subprocess.PIPE, text=True,
+            )
+            for w in range(2)
+        ]
+        outs = [p.communicate(timeout=150) for p in procs]
+        for p, (out, err) in zip(procs, outs):
+            assert p.returncode == 0, err[-2000:]
+        per = threads * calls
+        for out, _ in outs:
+            got = json.loads(out.strip().splitlines()[-1])
+            assert got["n_bad"] == 0, got["bad"]
+            assert got["enqueued"] == per
+            assert got["timeouts"] == 0 and got["ring_full"] == 0
+            assert got["wakes"] + got["wake_backstops"] == per
+        st = owner.stats()
+        assert st["dss_shm_served_total"] == 2 * per
+        assert st["dss_shm_slots_in_flight"] == 0
+        assert st["dss_shm_reclaimed_total"] == 0
+        assert 1 <= (st["dss_shm_owner_wakes_total"]
+                     + st["dss_shm_owner_wake_backstops_total"]) <= 2 * per
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+        owner.close()
+        r_o.close()
+
+
+def test_ring_wake_pct_reads_counters_a_front_renders(front):
+    """Every gauge that dssbench/metrics/ring_wake_pct.json cites is on
+    /metrics of a front that has served a request, bare or under the
+    `process` label alone (what the benchmark's scrape keys by name),
+    and the ratio it forms reads 100 when every wait was woken."""
+    from dss_tpu.api.app import _GAUGE_VEC_LABELS
+    from dss_tpu.obs.metrics import MetricsRegistry
+
+    with open(os.path.join(
+            _ROOT, "dssbench", "metrics", "ring_wake_pct.json")) as fh:
+        spec = json.load(fh)
+    assert spec["reader"] == "scrape_ratio" and spec["args"]["scale"] == 100
+    num, den = spec["args"]["num"], spec["args"]["den"]
+    assert set(num) < set(den)
+
+    h = front
+    cells = _cells(1000, 1008)
+    h.leader.rid.insert_isa(_isa(1, cells))
+    h.sync()
+    got = h.rid.search_isas(cells, T0 + timedelta(minutes=5), None)
+    assert len(got) == 1  # through the ring: nothing was cached yet
+    reg = MetricsRegistry(proc="worker-0:1")
+    for name, val in h.front.stats().items():  # api/app.py metrics_handler
+        if isinstance(val, dict):
+            reg.set_gauge_vec(
+                name, _GAUGE_VEC_LABELS.get(name, "shard"), val
+            )
+        else:
+            reg.set_gauge(name, val)
+    text = reg.render()
+    read = {}
+    for name in den:
+        m = re.findall(
+            rf'^{name}(?:\{{process="[^"]*"\}})? ([0-9.e+-]+)$', text, re.M
+        )
+        assert m, name
+        read[name] = float(m[-1])
+        assert name in shmring.empty_stats() or name.startswith(
+            "dss_shm_worker_")
+    assert sum(read.values()) >= 2  # the owner's scan, the worker's wait
+    if shmring._futex is not None:
+        assert sum(read[k] for k in num) >= sum(read.values()) - 1
 
 
 # ---------------------------------------------------------------------------
