@@ -2839,7 +2839,7 @@ def autotune_leg(emit: bool = True, smoke: bool = False):
     width = 8
     table = build_table(n_ent, n_cel, 8, seed=3)
     try:
-        ft = table._state.snap.fast
+        ft = table._state.tiers[0].snap.fast
         # prewarm every executable BOTH passes can touch: the compare
         # isolates seed quality, not compile luck (compile caches are
         # process-wide, so whichever pass ran first would otherwise
@@ -4425,10 +4425,8 @@ def _stage_attribution(h0: dict, h1: dict) -> dict:
     bucket (routes merged — the table answers 'which STAGE owns the
     tail').  The BENCH_r07 hand-rolled per-process CPU breakdown,
     generalized: measured stage tails, from the serving stack itself.
-    The interpolation itself lives in obs/metrics.stage_hist_quantile —
-    shared with the tune observer's fitter, so the p99 this table
-    prints and the floor the tuner fits can never disagree about what
-    a histogram says."""
+    The interpolation itself lives in
+    obs/metrics.stage_hist_quantile."""
     from dss_tpu.obs.metrics import stage_hist_quantile
 
     by_stage: dict = {}
@@ -4842,385 +4840,6 @@ def http_curve_leg() -> int:
     return 0 if errs == 0 else 1
 
 
-# ------------------------------------------------------------------------------
-# self-tuning serving (`--leg tune` / `--leg tune-smoke`, dss_tpu/tune)
-#
-# Closed deterministic loop over the REAL control stack: the real
-# Planner + CostModel, the real DecisionRecorder fed through
-# plan.set_decision_hook, the real Observer/proposer/shadow/guard in a
-# real TuneController — only the served latency comes from a fixed
-# true-cost table instead of a live accelerator, because a CI host
-# cannot hold real device/host cost ratios still enough to gate on.
-# The scenario is the one the tuner exists for (see the winsorization
-# note in plan/costs.py): a boot profile whose device floor is
-# poisoned HIGH is self-sealing — the planner never takes the device
-# route, so the EWMAs never see a device sample and never correct it.
-# The frozen server serves the second-best route forever; the tuner's
-# guard-bounded probes walk the poisoned floor down until the route
-# flips and measured p99 proves it.
-
-
-class _TuneWorld:
-    """One simulated serving surface: real planner/cost/controller,
-    deterministic true route costs, stage-histogram accounting in the
-    exact MetricsRegistry snapshot shape."""
-
-    def __init__(self, *, boot_floor_ms, true_floor_ms,
-                 item_ms=0.002, chunk_ms=0.2, headroom_ms=16.0,
-                 feed_ewma=True):
-        from dss_tpu.obs.metrics import STAGE_BUCKETS
-        from dss_tpu.plan import Planner
-
-        self.planner = Planner(
-            floor_ms=boot_floor_ms, item_ms=item_ms,
-            chunk_ms=chunk_ms, chunk=64,
-        )
-        self.cost = self.planner.cost
-        # feed_ewma=False pins the live estimators: the worst case the
-        # guard window exists for — a wrong knob whose route the EWMAs
-        # either never observe or cannot attribute (the shadow-neutral
-        # geometry knobs in production)
-        self.feed_ewma = bool(feed_ewma)
-        self.true_floor_ms = float(true_floor_ms)
-        self.true_item_ms = float(item_ms)
-        self.true_chunk_ms = float(chunk_ms)
-        self.headroom_ms = float(headroom_ms)
-        self.buckets = STAGE_BUCKETS
-        self._row = [0] * (len(STAGE_BUCKETS) + 2)
-        self.clock = 0.0  # the controller's fake monotonic time
-
-    def true_ms(self, route: str, n: int) -> float:
-        if route in ("device", "resident", "mesh"):
-            return self.true_floor_ms + self.true_item_ms * n
-        if route == "inline":
-            return 0.05
-        return (
-            -(-n // 64) * self.true_chunk_ms  # ceil chunks
-        )
-
-    def serve(self, n: int):
-        """One batch through the real plan() (recorded by the tuner's
-        hook when one is installed), served at its route's true cost;
-        the cost model observes exactly what a live coalescer would."""
-        from dss_tpu.plan import BatchShape
-
-        state = self.planner.capture(device_ok=True)
-        plan = self.planner.plan(
-            BatchShape(n=n, all_stale=True), state, self.headroom_ms
-        )
-        ms = self.true_ms(plan.route, n)
-        if self.feed_ewma:
-            if plan.route == "device":
-                self.cost.observe_device(n, ms)
-            elif plan.route == "hostchunk":
-                self.cost.observe_host(n, ms)
-        s = ms / 1000.0
-        for i, b in enumerate(self.buckets):
-            if s <= b:
-                self._row[i] += 1
-        self._row[-2] += s
-        self._row[-1] += 1
-        return plan.route, ms
-
-    def window(self, sizes):
-        """Serve one observe window; returns (p99_ms, route mix)."""
-        lats, mix = [], {}
-        for n in sizes:
-            route, ms = self.serve(n)
-            lats.append(ms)
-            mix[route] = mix.get(route, 0) + 1
-        lats.sort()
-        p99 = lats[min(len(lats) - 1, int(0.99 * len(lats)))]
-        return p99, mix
-
-    # -- TuneController seams ----------------------------------------------
-
-    def hist_provider(self):
-        return {
-            ("search", "store_ms"): (
-                tuple(self._row[:-2]), self._row[-2], self._row[-1],
-            )
-        }
-
-    def current_knobs(self):
-        return {
-            "DSS_CO_EST_FLOOR_MS": self.cost.est_floor_ms,
-            "DSS_CO_EST_ITEM_MS": self.cost.est_item_ms,
-            "DSS_CO_EST_CHUNK_MS": self.cost.est_chunk_ms,
-            "DSS_CO_EST_RES_FLOOR_MS": self.cost.est_res_floor_ms,
-            "DSS_CO_EST_RES_LAT_MS": self.cost.est_res_lat_ms,
-        }
-
-    def actuate(self, knobs):
-        """The coalescer configure() seam, reduced to its reseed half
-        (no resident loop in this world)."""
-        kw = {}
-        for k, v in knobs.items():
-            kw[{
-                "DSS_CO_EST_FLOOR_MS": "floor_ms",
-                "DSS_CO_EST_ITEM_MS": "item_ms",
-                "DSS_CO_EST_CHUNK_MS": "chunk_ms",
-                "DSS_CO_EST_RES_FLOOR_MS": "res_floor_ms",
-                "DSS_CO_EST_RES_LAT_MS": "res_lat_ms",
-            }[k]] = v
-        self.cost.reseed(**kw)
-
-    def controller(self, **over):
-        from dss_tpu.tune import TuneController
-
-        kw = dict(
-            hist_provider=self.hist_provider,
-            actuator=self.actuate,
-            current_fn=self.current_knobs,
-            interval_s=30.0, guard_s=30.0, min_count=100,
-            deadband=0.25, p99_tol=0.10, rollback_frac=1.25,
-            ring=512, clock=lambda: self.clock,
-        )
-        kw.update(over)
-        return TuneController(**kw)
-
-
-def _tune_sizes(window_idx: int, batches: int, flipped: bool):
-    """Deterministic workload: small coalesced batches pre-flip, a
-    bulk-drain regime (3-5k items) post-flip — the flip that drags
-    the poisoned device floor into the routing decision."""
-    lo, hi = ((3072, 5120) if flipped else (64, 256))
-    span = hi - lo
-    return [
-        lo + ((window_idx * 7919 + i * 523) % (span + 1))
-        for i in range(batches)
-    ]
-
-
-def tune_leg() -> int:
-    """`bench.py --leg tune`: self-tuned vs frozen boot-profile
-    serving across a deterministic workload flip, emitting
-    TUNE_r01.json.  Both arms boot from the same poisoned profile
-    (device floor 20 ms vs a true 2 ms) and serve the identical
-    batch stream; the tuned arm runs the TuneController between
-    windows (fake clock — every observe window is one interval).
-    Exit nonzero unless the tuned arm's steady-state post-flip p99
-    measurably beats the frozen arm's."""
-    BOOT, TRUE = 20.0, 2.0
-    WARM_W, POST_W, BATCHES = 2, 16, 150
-    STEADY = 5  # last N post-flip windows = steady state
-
-    def run_arm(tuned: bool):
-        world = _TuneWorld(boot_floor_ms=BOOT, true_floor_ms=TRUE)
-        ctl = None
-        if tuned:
-            ctl = world.controller()
-            ctl.start(thread=False)
-        timeline = []
-        for w in range(WARM_W + POST_W):
-            flipped = w >= WARM_W
-            p99, mix = world.window(
-                _tune_sizes(w, BATCHES, flipped)
-            )
-            event = None
-            if ctl is not None:
-                world.clock += 30.0
-                event = ctl.tick()
-            timeline.append({
-                "window": w,
-                "flipped": flipped,
-                "p99_ms": round(p99, 3),
-                "route_mix": mix,
-                "est_floor_ms": round(world.cost.est_floor_ms, 3),
-                "tune_event": None if event is None
-                else event.get("event"),
-            })
-        if ctl is not None:
-            stats = ctl.stats()
-            ctl.close()
-        else:
-            stats = None
-        steady = [t["p99_ms"] for t in timeline[-STEADY:]]
-        return {
-            "timeline": timeline,
-            "steady_p99_ms": round(
-                sorted(steady)[len(steady) // 2], 3
-            ),
-            "tune_stats": stats,
-        }
-
-    frozen = run_arm(tuned=False)
-    tuned = run_arm(tuned=True)
-    win = tuned["steady_p99_ms"] < 0.95 * frozen["steady_p99_ms"]
-    result = {
-        "bench": "TUNE_r01",
-        "boot_floor_ms": BOOT,
-        "true_floor_ms": TRUE,
-        "frozen": frozen,
-        "tuned": tuned,
-        "steady_p99_frozen_ms": frozen["steady_p99_ms"],
-        "steady_p99_tuned_ms": tuned["steady_p99_ms"],
-        "tuned_wins": win,
-        "note": (
-            "closed deterministic loop over the real planner/cost/"
-            "recorder/shadow/guard stack; served latency from a fixed"
-            " true-cost table (see bench.py _TuneWorld).  The boot"
-            " profile's poisoned-high device floor is self-sealing"
-            " for the frozen arm (the route is never taken, so the"
-            " EWMA never corrects it); the tuned arm's guard-bounded"
-            " probes walk the floor down until the route flips"
-        ),
-    }
-    out = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "TUNE_r01.json"
-    )
-    with open(out, "w", encoding="utf-8") as fh:
-        json.dump(result, fh, indent=1)
-    print(json.dumps({
-        k: result[k] for k in (
-            "bench", "steady_p99_frozen_ms", "steady_p99_tuned_ms",
-            "tuned_wins",
-        )
-    }))
-    return 0 if win else 1
-
-
-def tune_smoke_leg() -> int:
-    """`bench.py --leg tune-smoke` (CI job tune-smoke): deterministic
-    drill chain — workload flip converges to >=1 accepted+committed
-    proposal; a seeded FaultPlan at tune.apply crashes an apply
-    mid-swap (reverted, nothing half-applied); a deliberately bad
-    est proposal is SHADOW-rejected; a plausible-but-wrong proposal
-    passes shadow, regresses the guard window's measured p99, and is
-    guard-rolled-back with p99 recovering and every knob back at its
-    pre-injection value.  Nonzero exit on any miss."""
-    from dss_tpu import chaos
-
-    failures = []
-
-    def check(name, ok, detail=""):
-        print(f"  {'ok ' if ok else 'FAIL'} {name} {detail}")
-        if not ok:
-            failures.append(name)
-
-    # -- phase A: flip -> accepted proposal --------------------------------
-    world = _TuneWorld(boot_floor_ms=20.0, true_floor_ms=2.0)
-    ctl = world.controller()
-    ctl.start(thread=False)
-    committed = 0
-    for w in range(14):
-        world.window(_tune_sizes(w, 150, flipped=w >= 2))
-        world.clock += 30.0
-        ev = ctl.tick()
-        if ev.get("event") == "committed":
-            committed += 1
-    check(
-        "flip_accepted_proposal",
-        ctl.applied >= 1 and committed >= 1,
-        f"applied={ctl.applied} committed={committed}",
-    )
-    final_route = world.window(_tune_sizes(99, 50, True))[1]
-    check(
-        "route_flipped_to_device",
-        final_route.get("device", 0) == 50,
-        f"mix={final_route}",
-    )
-    ctl.close()
-
-    # -- phases B-D run in a world where the device is TRULY slow: the
-    # boot floor (40 ms) is honest, so every injected "improvement" is
-    # a lie the safety machinery must catch.  The floor knob is
-    # operator-pinned via the controller's env so no organic probe
-    # moves it between drills, and the EWMAs are pinned (feed_ewma
-    # off): the drill targets the case the guard window exists for — a
-    # lie the live estimators cannot observe-correct.
-    world = _TuneWorld(
-        boot_floor_ms=40.0, true_floor_ms=40.0, feed_ewma=False,
-    )
-    ctl = world.controller(env={"DSS_CO_EST_FLOOR_MS": "40.0"})
-    ctl.start(thread=False)
-    boot_knobs = dict(world.current_knobs())
-    baseline_p99, _ = world.window(_tune_sizes(0, 150, True))
-    world.clock += 30.0
-    ev = ctl.tick()  # baseline window: no proposal, p99 recorded
-    check(
-        "pinned_env_blocks_organic_proposals",
-        ev.get("event") == "no_proposal", str(ev.get("event")),
-    )
-
-    # -- phase B: seeded FaultPlan crashes the apply mid-swap --------------
-    chaos.install_plan(chaos.FaultPlan(
-        [chaos.FaultEvent("tune.apply", "error", count=1)], seed=11,
-    ))
-    ev = ctl.inject(
-        {"DSS_CO_EST_FLOOR_MS": 3.0}, reason="smoke: faulted apply"
-    )
-    chaos.clear_plan()
-    check(
-        "faulted_apply_reverted",
-        ev.get("event") == "apply_failed"
-        and ctl.apply_failed == 1
-        and world.current_knobs() == boot_knobs,
-        f"event={ev.get('event')} knobs_restored="
-        f"{world.current_knobs() == boot_knobs}",
-    )
-
-    # -- phase C: deliberately bad est proposal -> shadow-rejected ---------
-    ev = ctl.inject(
-        {"DSS_CO_EST_CHUNK_MS": 5.0}, reason="smoke: bad est knob"
-    )
-    check(
-        "bad_est_shadow_rejected",
-        ev.get("event") == "shadow_rejected"
-        and ctl.shadow_rejected == 1
-        and world.current_knobs() == boot_knobs,
-        str(ev.get("shadow", ""))[:80],
-    )
-
-    # -- phase D: plausible lie -> guard-window rollback -------------------
-    ev = ctl.inject(
-        {"DSS_CO_EST_FLOOR_MS": 3.0},
-        reason="smoke: optimistic floor (true device cost 40 ms)",
-    )
-    check("lie_passed_shadow", ev.get("event") == "applied",
-          str(ev.get("event")))
-    bad_p99, bad_mix = world.window(_tune_sizes(1, 150, True))
-    world.clock += 30.0
-    ev = ctl.tick()
-    check(
-        "guard_rolled_back",
-        ev.get("event") == "rollback"
-        and ev.get("reason") == "p99_regression"
-        and ctl.rollbacks == 1,
-        f"event={ev.get('event')} guard_p99="
-        f"{ev.get('guard_p99_ms')}",
-    )
-    check(
-        "knobs_back_at_boot",
-        world.current_knobs() == boot_knobs,
-        str(world.current_knobs()),
-    )
-    rec_p99, _ = world.window(_tune_sizes(2, 150, True))
-    check(
-        "p99_recovered",
-        bad_p99 > 1.25 * baseline_p99
-        and rec_p99 <= 1.05 * baseline_p99,
-        f"baseline={baseline_p99} bad={round(bad_p99, 2)} "
-        f"recovered={round(rec_p99, 2)}",
-    )
-    stats = ctl.stats()
-    check(
-        "stats_counters",
-        stats["dss_tune_proposals_total"] >= 3
-        and stats["dss_tune_rollbacks_total"] == 1
-        and stats["dss_tune_shadow_rejected_total"] == 1
-        and stats["dss_tune_apply_failed_total"] == 1,
-        str({k: v for k, v in stats.items()
-             if isinstance(v, int) and v}),
-    )
-    ctl.close()
-    print(json.dumps({
-        "bench": "tune-smoke",
-        "failures": failures,
-    }))
-    return 0 if not failures else 1
-
-
 def main():
     import argparse
 
@@ -5232,8 +4851,7 @@ def main():
                  "skew-smoke", "autotune", "autotune-smoke",
                  "chaos", "chaos-smoke", "scenario", "scenario-smoke",
                  "http-curve", "federation", "shm-smoke",
-                 "trace-smoke", "fanout-push", "fanout-smoke",
-                 "tune", "tune-smoke"],
+                 "trace-smoke", "fanout-push", "fanout-smoke"],
         default="north-star",
         help="'north-star': the headline SCD conflict-qps benchmark "
         "(default); 'workers': multi-worker HTTP serving scaling smoke "
@@ -5300,17 +4918,7 @@ def main():
         "delivery-worker SIGKILL drill over a real child process "
         "proving zero acked-notification loss + at-least-once "
         "redelivery, and queue saturation flipping PUSH_DEGRADED "
-        "then recovering HEALTHY; 'tune': self-tuned vs frozen "
-        "boot-profile serving across a deterministic workload flip "
-        "(the poisoned-device-floor trap the EWMAs cannot escape), "
-        "emitting TUNE_r01.json — nonzero exit unless the tuned "
-        "arm's steady-state post-flip p99 beats the frozen arm's; "
-        "'tune-smoke': deterministic tuner CI drill — flip converges "
-        "to an accepted+committed proposal, a seeded FaultPlan at "
-        "tune.apply crashes an apply mid-swap (reverted), a bad est "
-        "knob is shadow-rejected, and a plausible lie is guard-"
-        "rolled-back with p99 recovering and knobs back at boot "
-        "values",
+        "then recovering HEALTHY",
     )
     args = ap.parse_args()
     if args.leg == "workers":
@@ -5352,10 +4960,6 @@ def main():
         return fanout_push_leg()
     if args.leg == "fanout-smoke":
         return fanout_smoke_leg()
-    if args.leg == "tune":
-        return tune_leg()
-    if args.leg == "tune-smoke":
-        return tune_smoke_leg()
 
     n_entities = int(os.environ.get("DSS_BENCH_ENTITIES", 1_000_000))
     n_cells = int(os.environ.get("DSS_BENCH_CELLS", 200_000))
@@ -5368,7 +4972,7 @@ def main():
     do_serving = os.environ.get("DSS_BENCH_SERVING", "1") != "0"
 
     table = build_table(n_entities, n_cells, kpe)
-    ft = table._state.snap.fast
+    ft = table._state.tiers[0].snap.fast
     # what the server does after boot (cmds/server.py): park the
     # built table outside gen2 GC scans — the 1M-record heap otherwise
     # costs ~8 ms of stall per full collection

@@ -49,7 +49,7 @@ def main():
     from dss_tpu.dar.wal import WriteAheadLog
     from dss_tpu.models import scd as scdm
     from dss_tpu.parallel import make_mesh
-    from dss_tpu.parallel.replica import ShardedOpReplica
+    from dss_tpu.parallel.replica import ShardedReplica
 
     rng = np.random.default_rng(0)
     now_dt = datetime.now(timezone.utc)
@@ -110,7 +110,7 @@ def main():
     wal_write_s = time.perf_counter() - t_build0
 
     mesh = make_mesh(dp * sp, dp=dp, sp=sp)
-    rep = ShardedOpReplica(mesh, wal_path=wal_path)
+    rep = ShardedReplica(mesh, wal_path=wal_path)
     t0 = time.perf_counter()
     applied = rep.poll_once()
     ingest_s = time.perf_counter() - t0
@@ -165,7 +165,7 @@ def main():
             "batch": batch,
             "reps": reps,
             "hits_per_query": round(hits / (batch * reps), 1),
-            "path": "WAL tail -> ShardedOpReplica -> shard_map query",
+            "path": "WAL tail -> ShardedReplica -> shard_map query",
         },
     )
 

@@ -15,7 +15,7 @@ Layer map (outside in):
                 notification fanout, quotas)
     dar/        storage: repository seam, in-memory store, TPU-backed store
                 (host-authoritative WAL + device DAR snapshot)
-    ops/        JAX/Pallas conflict-query kernels
+    ops/        JAX conflict-query kernels
     parallel/   multi-chip DAR sharding (Mesh/shard_map, ICI collectives)
     geo/        S2 cell geometry (level-13 coverings)
     models/     shared value types (ID, Owner, Version, OVN, Volume4D)

@@ -424,11 +424,6 @@ _GAUGE_VEC_LABELS = {
     "dss_fed_peer_state": "region",
     "dss_fed_mirror_lag_s": "region",
     "dss_push_breaker_state": "uss",
-    # self-tuning knob families (dss_tpu/tune): active vs proposed
-    # values per hot-swappable knob — the Grafana tuner panel diffs
-    # the two series
-    "dss_tune_knob_active": "knob",
-    "dss_tune_knob_proposed": "knob",
     # shared-memory front per-worker counters (parallel/shmring.py):
     # the leader aggregates every worker's shm stats block so ONE
     # scrape sees the whole front, keyed by the worker's process id
@@ -636,7 +631,7 @@ def build_app(
     status_fn=None,  # freshness introspection: DSSStore.freshness_status
     health_fn=None,  # degradation mode: DSSStore.health.mode_name
     default_timeout_s: float = 10.0,
-    replica=None,  # ShardedOpReplica: multi-chip read-replica surface
+    replica=None,  # ShardedReplica: multi-chip read-replica surface
     federation=None,  # FederationRouter: peer query/sync surface
     push=None,  # PushPipeline: webhook registry + ingest surface
     trace_requests: bool = False,

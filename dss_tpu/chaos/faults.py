@@ -55,10 +55,6 @@ Registered sites (grep for the literal to find the seam):
   region.federation.sync          region/federation.py (mirror refresh)
   push.match                      push/match.py (reverse-query batch)
   push.deliver                    push/deliver.py (webhook attempt)
-  tune.apply                      tune/controller.py (knob hot-swap;
-                                  the mid-swap crash drill — the
-                                  controller must revert, never leave
-                                  half a proposal live)
 """
 
 from __future__ import annotations

@@ -101,21 +101,6 @@ def make_parser() -> argparse.ArgumentParser:
         "DSS_AUTOTUNE_PROFILE",
     )
     p.add_argument(
-        "--self_tune",
-        action="store_true",
-        default=os.environ.get("DSS_TUNE", "0").lower()
-        in ("1", "true", "yes", "on"),
-        help="arm the self-tuning controller (dss_tpu/tune): fit "
-        "cost-model knobs from the live stage histograms, shadow-"
-        "evaluate every proposal against the recorded decision "
-        "trace, hot-swap accepted knobs through configure_serving, "
-        "and roll back automatically if the guard window's measured "
-        "p99 regresses.  Knob precedence: operator env > boot "
-        "profile > tuner (profile-seeded keys stay tunable, "
-        "explicit env keys are never touched).  DSS_TUNE_* knobs in "
-        "docs/OPERATIONS.md.  Env fallback DSS_TUNE",
-    )
-    p.add_argument(
         "--region_url",
         default="",
         help="region log server URL(s), comma-separated primary + "
@@ -851,52 +836,13 @@ def build(args) -> web.Application:
     app["dss_store"] = store
     app["dss_metrics"] = metrics
 
-    # autotune profile provenance (satellite of the self-tuning loop):
-    # stable gauge whether or not a profile was loaded — 0.0 means
-    # "no profile or no timestamp", the alertable case is large
+    # autotune profile provenance: stable gauge whether or not a
+    # profile was loaded — 0.0 means "no profile or no timestamp",
+    # the alertable case is large
     metrics.set_gauge(
         "dss_autotune_profile_age_s",
         float(getattr(args, "_autotune_profile_age_s", 0.0)),
     )
-
-    tune_cfg = None
-    if args.self_tune:
-        from dss_tpu import tune as _tune
-
-        tune_cfg = _tune.env_knobs()
-
-        def _tune_actuator(kn, _store=store):
-            _store.configure_serving(**{
-                _tune.KNOB_TO_CONFIGURE[k]: v for k, v in kn.items()
-            })
-
-        controller = _tune.TuneController(
-            # late-binds the shm whole-front aggregate: main() wires
-            # set_stage_agg after the listen sockets exist
-            hist_provider=metrics.stage_hist_front,
-            actuator=_tune_actuator,
-            current_fn=store.tune_knob_values,
-            interval_s=tune_cfg["interval_s"],
-            guard_s=tune_cfg["guard_s"],
-            min_count=tune_cfg["min_count"],
-            deadband=tune_cfg["deadband"],
-            p99_tol=tune_cfg["p99_tol"],
-            rollback_frac=tune_cfg["rollback_frac"],
-            ring=tune_cfg["ring"],
-            profile_seeded=getattr(
-                args, "_autotune_profile_seeded", ()
-            ),
-        )
-        store.attach_tuner(controller)
-        log.info(
-            "self-tuning armed: interval %.0fs, guard %.0fs, "
-            "min_count %d, deadband %.0f%%, rollback at %.2fx p99 "
-            "(DSS_TUNE_* knobs in OPERATIONS.md; freeze with "
-            "store.tune.freeze() or a DSS_TUNE=0 restart)",
-            tune_cfg["interval_s"], tune_cfg["guard_s"],
-            tune_cfg["min_count"], 100.0 * tune_cfg["deadband"],
-            tune_cfg["rollback_frac"],
-        )
 
     from dss_tpu.obs import trace as _trace
 
@@ -1041,8 +987,7 @@ def main():
                 "AUTOTUNE PROFILE HOST-CLASS MISMATCH: profile "
                 "measured on %r, this host is %r — the seeded cost "
                 "models describe a DIFFERENT machine; re-run "
-                "`bench.py --leg autotune` here (or arm --self_tune "
-                "to converge live)",
+                "`bench.py --leg autotune` here",
                 stale["profile_host_class"], stale["host_class"],
             )
         if not stale["has_timestamp"]:
@@ -1059,11 +1004,8 @@ def main():
                 "re-run `bench.py --leg autotune`",
                 args.autotune_profile, stale["age_s"] / 86400.0,
             )
-        # build() exports age as dss_autotune_profile_age_s and hands
-        # the seeded key set to the tuner (env > profile > tuner:
-        # profile-seeded env keys stay proposable)
+        # build() exports the age as dss_autotune_profile_age_s
         args._autotune_profile_age_s = stale["age_s"]
-        args._autotune_profile_seeded = tuple(sorted(applied))
 
     from dss_tpu.cmds import make_ssl_context
 

@@ -107,14 +107,10 @@ from dss_tpu.obs import trace as _trace
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
 from dss_tpu.plan import (
     HEADROOM_SAFETY as _PLAN_HEADROOM_SAFETY,
-)
-from dss_tpu.plan import (
     BatchShape,
     CostModel,
     Planner,
-    plan_drain_cap,
 )
-from dss_tpu.plan.planner import state_of as _plan_state_of
 
 
 class _Item:
@@ -205,26 +201,6 @@ class _BatchController:
         ):
             self.cur = min(self.max_batch, self.cur * 2)
             self.grows += 1
-
-    def drain_cap(
-        self, headroom_ms: Optional[float], cost: _CostModel,
-        inflight: int, inflight_host_chunks: int = 0,
-        resident_ready: bool = False, inflight_resident: int = 0,
-    ) -> int:
-        """Deadline-aware drain bound — the logic lives in
-        plan.plan_drain_cap (one HEADROOM_SAFETY budget shared with
-        the route choice, so the drain sizing and the plan can never
-        disagree); this shim keeps the controller's historical call
-        shape for callers that hold a bare cost model (the coalescer
-        itself goes through its planner in _drain_locked)."""
-        state = _plan_state_of(
-            cost,
-            inflight_device=int(inflight),
-            inflight_host_chunks=int(inflight_host_chunks),
-            inflight_resident=int(inflight_resident),
-            resident_ready=bool(resident_ready),
-        )
-        return plan_drain_cap(self.cur, headroom_ms, state)
 
 
 def _env_bool(v: str) -> bool:
@@ -575,14 +551,14 @@ class QueryCoalescer:
         res_ring: Optional[int] = None,
         res_inflight: Optional[int] = None,
     ) -> None:
-        """Adjust serving knobs at runtime (ops endpoint / tests / the
-        tune actuator).  Pipeline depth is fixed at construction (the
+        """Adjust serving knobs at runtime (ops endpoint / tests).
+        Pipeline depth is fixed at construction (the
         double buffer).  resident=True attaches the resident loop
         (idempotent); resident=False detaches it for NEW batches (the
         loop drains what it holds — in-flight callers still resolve).
-        The est_* knobs reseed the live CostModel (CostModel.reseed —
-        the tuner's hot-swap path; winsorization would otherwise make
-        a post-flip correction crawl); res_ring/res_inflight resize
+        The est_* knobs reseed the live CostModel (CostModel.reseed:
+        winsorization would otherwise make a post-flip correction
+        crawl); res_ring/res_inflight resize
         the resident loop by detach+reattach when one is running
         (in-flight batches drain first, same contract as resident
         toggling)."""
@@ -976,31 +952,6 @@ class QueryCoalescer:
         pinned decision-identical to the pre-planner router."""
         return self._planner.plan(
             self._shape_of(batch), self._capture_state(), headroom_ms,
-        )
-
-    def _choose_route(self, batch, headroom_ms,
-                      allow_resident: bool = True) -> str:
-        """Route-string view of the planner decision (the pre-planner
-        router's contract, kept for the routing tests): never returns
-        "mesh" — the mesh candidate was historically decided before
-        this comparison and still is (_plan_batch handles it)."""
-        return self._planner.plan(
-            self._shape_of(batch), self._capture_state(), headroom_ms,
-            allow_resident=allow_resident, allow_mesh=False,
-            record=False,
-        ).route
-
-    def _choose_host_route(self, batch, headroom_ms) -> bool:
-        """Boolean view of _choose_route for consumers that CANNOT
-        ride the resident loop (the inline lone-caller path and the
-        mesh fallback run synchronously on the caller's thread).  The
-        resident candidate is excluded from the comparison: a batch
-        cleared only because the stream's latency fits would otherwise
-        be run as a COLD dispatch here and blow the very deadline the
-        clearance assumed."""
-        return (
-            self._choose_route(batch, headroom_ms, allow_resident=False)
-            == "hostchunk"
         )
 
     def _pack_loop(self):

@@ -1558,10 +1558,6 @@ class DSSStore:
         # shared-memory serving front (parallel/shmring.py): None
         # until attach_shm_front makes this process the device owner
         self._shm_owner = None
-        # self-tuning controller (tune/controller.py): None until
-        # attach_tuner; stats() exports the stable dss_tune_* key set
-        # either way (DSS_TUNE=0 builds nothing, installs no hook)
-        self.tune = None
         self._replaying = False
         if region_url:
             self.region = RegionCoordinator(
@@ -1820,37 +1816,6 @@ class DSSStore:
         getattr(self.scd, "_local", self.scd).set_push(pipeline)
         self.push = pipeline
 
-    def attach_tuner(self, controller) -> None:
-        """Arm the self-tuning controller (tune/controller.py): record
-        boot knob values (the rollback floor), install the planner
-        decision-recorder hook, and start the observe/propose/shadow/
-        guard loop.  Exactly one tuner per store — the recorder hook is
-        a process-global seam."""
-        if self.tune is not None:
-            raise RuntimeError("tuner already attached")
-        controller.start()
-        self.tune = controller
-
-    def tune_knob_values(self) -> dict:
-        """Live values of every hot-swappable knob (tune.HOT_KNOBS),
-        read off one representative coalescer's cost model + resident
-        geometry — the tuner's current_fn, and the 'active' side of
-        the Grafana knob panel.  {} on the memory backend (no
-        coalescers: the tuner observes but can never propose)."""
-        co = getattr(self.rid._isa_index, "coalescer", None)
-        if co is None:
-            return {}
-        cost = co._planner.cost
-        return {
-            "DSS_CO_EST_FLOOR_MS": float(cost.est_floor_ms),
-            "DSS_CO_EST_ITEM_MS": float(cost.est_item_ms),
-            "DSS_CO_EST_CHUNK_MS": float(cost.est_chunk_ms),
-            "DSS_CO_EST_RES_FLOOR_MS": float(cost.est_res_floor_ms),
-            "DSS_CO_EST_RES_LAT_MS": float(cost.est_res_lat_ms),
-            "DSS_CO_RES_INFLIGHT": float(co._res_inflight),
-            "DSS_CO_RES_RING": float(co._res_ring),
-        }
-
     def attach_mesh_replica(self, replica, min_batch: int = 64) -> None:
         """Route oversized bounded-staleness search batches from each
         entity class's coalescer to the multi-chip replica when it is
@@ -1896,10 +1861,6 @@ class DSSStore:
             use_load(self.range_load)
 
     def close(self):
-        # tuner first: clears the planner decision hook and stops the
-        # loop before the coalescers it actuates start tearing down
-        if self.tune is not None:
-            self.tune.close()
         if self.push is not None:
             self.push.close()
         if self._shm_owner is not None:
@@ -1978,15 +1939,6 @@ class DSSStore:
             out.update(self.push.stats())
         else:
             out.update(_pushmod.empty_stats())
-        # self-tuning gauges: stable key set whether or not a tuner is
-        # attached (dss_tune_knob_active/_proposed render as labeled
-        # families keyed by knob)
-        from dss_tpu import tune as _tunemod
-
-        if self.tune is not None:
-            out.update(self.tune.stats())
-        else:
-            out.update(_tunemod.empty_stats())
         # trace recorder gauges (obs/trace.py): sampling config, kept/
         # dropped counters, ring depth, and the allocation counter the
         # zero-cost-when-disabled contract is asserted against

@@ -61,15 +61,10 @@ from dss_tpu.dar import budget
 from dss_tpu.dar import tiers as tiersmod
 from dss_tpu.dar.oracle import Record
 from dss_tpu.dar.pack import pow2_at_least
-from dss_tpu.dar.tiers import EMPTY_SNAPSHOT, Tier, TierSnapshot
+from dss_tpu.dar.tiers import Tier, TierSnapshot
 from dss_tpu.obs import trace as _trace
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
 from dss_tpu.ops import fastpath
-
-# back-compat aliases: the single-snapshot type moved to dar.tiers when
-# it became the per-tier building block
-_Snapshot = TierSnapshot
-_EMPTY_SNAPSHOT = EMPTY_SNAPSHOT
 
 
 class _Overlay(NamedTuple):
@@ -91,16 +86,6 @@ class _State(NamedTuple):
     tiers: "tuple[Tier, ...]"  # oldest (L0) first; () before any fold
     pending: Dict[str, Record]  # overlay source records (immutable)
     overlay: Optional[_Overlay]  # packed form of pending (None if empty)
-
-    # back-compat views (bench.py / __graft_entry__ grab the base
-    # FastTable through these)
-    @property
-    def snap(self) -> TierSnapshot:
-        return self.tiers[0].snap if self.tiers else EMPTY_SNAPSHOT
-
-    @property
-    def dead(self) -> frozenset:
-        return self.tiers[0].dead if self.tiers else frozenset()
 
 
 _EMPTY_STATE = _State((), {}, None)
@@ -681,7 +666,7 @@ class DarTable:
                 self._fold_removed = []
 
     @staticmethod
-    def _build_snapshot(live: List[Record]) -> _Snapshot:
+    def _build_snapshot(live: List[Record]) -> TierSnapshot:
         return tiersmod.build_snapshot(live)
 
     def _rebuild_locked(self):

@@ -31,7 +31,6 @@ from dss_tpu.native import _buildlib
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = [os.path.join(_DIR, n) for n in _buildlib.SOURCE_NAMES]
-_SRC = _SOURCES[0]  # kept for back-compat references
 _SO = os.path.join(_DIR, _buildlib.SO_NAME)
 
 _load_lock = threading.Lock()   # guards _lib / _load_failed + dlopen

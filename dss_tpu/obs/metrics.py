@@ -216,27 +216,12 @@ class MetricsRegistry:
         """{(route, stage): (cumulative bucket counts, sum_s, cnt)} —
         this process's stage histograms, in the SAME shape a whole-front
         shm aggregate provider returns (parallel/shmring.shm_stage_hist)
-        and the bench /metrics scrape parses, so the tune observer reads
-        any of the three through one seam."""
+        and the bench /metrics scrape parses."""
         with self._lock:
             return {
                 k: (tuple(row[:-2]), row[-2], row[-1])
                 for k, row in self._shist.items()
             }
-
-    def stage_hist_front(self) -> Dict[Tuple[str, str], tuple]:
-        """The widest stage-histogram view this process can see: the
-        whole-front shm aggregate when one is wired (set_stage_agg),
-        else this process's own histograms.  The tune observer's
-        default provider — the tuner fits what the FRONT measured, not
-        just the owner process."""
-        agg = self._stage_agg
-        if agg is not None:
-            try:
-                return agg() or {}
-            except Exception:  # noqa: BLE001 — fall back to local
-                pass
-        return self.stage_hist_snapshot()
 
     def set_gauge(self, name: str, value: float) -> None:
         with self._lock:
@@ -395,36 +380,15 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
 
-# -- stage-histogram window math (the tune observer's inputs) -----------------
-
-
-def stage_hist_delta(h0: dict, h1: dict) -> dict:
-    """Per-key difference of two stage-histogram snapshots (h1 - h0):
-    what was observed INSIDE the window between them.  Keys that first
-    appear in h1 count from zero; negative deltas (a restarted worker's
-    shm block, a reset registry) clamp to zero rather than poisoning a
-    fit; keys with no new observations are dropped."""
-    out = {}
-    for k, (c1, s1, n1) in h1.items():
-        c0, s0, n0 = h0.get(k, ((0,) * len(c1), 0.0, 0))
-        dn = max(0, int(n1) - int(n0))
-        if dn <= 0:
-            continue
-        dc = tuple(
-            max(0, int(a) - int(b)) for a, b in zip(c1, c0)
-        )
-        out[k] = (dc, max(0.0, float(s1) - float(s0)), dn)
-    return out
+# -- stage-histogram window math ----------------------------------------------
 
 
 def stage_hist_quantile(counts, cnt, q: float,
                         buckets=STAGE_BUCKETS):
     """Linear-interpolated quantile (seconds) of one histogram row:
     cumulative bucket counts + total count -> the q-quantile
-    interpolated inside the breached bucket.  THE shared interpolation:
-    bench.py's stage-attribution table and the tune observer's
-    cost-model fitter both call this, so a fitted floor can never
-    disagree with the p99 the operator reads in the bench report.
+    interpolated inside the breached bucket (bench.py's
+    stage-attribution table reads its p99 through this).
 
     Edge cases are policy, not accidents: an empty histogram returns
     None (nothing to claim), a tail living past the last bucket returns

@@ -1,4 +1,4 @@
-"""JAX/Pallas kernels for the DAR hot path.
+"""JAX kernels for the DAR hot path.
 
 x64 is enabled globally: entity times are exact int64 unix-nanoseconds
 on device, matching the reference's timestamp comparison semantics

@@ -24,17 +24,12 @@ Submit/collect are asynchronous: submit() enqueues the upload + kernel
 and starts the D2H copy without blocking, so many batches pipeline and
 the dispatch round trip is paid once per *stream*, not once per batch.
 
-Two device implementations:
-  - XLA (default): leading-dim block gather (embedding-lookup shape).
-  - Pallas (`use_pallas=True`, legacy mask path): explicit
-    double-buffered HBM->VMEM DMA per window.  On a TPU v5e (jax
-    0.9.0, PR 21) the quantized-mask DMA kernel compiles with Mosaic
-    and matches interpret mode; the fused exact twin does not (it
-    takes i64 operands — see ops/fastpath_pallas.py).  No serving
-    caller: the XLA path is the one device implementation in use.
-
-The legacy quantized-mask path (query_batch + exact_filter host
-re-check) is kept as the overflow fallback and the Pallas host.
+One device implementation: XLA, a leading-dim block gather
+(embedding-lookup shape) over the four exact block columns.  When the
+compacted hit-word buffer overflows, collect() re-runs the same fused
+kernel at the hard bound of four words a window.  Small batches never
+reach the device: query_host_auto scans the host postings copy with
+the same compares on the same values.
 """
 
 from __future__ import annotations
@@ -48,7 +43,6 @@ import jax
 import jax.numpy as jnp
 
 INT32_MAX = np.int32(2**31 - 1)
-INT32_MIN = np.int32(-(2**31))
 BLOCK = 128  # postings per block == TPU lane width
 WORDS = BLOCK // 32  # u32 hit words per window
 
@@ -89,33 +83,6 @@ def pow2_bucket(n: int, lo: int = 256) -> int:
     while v < n:
         v *= 2
     return v
-
-
-# ---------------------------------------------------------------------------
-# quantization (conservative: expand intervals outward)
-# ---------------------------------------------------------------------------
-
-
-def mm_floor(x) -> np.ndarray:
-    v = np.floor(np.asarray(x, np.float64) * 1000.0)
-    return np.clip(v, -(2**31), 2**31 - 1).astype(np.int32)
-
-
-def mm_ceil(x) -> np.ndarray:
-    v = np.ceil(np.asarray(x, np.float64) * 1000.0)
-    return np.clip(v, -(2**31), 2**31 - 1).astype(np.int32)
-
-
-def sec_floor(x) -> np.ndarray:
-    return np.clip(
-        np.asarray(x, np.int64) // 10**9, -(2**31), 2**31 - 1
-    ).astype(np.int32)
-
-
-def sec_ceil(x) -> np.ndarray:
-    return np.clip(
-        -((-np.asarray(x, np.int64)) // 10**9), -(2**31), 2**31 - 1
-    ).astype(np.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -167,14 +134,6 @@ def _expand_hit_words(bits_u32: np.ndarray):
         active = active[v != 0]
         k += 1
     return wi, bitpos
-
-
-def _bitpack_weights() -> np.ndarray:
-    """(128, 8) f32: lane i contributes 2^(i%16) to word i//16."""
-    w = np.zeros((BLOCK, 8), np.float32)
-    for i in range(BLOCK):
-        w[i, i // 16] = float(1 << (i % 16))
-    return w
 
 
 def fused_window_filter(
@@ -380,156 +339,77 @@ class FastTable:
         t_end: np.ndarray,
         live: np.ndarray,  # bool[P]
         *,
-        slot_exact: Optional[dict] = None,
+        slot_exact: dict,  # per-SLOT exact columns {"alt_lo", "alt_hi",
+        #                    "t0", "t1", "live"}: the host scan's values
+        #                    and the post-build tombstones
         device=None,
     ):
         P = len(post_key)
-        # query_batch pads with key -1 (per-row qkeys pad) and -2 (the
-        # never-matching window pad); both must stay distinguishable
-        # from real DAR keys, so keys are required to be non-negative
-        # (cell_to_dar_key yields 30-bit keys, geo/s2cell.py).
+        # a query row pads its keys with -1, which must stay
+        # distinguishable from a real DAR key, so keys are required to
+        # be non-negative (cell_to_dar_key yields 30-bit keys,
+        # geo/s2cell.py).
         if P and int(post_key.min()) < 0:
             raise ValueError(
                 f"FastTable requires non-negative DAR keys, got min "
                 f"{int(post_key.min())}"
             )
-        # INT32_MAX is the packed-column pad fill, and the native run
-        # search computes key+1 (UB at INT32_MAX); real DAR keys are
-        # 30-bit (geo/s2cell.py), so reject the sentinel outright
+        # the native run search computes key+1 (UB at INT32_MAX); real
+        # DAR keys are 30-bit (geo/s2cell.py), so reject it outright
         if P and int(post_key.max()) >= INT32_MAX:
             raise ValueError(
-                "FastTable requires DAR keys < INT32_MAX "
-                f"(pad sentinel), got max {int(post_key.max())}"
+                "FastTable requires DAR keys < INT32_MAX, "
+                f"got max {int(post_key.max())}"
             )
         self.n_postings = P
         # 2 extra blocks of padding so lo_blk+1 never reads out of range
         ppad = ((P + 2 * BLOCK - 1) // (2 * BLOCK)) * 2 * BLOCK + 4 * BLOCK
-        packed = np.full((5, ppad), INT32_MAX, np.int32)
-        packed[0, :P] = post_key
-        packed[1, :P] = mm_floor(alt_lo)
-        packed[2, :P] = mm_ceil(alt_hi)
-        packed[3, :P] = sec_floor(t_start)
-        packed[4, :P] = np.where(live, sec_ceil(t_end), INT32_MIN)
         nb = ppad // BLOCK
-        p3 = packed.reshape(5, nb, BLOCK).transpose(1, 0, 2).copy()
-        self.p3 = jax.device_put(p3, device)  # (NB, 5, BLOCK)
         self.n_blocks = nb
         self.host_key = np.asarray(post_key)
         self.host_ent = np.asarray(post_ent)
         self.host_live = np.asarray(live, bool)
-        self.bitpack_w = jax.device_put(_bitpack_weights(), device)
-        self._device = device
 
-        # Fused on-device path: EXACT per-posting attribute columns in
-        # block layout, resident in HBM, so the window test is exact
-        # (no quantization, no host re-filter).  Tombstoned postings
-        # get t_end = NO_TIME_LO so `t_end >= now` never passes;
-        # post-build tombstones are dropped host-side in collect() via
-        # slot_exact["live"].  slot_exact: {"alt_lo","alt_hi","t0",
-        # "t1","live"} per-slot arrays (host, for fallback + liveness).
-        self.slot_exact = None
-        if slot_exact is not None:
-            nblo = np.int64(-(2**62))
-            b_alo = np.full(ppad, np.inf, np.float32)
-            b_ahi = np.full(ppad, -np.inf, np.float32)
-            b_t0 = np.full(ppad, 2**62, np.int64)
-            b_t1 = np.full(ppad, nblo, np.int64)
-            b_alo[:P] = np.asarray(alt_lo, np.float32)
-            b_ahi[:P] = np.asarray(alt_hi, np.float32)
-            b_t0[:P] = np.asarray(t_start, np.int64)
-            b_t1[:P] = np.where(np.asarray(live, bool), np.asarray(t_end, np.int64), nblo)
-            self.b_alo = jax.device_put(b_alo.reshape(nb, BLOCK), device)
-            self.b_ahi = jax.device_put(b_ahi.reshape(nb, BLOCK), device)
-            self.b_t0 = jax.device_put(b_t0.reshape(nb, BLOCK), device)
-            self.b_t1 = jax.device_put(b_t1.reshape(nb, BLOCK), device)
-            self.slot_exact = {
-                k: np.asarray(v) for k, v in slot_exact.items()
-            }
-            # normalize the live column to a contiguous buffer HERE,
-            # where no concurrent mutator can exist yet: mark_dead()
-            # flips bits of THIS array in place and the native host
-            # path caches a uint8 view of the same memory — adopting a
-            # contiguous copy lazily on the query path (as before)
-            # could lose a tombstone that raced the adoption
-            self.slot_exact["live"] = np.ascontiguousarray(
-                self.slot_exact["live"]
-            )
+        # EXACT per-posting attribute columns in block layout, resident
+        # in HBM, so the window test is exact (no quantization, no host
+        # re-filter): 24 B a padded posting.  Tombstoned postings get
+        # t_end = -2^62 so `t_end >= now` never passes; post-build
+        # tombstones are dropped host-side in collect() via
+        # slot_exact["live"].
+        nblo = np.int64(-(2**62))
+        b_alo = np.full(ppad, np.inf, np.float32)
+        b_ahi = np.full(ppad, -np.inf, np.float32)
+        b_t0 = np.full(ppad, 2**62, np.int64)
+        b_t1 = np.full(ppad, nblo, np.int64)
+        b_alo[:P] = np.asarray(alt_lo, np.float32)
+        b_ahi[:P] = np.asarray(alt_hi, np.float32)
+        b_t0[:P] = np.asarray(t_start, np.int64)
+        b_t1[:P] = np.where(self.host_live, np.asarray(t_end, np.int64), nblo)
+        self.b_alo = jax.device_put(b_alo.reshape(nb, BLOCK), device)
+        self.b_ahi = jax.device_put(b_ahi.reshape(nb, BLOCK), device)
+        self.b_t0 = jax.device_put(b_t0.reshape(nb, BLOCK), device)
+        self.b_t1 = jax.device_put(b_t1.reshape(nb, BLOCK), device)
+        self.slot_exact = {k: np.asarray(v) for k, v in slot_exact.items()}
+        # normalize the live column to a contiguous buffer HERE, where
+        # no concurrent mutator can exist yet: mark_dead() flips bits of
+        # THIS array in place and the native host path caches a uint8
+        # view of the same memory — adopting a contiguous copy lazily on
+        # the query path could lose a tombstone that raced the adoption
+        self.slot_exact["live"] = np.ascontiguousarray(
+            self.slot_exact["live"]
+        )
 
     def device_bytes(self) -> int:
-        """Bytes of this table's device-resident arrays (the quantized
-        block pack plus, when built, the four exact block columns)."""
-        arrs = [self.p3, self.bitpack_w]
-        if self.slot_exact is not None:
-            arrs += [self.b_alo, self.b_ahi, self.b_t0, self.b_t1]
-        return int(sum(a.nbytes for a in arrs))
-
-    # -- device kernels ------------------------------------------------------
-
-    @staticmethod
-    @partial(jax.jit, static_argnames=("chunk",))
-    def _filter_xla(
-        p3, bitpack_w, win_blk, qk, qalo_mm, qahi_mm, qt0s, qt1s,
-        *, chunk=16384,
-    ):
-        """Flat window list (one postings block each) -> bit-packed hit
-        mask (NW, 8) i32.  All inputs are per-window (NW,) arrays; the
-        host expands each (query, cell) range into every block its run
-        touches, so arbitrarily long runs are fully covered.  Processed
-        in `chunk`-window chunks (lax.map) to bound HBM materialization.
-        """
-        nw = win_blk.shape[0]
-
-        def one_chunk(c):
-            blk, qk_c, alo_c, ahi_c, t0_c, t1_c = c
-            win = jnp.take(p3, blk, axis=0)  # (C, 5, 128)
-            hit = (
-                (win[:, 0, :] == qk_c[:, None])
-                & (win[:, 2, :] >= alo_c[:, None])
-                & (win[:, 1, :] <= ahi_c[:, None])
-                & (win[:, 4, :] >= t0_c[:, None])
-                & (win[:, 3, :] <= t1_c[:, None])
-            )
-            bits = jnp.dot(hit.astype(jnp.float32), bitpack_w)
-            return bits.astype(jnp.int32)  # (C, 8)
-
-        if nw <= chunk:
-            return one_chunk((win_blk, qk, qalo_mm, qahi_mm, qt0s, qt1s))
-        pad = (-nw) % chunk
-
-        def padq(a):
-            if pad:
-                a = jnp.concatenate(
-                    [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)]
-                )
-            return a.reshape(-1, chunk, *a.shape[1:])
-
-        bits = jax.lax.map(
-            one_chunk,
-            (padq(win_blk), padq(qk), padq(qalo_mm), padq(qahi_mm),
-             padq(qt0s), padq(qt1s)),
-        )
-        return bits.reshape(-1, 8)[:nw]
-
-    def _filter_pallas(self, win_blk, qk, qalo_mm, qahi_mm, qt0s, qt1s, *, interpret=False):
-        from dss_tpu.ops.fastpath_pallas import filter_windows_pallas
-
-        return filter_windows_pallas(
-            self.p3,
-            win_blk,
-            qk,
-            qalo_mm,
-            qahi_mm,
-            qt0s,
-            qt1s,
-            interpret=interpret,
-        )
+        """Bytes of this table's device-resident arrays: the four exact
+        block columns."""
+        return int(sum(
+            a.nbytes for a in (self.b_alo, self.b_ahi, self.b_t0, self.b_t1)
+        ))
 
     def mark_dead(self, slot: int) -> None:
         """Tombstone one slot in place (no rebuild): flips the host
         live bit; collect() drops the slot during result assembly, so
         the fused path stops returning it immediately."""
-        if self.slot_exact is None:
-            return
         self.slot_exact["live"][slot] = False
 
     # -- fused on-device kernel ----------------------------------------------
@@ -546,7 +426,7 @@ class FastTable:
         )
     )
 
-    # -- host window expansion (shared by legacy + fused paths) --------------
+    # -- host window expansion -----------------------------------------------
 
     def _range_lookup(self, k: np.ndarray):
         """Vectorized postings-range lookup: for each query key, the
@@ -571,8 +451,8 @@ class FastTable:
 
     def _expand_windows(self, qkeys: np.ndarray):
         """(query, cell) pairs -> every 128-block their postings runs
-        touch.  Returns (win_q, win_key, win_blk, win_start, win_end)
-        host i32 arrays; [start, end) is the run's lane slice within
+        touch.  Returns (win_q, win_blk, win_start, win_end) host i32
+        arrays; [start, end) is the run's lane slice within
         the window's block."""
         B, W = qkeys.shape
         qk = np.ascontiguousarray(qkeys, np.int32)
@@ -580,17 +460,15 @@ class FastTable:
         nonempty = hi > lo  # also drops pad cells (-1)
         lo, hi = lo[nonempty], hi[nonempty]
         flat_q = np.repeat(np.arange(B), W)[nonempty]
-        flat_k = qk.ravel()[nonempty]
         first_blk = lo // BLOCK
         n_blocks = (hi - 1) // BLOCK - first_blk + 1  # >= 1
         win_q = np.repeat(flat_q, n_blocks).astype(np.int32)
-        win_key = np.repeat(flat_k, n_blocks)
         starts = np.repeat(first_blk, n_blocks)
         win_blk = (starts + segmented_arange(n_blocks)).astype(np.int32)
         blk0 = win_blk.astype(np.int64) * BLOCK
         win_start = np.maximum(np.repeat(lo, n_blocks) - blk0, 0).astype(np.int32)
         win_end = np.minimum(np.repeat(hi, n_blocks) - blk0, BLOCK).astype(np.int32)
-        return win_q, win_key, win_blk, win_start, win_end
+        return win_q, win_blk, win_start, win_end
 
     def _sample_index(self):
         """(host_key i32, sample, sample0) for the native range
@@ -631,7 +509,7 @@ class FastTable:
             )
             if res is not None:
                 return res
-        win_q, _, win_blk, win_start, win_end = self._expand_windows(qkeys)
+        win_q, win_blk, win_start, win_end = self._expand_windows(qkeys)
         nw = len(win_blk)
         if nw == 0:
             return None, win_q, win_blk, 0
@@ -661,14 +539,13 @@ class FastTable:
         #               shared jit path
     ) -> Optional[PendingBatch]:
         """Enqueue one fused query batch (async; no device sync).
-        Requires slot_exact.  Returns None when no query key has any
-        postings (empty result).
+        Returns None when no query key has any postings (empty
+        result).
 
         max_words=None auto-sizes the compacted-hit-word buffer to a
         pow2 bucket >= the window count (one non-empty word per window
         is the typical ceiling; 4*nw is the hard one).  collect()
         retries at the 4*nw hard bound on overflow."""
-        assert self.slot_exact is not None, "submit() requires slot_exact"
         wins, win_q, win_blk, nw = self._pack_windows(qkeys)
         if nw == 0:
             return None
@@ -741,7 +618,7 @@ class FastTable:
             # overflow: the word buffer was too small — rerun the fused
             # kernel at the hard upper bound (4 words per window), which
             # cannot overflow.  Exact same semantics, one extra round
-            # trip, no legacy mask path.
+            # trip.
             qkeys, alt_lo, alt_hi, t_start, t_end, now = pending.host_inputs
             hard = pow2_bucket(4 * pending.nw, lo=1 << 16)
             return self.collect(
@@ -830,7 +707,7 @@ class FastTable:
         max_candidates override the auto-route gates (the deadline
         router's forced host chunks raise them)."""
         mb = self.HOST_MAX_BATCH if max_batch is None else int(max_batch)
-        if len(qkeys) > mb or self.slot_exact is None:
+        if len(qkeys) > mb:
             return None
         mc = (
             self.HOST_MAX_CANDIDATES
@@ -866,8 +743,6 @@ class FastTable:
         work).  This is the deadline router's escape hatch from the
         device dispatch floor: N/64 sequential ~100 us scans beat one
         dispatch round trip whenever the floor is the larger."""
-        if self.slot_exact is None:
-            return None
         b = len(qkeys)
         step = self.HOST_MAX_BATCH if chunk is None else max(1, int(chunk))
         now_b = np.broadcast_to(np.asarray(now, np.int64), (b,))
@@ -905,7 +780,7 @@ class FastTable:
         max_candidates raise the route gates for the deadline router's
         forced host chunks (query_host_chunked)."""
         mb = self.HOST_MAX_BATCH if max_batch is None else int(max_batch)
-        if len(qkeys) > mb or self.slot_exact is None:
+        if len(qkeys) > mb:
             return None
         try:
             from dss_tpu import native as _native
@@ -998,108 +873,3 @@ class FastTable:
             & (se["t0"][slots] <= t_end[qidx])
         )
         return qidx[keep].astype(np.int64), slots[keep].astype(np.int64)
-
-    # -- the full query pipeline ---------------------------------------------
-
-    def query_batch(
-        self,
-        qkeys: np.ndarray,  # i32[B, W] DAR keys, pad -1
-        alt_lo: np.ndarray,  # f32[B] (-inf if unbounded)
-        alt_hi: np.ndarray,
-        t_start: np.ndarray,  # i64[B] ns (NO_TIME_LO if unbounded)
-        t_end: np.ndarray,
-        *,
-        now: int,
-        use_pallas: bool = False,
-        interpret: bool = False,
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """-> (query_index i64[H], posting_offset i64[H]): the raw hit
-        pairs after the conservative device filter.  Callers re-check
-        exact attributes per hit (see exact_filter)."""
-        # host range lookup: expand every (query, cell) run into ALL
-        # the 128-blocks it touches, so hot cells with arbitrarily long
-        # runs are fully covered (no window-size false negatives)
-        win_q, win_key, win_blk, _, _ = self._expand_windows(qkeys)
-        if len(win_blk) == 0:
-            return np.zeros(0, np.int64), np.zeros(0, np.int64)
-
-        alo_mm = mm_floor(np.where(np.isneginf(alt_lo), -2e6, alt_lo))
-        ahi_mm = mm_ceil(np.where(np.isposinf(alt_hi), 2e6, alt_hi))
-        t0s = sec_floor(t_start)
-        t1s = sec_ceil(t_end)
-
-        # pad NW to a power-of-two bucket with never-matching windows
-        # (key -2): NW is data-dependent, and an unpadded shape would
-        # force a jit recompile on every batch
-        nw = len(win_blk)
-        pad = pow2_bucket(nw) - nw
-
-        def padded(a, fill):
-            return np.concatenate(
-                [a, np.full(pad, fill, np.int32)]
-            ) if pad else a
-
-        args = (
-            jnp.asarray(padded(win_blk, 0)),
-            jnp.asarray(padded(win_key, -2)),
-            jnp.asarray(padded(alo_mm[win_q].astype(np.int32), 0)),
-            jnp.asarray(padded(ahi_mm[win_q].astype(np.int32), 0)),
-            jnp.asarray(padded(t0s[win_q].astype(np.int32), 0)),
-            jnp.asarray(padded(t1s[win_q].astype(np.int32), 0)),
-        )
-        if use_pallas:
-            # the pow2 bucket is already a multiple of the kernel GROUP
-            mask = np.asarray(
-                self._filter_pallas(*args, interpret=interpret)
-            )[:nw]  # (NW, 128) int8
-            wi, lane = np.nonzero(mask)
-        else:
-            m = np.asarray(
-                self._filter_xla(self.p3, self.bitpack_w, *args)
-            ).astype(np.uint32)[:nw]  # (NW, 8) 16-bit words
-            wi0, wordq = np.nonzero(m)
-            vals = m[wi0, wordq]
-            bitpos = np.arange(16, dtype=np.uint32)
-            expanded = (vals[:, None] >> bitpos[None, :]) & 1
-            e_i, e_b = np.nonzero(expanded)
-            wi = wi0[e_i]
-            lane = wordq[e_i] * 16 + e_b
-        offs = win_blk[wi].astype(np.int64) * BLOCK + lane
-        qidx = win_q[wi].astype(np.int64)
-        ok = offs < self.n_postings
-        return qidx[ok], offs[ok]
-
-    def exact_filter(
-        self,
-        qidx: np.ndarray,
-        offs: np.ndarray,
-        records_alt_lo: np.ndarray,  # per-SLOT exact values
-        records_alt_hi: np.ndarray,
-        records_t0: np.ndarray,
-        records_t1: np.ndarray,
-        records_live: np.ndarray,
-        alt_lo: np.ndarray,
-        alt_hi: np.ndarray,
-        t_start: np.ndarray,
-        t_end: np.ndarray,
-        *,
-        now,  # int scalar or i64[B] per-query request time
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Drop quantization false positives; -> (qidx, slots).
-
-        Key equality was already tested exactly on device (the window
-        compare is `win_key == qk`), so only the quantized attribute
-        tests need re-checking here."""
-        slots = self.host_ent[offs]
-        now_q = np.asarray(now, np.int64)
-        if now_q.ndim:
-            now_q = now_q[qidx]
-        keep = (
-            records_live[slots]
-            & (records_alt_hi[slots] >= alt_lo[qidx])
-            & (records_alt_lo[slots] <= alt_hi[qidx])
-            & (records_t1[slots] >= t_start[qidx])
-            & (records_t0[slots] <= t_end[qidx])
-            & (records_t1[slots] >= now_q)
-        )
-        return qidx[keep], slots[keep]

@@ -1340,8 +1340,3 @@ class ShardedReplica:
             out[f"replica_{cls}_dirty"] = int(self._dirty[cls])
             out[f"replica_{cls}_generation"] = self._gen[cls]
         return out
-
-
-class ShardedOpReplica(ShardedReplica):
-    """Back-compat alias: the r3/r4 SCD-operations-only replica surface
-    (query defaults to cls='ops')."""

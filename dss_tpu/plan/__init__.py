@@ -48,7 +48,6 @@ from dss_tpu.plan.planner import (
     Planner,
     decide,
     plan_drain_cap,
-    set_decision_hook,
 )
 
 __all__ = [
@@ -61,5 +60,4 @@ __all__ = [
     "ROUTES",
     "decide",
     "plan_drain_cap",
-    "set_decision_hook",
 ]

@@ -544,7 +544,7 @@ def autotune(*, quick: bool = False, entities: Optional[int] = None,
     table = _fixture(n_ent, n_cel)
     scen_shapes = scen_ms = None
     try:
-        ft = table._state.snap.fast
+        ft = table._state.tiers[0].snap.fast
         chunk_ms = measure_chunk_ms(ft, n_cel, reps=reps)
         dev = measure_device(ft, n_cel, reps=max(3, reps - 2))
         res = measure_resident(

@@ -213,13 +213,12 @@ class CostModel:
                res_lat_ms: Optional[float] = None,
                rq_floor_ms: Optional[float] = None,
                rq_item_ms: Optional[float] = None) -> None:
-        """Jump estimates to externally MEASURED values — the tune
-        actuator's hot-swap seam (dss_tpu/tune).  Unlike observe_*,
-        which winsorizes each sample to 4x the current prediction (a
-        genuine workload flip therefore converges only as fast as the
-        clamp ratchets), a reseed lands in one step: the tuner fitted
-        the new value from an unclamped whole-front histogram window,
-        so the usual single-outlier defense does not apply.  When the
+        """Jump estimates to externally MEASURED values (the
+        coalescer's configure(est_*=...)).  Unlike observe_*, which
+        winsorizes each sample to 4x the current prediction (a genuine
+        workload flip therefore converges only as fast as the clamp
+        ratchets), a reseed lands in one step: the caller vouches for
+        the value, so the single-outlier defense does not apply.  When the
         cold-device pair changes, the EWMA moments are re-primed from
         the new seed (exactly as __init__ does) so subsequent
         observations BLEND forward from it instead of snapping the fit

@@ -454,32 +454,6 @@ def state_of(cost, **pressure) -> ModelState:
     )
 
 
-# -- decision-trace recorder hook (dss_tpu/tune/shadow.py) -------------------
-#
-# One process-wide hook, same discipline as the trace flight recorder's
-# _ENABLED gate: when no recorder is installed the hot path pays ONE
-# module-global read and a None test — no allocation, no lock, no call.
-# The tune controller installs its DecisionRecorder here so EVERY
-# planner in the process (five class coalescers + the push match
-# stages) records into one bounded ring the shadow evaluator replays.
-# Module-level on purpose: tune imports plan, so plan cannot import
-# tune — the seam lives on the side that everything else already
-# depends on.
-
-_DECISION_HOOK = None
-
-
-def set_decision_hook(hook) -> None:
-    """Install (or clear, with None) the process-wide decision
-    recorder.  `hook(shape, state, headroom_ms, allow_resident,
-    allow_mesh, plan)` is called for every RECORDED plan — the allow_*
-    flags ride along so a replay presents `decide` with exactly the
-    arguments the live call used (decision identity, not just state
-    identity)."""
-    global _DECISION_HOOK
-    _DECISION_HOOK = hook
-
-
 class Planner:
     """Owns the cost models and produces Plans.
 
@@ -543,12 +517,6 @@ class Planner:
         )
         if record:
             self.note(p.route)
-            hook = _DECISION_HOOK
-            if hook is not None:
-                hook(
-                    shape, state, headroom_ms, allow_resident,
-                    allow_mesh, p,
-                )
         return p
 
     def note(self, route: str) -> None:

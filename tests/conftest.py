@@ -2,32 +2,24 @@
 
 Tests run on the CPU: the sharding tests need 8 devices, which exist
 only as virtual CPU devices, and nothing tier-1 asserts is a device
-number.  The chip is reached through chip_smoke.py and the
-DSS_TEST_TPU=1 canaries.  Must run before jax import.
+number.  The chip is reached through chip_smoke.py and the benchmark
+(dssbench/).  Must run before jax import.
 """
 
 import os
 
-# DSS_TEST_TPU=1 opts a (selective) pytest run onto the real TPU
-# backend — used for the device-gated tests (the compiled-Pallas
-# canaries, `-k on_tpu` in tests/test_pallas_fused_parity.py); the full
-# suite assumes the 8-device CPU mesh and should not run this way.
-_USE_TPU = os.environ.get("DSS_TEST_TPU") == "1"
-
-if not _USE_TPU:
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=8"
+    ).strip()
 
 import jax  # noqa: E402
 
-if not _USE_TPU:
-    # jax may already have been imported (and have read an older
-    # JAX_PLATFORMS) by a plugin: pin the platform at the config level
-    jax.config.update("jax_platforms", "cpu")
+# jax may already have been imported (and have read an older
+# JAX_PLATFORMS) by a plugin: pin the platform at the config level
+jax.config.update("jax_platforms", "cpu")
 
 
 import pytest

@@ -1,0 +1,154 @@
+"""What the benchmark reads, the program still writes.
+
+`dssbench/metrics/*.json` name counter and gauge families on `/metrics`
+and lines of the leader's boot log; a PR that deletes one of them used
+to find out from a `null` in the ledger a day later.  One case per
+metric file whose reader is `scrape_ratio`, `scrape_rate` or `bootlog`,
+against one boot of the served topology on the CPU backend, started
+and awaited by the harness's own code (`dssbench.run.start_server`,
+`dssbench.deploy.wait_ready`: the leader's "resident AOT warm:" line is
+part of the contract, the harness sends nothing before it) and scraped
+by its parser.  Every family pattern the file names has to match a key
+of the process it names (`leader`: the device owner's loopback port;
+`front`: the public port), by the rule of `readers/scrape_ratio.delta`;
+a `bootlog` file is handed to its reader and has to read a number.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import fnmatch
+import glob
+import json
+import os
+import time
+from unittest import mock
+
+import numpy as np
+import pytest
+
+from dssbench import deploy, run, traffic as tr
+from dssbench.readers import bootlog
+
+READERS = ("scrape_ratio", "scrape_rate", "bootlog")
+
+
+def _metric_files() -> list:
+    out = []
+    for path in sorted(glob.glob(
+            os.path.join(deploy.REPO, "dssbench", "metrics", "*.json"))):
+        with open(path) as fh:
+            m = json.load(fh)
+        if m["reader"] in READERS:
+            out.append(pytest.param(m, id=m["name"]))
+    return out
+
+
+# 16 x 16 cells at the rehearsal's footprints, a tenth of its records:
+# boots in ~10 s; the AOT grid pinned to one small bucket, as the
+# benchmark's configurations pin theirs
+CONFIG = {
+    "generator": {
+        "grid": 16, "owners": 8, "strata": 60, "stratum_m": 50,
+        "classes": {
+            "op": {"n": 3000, "cells": [2, 12]},
+            "isa": {"n": 40, "cells": [4, 12]},
+            "rid_sub": {"n": 10, "cells": [4, 12]},
+            "scd_sub": {"n": 10, "cells": [4, 12]},
+        },
+    },
+    "server": {
+        "flags": ["--enable_scd", "--insecure_no_auth"], "workers": 2,
+        "env": {"DSS_RES_BATCH_BUCKETS": "16",
+                "DSS_RES_WINDOW_BUCKETS": "1024"},
+    },
+}
+# one RID poll and one small op-intent check in turn: every stage of a
+# search through the ring is observed; none reaches the device route
+TRAFFIC = {"components": [
+    {"share": 0.5, "endpoint": "rid_search",
+     "w_cells": [1, 2], "h_cells": [1, 2]},
+    {"share": 0.5, "endpoint": "scd_query",
+     "w_cells": [2, 3], "h_cells": [2, 3],
+     "alt_band_m": 60, "alt_ceiling_m": 2900, "timed_every": 2,
+     "opens_in_s": [7200, 14400], "lasts_s": [900, 3600]},
+]}
+BOOT_TIMEOUT_S = 300
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """{'leader': keys, 'front': keys, 'bootlog': records} of one boot
+    that has answered 20 searches."""
+    work = str(tmp_path_factory.mktemp("served"))
+    wal = os.path.join(work, "dss.wal")
+    t_gen = int(time.time())
+    metro, ref = deploy.generate(5, CONFIG["generator"], t_gen, wal)
+    cores = sorted(os.sched_getaffinity(0))
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    with mock.patch.dict(os.environ, env, clear=True):
+        # conftest's 8 virtual devices are the tests', not the server's
+        srv = run.start_server(CONFIG, wal, work, "cpu", False, cores, cores)
+    try:
+        deploy.wait_ready(srv, CONFIG["server"]["workers"], BOOT_TIMEOUT_S)
+        requests = tr.build(TRAFFIC, metro, ref, {},
+                            np.random.default_rng(1), t_gen, 10, 2.0)
+
+        async def ask():
+            # as the harness's own prefill lanes: connections as they
+            # come (a worker may still be binding; the merged families
+            # do not care which one answered)
+            client = tr.Client(srv.port)
+            await tr.prefill(client, requests, 2)
+            await client.close()
+
+        asyncio.run(ask())
+        got = run.scrape_all(srv)
+        got["bootlog"], _ = srv.log_records()
+    finally:
+        srv.stop()
+    return got
+
+
+def _allocator_peak(monkeypatch) -> bool:
+    """`dss_device_peak_bytes_in_use`: the CPU's allocator reports
+    nothing, so no CPU boot exports it; checked where it is made
+    (`dss_tpu.ops.device_memory_stats`, which `DSSStore.stats` merges
+    into every scrape) with a device that reports as the chip does."""
+    import jax
+
+    from dss_tpu import ops
+
+    class Chip:
+        def memory_stats(self):
+            return {"bytes_in_use": 3, "peak_bytes_in_use": 7}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()])
+    return ops.device_memory_stats().get("dss_device_peak_bytes_in_use") == 7
+
+
+# families that no CPU boot can export, and where each is checked instead
+ELSEWHERE = {"dss_device_peak_bytes_in_use": _allocator_peak}
+
+
+@pytest.mark.parametrize("metric", _metric_files())
+def test_the_program_exports_what_the_metric_reads(metric, served,
+                                                   monkeypatch):
+    name, args = metric["name"], metric["args"]
+    if metric["reader"] == "bootlog":
+        assert bootlog.read({"bootlog": served["bootlog"]}, **args) is not None, (
+            f"dssbench/metrics/{name}.json reads the leader's boot log "
+            f"for {args}: no such line was logged"
+        )
+        return
+    keys = served[args["proc"]]
+    for pat in args.get("num", []) + args.get("den", []) + args.get(
+            "names", []):
+        found = pat in keys or (
+            any(c in pat for c in "*?[") and fnmatch.filter(keys, pat))
+        if not found and pat in ELSEWHERE:
+            found = ELSEWHERE[pat](monkeypatch)
+        assert found, (
+            f"dssbench/metrics/{name}.json reads {pat} from the "
+            f"{args['proc']}'s /metrics: the program exports no such family"
+        )

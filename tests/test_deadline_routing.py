@@ -20,6 +20,8 @@ from dss_tpu import errors
 from dss_tpu.dar import deadline as deadline_mod
 from dss_tpu.dar.coalesce import QueryCoalescer, _BatchController, _CostModel, _Item
 from dss_tpu.dar.snapshot import DarTable
+from dss_tpu.plan import plan_drain_cap
+from dss_tpu.plan.planner import state_of
 
 NOW = 1_700_000_000_000_000_000
 HOUR = 3_600_000_000_000
@@ -77,17 +79,33 @@ def test_drain_cap_respects_headroom():
     chunk (forward progress)."""
     ctl = _BatchController(min_batch=64, max_batch=4096, start=4096)
     cost = _CostModel(floor_ms=100.0, item_ms=0.01, chunk_ms=0.5, chunk=64)
+    state = state_of(cost)  # nothing in flight, no resident loop
     # rich headroom: AIMD size stands
-    assert ctl.drain_cap(None, cost, 0) == 4096
-    assert ctl.drain_cap(10_000.0, cost, 0) == 4096
+    assert plan_drain_cap(ctl.cur, None, state) == 4096
+    assert plan_drain_cap(ctl.cur, 10_000.0, state) == 4096
     # tight headroom: only the host chunks that fit half of it
-    cap = ctl.drain_cap(10.0, cost, 0)
+    cap = plan_drain_cap(ctl.cur, 10.0, state)
     assert cap == 64 * (int(5.0 / 0.5))  # 10 chunks
     # even 1 ms of headroom still drains one chunk
-    assert ctl.drain_cap(1.0, cost, 0) == 64
+    assert plan_drain_cap(ctl.cur, 1.0, state) == 64
 
 
 # -- routing decisions (fake clock, seeded estimates) ------------------------
+
+
+def _route(co, batch, headroom_ms, allow_resident=True):
+    """The planner's route for this drain, as the pack stage asks it
+    (unrecorded; the mesh candidate is decided before this choice)."""
+    return co._planner.plan(
+        co._shape_of(batch), co._capture_state(), headroom_ms,
+        allow_resident=allow_resident, allow_mesh=False, record=False,
+    ).route
+
+
+def _host_route(co, batch, headroom_ms):
+    """Whether a consumer that cannot ride the resident loop (the
+    inline lone caller, the mesh fallback) is sent to host chunks."""
+    return _route(co, batch, headroom_ms, allow_resident=False) == "hostchunk"
 
 
 def _routing_co(table, **kw):
@@ -108,15 +126,15 @@ def test_tight_headroom_routes_host_slack_routes_device():
         batch = [_item() for _ in range(200)]
         # 8 ms of headroom: predicted device (100 ms floor) blows it,
         # predicted host (4 chunks * 0.2 ms) does not -> host route
-        assert co._choose_host_route(batch, 8.0) is True
+        assert _host_route(co, batch, 8.0) is True
         # a second of headroom: the device fits -> device route
-        assert co._choose_host_route(batch, 1000.0) is False
+        assert _host_route(co, batch, 1000.0) is False
         # no fresh deadlines at all (bulk / all-stale): device route
-        assert co._choose_host_route(batch, None) is False
+        assert _host_route(co, batch, None) is False
         # headroom blown by BOTH routes: pick the lesser evil (device
         # when host chunks are predicted slower)
         co._cost.est_chunk_ms = 1000.0
-        assert co._choose_host_route(batch, 8.0) is False
+        assert _host_route(co, batch, 8.0) is False
     finally:
         co.close()
         table.close()

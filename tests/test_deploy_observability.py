@@ -821,11 +821,10 @@ def test_grafana_and_rules_cover_tracing():
     )
 
 
-def test_grafana_and_rules_cover_tuner():
-    """The self-tuning loop must stay observable: a knob panel showing
-    active vs last-proposed values (plus boot-profile age), a flow
-    panel over the proposal/apply/rollback counters and guard-window
-    p99, and the DssTuneRollback warn alert on the rollback counter."""
+def test_grafana_covers_the_autotune_profile_age():
+    """The boot profile's provenance stays on the dashboard (its
+    panel outlived the self-tuner's, PR 31), and no panel or alert
+    reads a family the program no longer exports."""
     dash = json.load(
         open(os.path.join(ROOT, "deploy/grafana/dss-dashboard.json"))
     )
@@ -834,58 +833,32 @@ def test_grafana_and_rules_cover_tuner():
         for p in dash["panels"]
         for t in p.get("targets", [])
     ]
-    for needed in (
-        "dss_tune_knob_active",
-        "dss_tune_knob_proposed",
-        "dss_tune_proposals_total",
-        "dss_tune_applied_total",
-        "dss_tune_shadow_rejected_total",
-        "dss_tune_rollbacks_total",
-        "dss_tune_apply_failed_total",
-        "dss_tune_guard_p99_ms",
-        "dss_autotune_profile_age_s",
-    ):
-        assert any(needed in e for e in exprs), needed
+    assert any("dss_autotune_profile_age_s" in e for e in exprs)
     rules = yaml.safe_load(
         open(os.path.join(ROOT, "deploy/prometheus/rules.yaml"))
     )
-    alerts = {
-        r.get("alert"): r
-        for g in rules["groups"]
-        for r in g["rules"]
-    }
-    assert "DssTuneRollback" in alerts
-    assert "dss_tune_rollbacks_total" in alerts["DssTuneRollback"]["expr"]
-    assert alerts["DssTuneRollback"]["labels"]["severity"] == "warn"
+    exprs += [r["expr"] for g in rules["groups"] for r in g["rules"]]
+    assert not [e for e in exprs if "dss_tune_" in e]
 
 
-def test_tune_gauges_render_as_labeled_families():
-    """dss_tune_knob_active / dss_tune_knob_proposed are dict-valued
-    stats keys: the metrics handler's per-metric label map explodes
-    them into gauge families labeled by knob name, and a tunerless
-    store must still export the whole scalar dss_tune_* surface
-    (series never appear only once someone flips DSS_TUNE=1)."""
+def test_dict_valued_stats_render_as_labeled_families():
+    """A dict-valued stats key is exploded by the metrics handler's
+    per-metric label map into a gauge family; a store exports no
+    dss_tune_* key (the self-tuner is gone, PR 31)."""
     from dss_tpu.api.app import _GAUGE_VEC_LABELS
     from dss_tpu.clock import Clock
     from dss_tpu.dar.dss_store import DSSStore
     from dss_tpu.obs.metrics import MetricsRegistry
 
-    assert _GAUGE_VEC_LABELS["dss_tune_knob_active"] == "knob"
-    assert _GAUGE_VEC_LABELS["dss_tune_knob_proposed"] == "knob"
+    assert _GAUGE_VEC_LABELS["dss_push_breaker_state"] == "uss"
     store = DSSStore(storage="memory", clock=Clock())
     stats = store.stats()
-    assert stats["dss_tune_enabled"] == 0
-    assert stats["dss_tune_rollbacks_total"] == 0
-    assert stats["dss_tune_knob_active"] == {}
+    assert stats["dss_push_breaker_state"] == {}
+    assert not [k for k in stats if k.startswith("dss_tune_")]
+    assert not [k for k in _GAUGE_VEC_LABELS if k.startswith("dss_tune_")]
     reg = MetricsRegistry()
-    reg.set_gauge_vec(
-        "dss_tune_knob_active", "knob",
-        {"DSS_CO_EST_FLOOR_MS": 2.5},
-    )
-    text = reg.render()
-    assert (
-        'dss_tune_knob_active{knob="DSS_CO_EST_FLOOR_MS"} 2.5' in text
-    )
+    reg.set_gauge_vec("dss_push_breaker_state", "uss", {"uss1": 2})
+    assert 'dss_push_breaker_state{uss="uss1"} 2.0' in reg.render()
 
 
 def test_stage_histogram_renders_as_labeled_family():

@@ -1,8 +1,6 @@
-"""Fast-path conflict kernels vs the exact oracle (CPU).
-
-Covers both device implementations: the XLA block-gather filter and
-the Pallas DMA kernel (interpret mode — the real-TPU compile is
-environment-gated, see ops/fastpath_pallas.py docstring).
+"""The fast path's two exact implementations vs the oracle (CPU): the
+fused device kernel (submit / collect, XLA) and the host scan
+(query_host_auto: the native kernel, or numpy without the library).
 """
 
 import numpy as np
@@ -11,13 +9,52 @@ import pytest
 from dss_tpu.dar import oracle
 from dss_tpu.dar.oracle import Record
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
-from dss_tpu.ops.fastpath import FastTable
+from dss_tpu.ops.fastpath import BLOCK, FastTable
 
 NOW = 1_700_000_000_000_000_000
 HOUR = 3_600_000_000_000
+PATHS = ("fused", "host")
 
 
-def _mk_table(rng, n, key_space=400, slot_exact=False):
+def _answer(ft, path, qkeys, alo, ahi, ts, te, now=NOW):
+    """(qidx, slots) by the served path's two executions of a query."""
+    if path == "fused":
+        return ft.query_fused(qkeys, alo, ahi, ts, te, now=now)
+    res = ft.query_host_auto(qkeys, alo, ahi, ts, te, now=now)
+    assert res is not None, "the batch is under both host gates"
+    return res
+
+
+def _pack(recs, live_slots=None):
+    """Records -> FastTable over their sorted postings (slot = index)."""
+    pk, pe = [], []
+    for slot, r in enumerate(recs):
+        pk.extend(int(k) for k in r.keys)
+        pe.extend([slot] * len(r.keys))
+    pk = np.asarray(pk, np.int32)
+    pe = np.asarray(pe, np.int32)
+    order = np.argsort(pk, kind="stable")
+    pk, pe = pk[order], pe[order]
+    live = np.ones(len(recs), bool) if live_slots is None else live_slots
+    return FastTable(
+        pk,
+        pe,
+        np.asarray([recs[s].alt_lo for s in pe], np.float32),
+        np.asarray([recs[s].alt_hi for s in pe], np.float32),
+        np.asarray([recs[s].t_start for s in pe], np.int64),
+        np.asarray([recs[s].t_end for s in pe], np.int64),
+        live[pe],
+        slot_exact=dict(
+            alt_lo=np.asarray([r.alt_lo for r in recs], np.float32),
+            alt_hi=np.asarray([r.alt_hi for r in recs], np.float32),
+            t0=np.asarray([r.t_start for r in recs], np.int64),
+            t1=np.asarray([r.t_end for r in recs], np.int64),
+            live=live.copy(),
+        ),
+    )
+
+
+def _mk_table(rng, n, key_space=400):
     recs = []
     for i in range(n):
         nk = int(rng.integers(1, 10))
@@ -36,49 +73,11 @@ def _mk_table(rng, n, key_space=400, slot_exact=False):
                 owner_id=int(rng.integers(0, 5)),
             )
         )
-    # pack into postings
-    pk, pe = [], []
-    for slot, r in enumerate(recs):
-        pk.extend(int(k) for k in r.keys)
-        pe.extend([slot] * len(r.keys))
-    pk = np.asarray(pk, np.int32)
-    pe = np.asarray(pe, np.int32)
-    order = np.argsort(pk, kind="stable")
-    pk, pe = pk[order], pe[order]
-    se = None
-    if slot_exact:
-        se = dict(
-            alt_lo=np.asarray([r.alt_lo for r in recs], np.float32),
-            alt_hi=np.asarray([r.alt_hi for r in recs], np.float32),
-            t0=np.asarray([r.t_start for r in recs], np.int64),
-            t1=np.asarray([r.t_end for r in recs], np.int64),
-            live=np.ones(len(recs), bool),
-        )
-    ft = FastTable(
-        pk,
-        pe,
-        np.asarray([recs[s].alt_lo for s in pe], np.float32),
-        np.asarray([recs[s].alt_hi for s in pe], np.float32),
-        np.asarray([recs[s].t_start for s in pe], np.int64),
-        np.asarray([recs[s].t_end for s in pe], np.int64),
-        np.ones(len(pe), bool),
-        slot_exact=se,
-    )
-    return recs, ft
+    return recs, _pack(recs)
 
 
-def _exact_arrays(recs):
-    return dict(
-        records_alt_lo=np.asarray([r.alt_lo for r in recs], np.float32),
-        records_alt_hi=np.asarray([r.alt_hi for r in recs], np.float32),
-        records_t0=np.asarray([r.t_start for r in recs], np.int64),
-        records_t1=np.asarray([r.t_end for r in recs], np.int64),
-        records_live=np.ones(len(recs), bool),
-    )
-
-
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_fastpath_matches_oracle(use_pallas):
+@pytest.mark.parametrize("path", PATHS)
+def test_fastpath_matches_oracle(path):
     rng = np.random.default_rng(42)
     recs, ft = _mk_table(rng, 250)
     B, W = 8, 16
@@ -98,14 +97,7 @@ def test_fastpath_matches_oracle(use_pallas):
             ts[i] = NOW - 2 * HOUR
             te[i] = NOW + 2 * HOUR
 
-    qidx, offs = ft.query_batch(
-        qkeys, alo, ahi, ts, te, now=NOW,
-        use_pallas=use_pallas, interpret=use_pallas,
-    )
-    qidx, slots = ft.exact_filter(
-        qidx, offs, **_exact_arrays(recs),
-        alt_lo=alo, alt_hi=ahi, t_start=ts, t_end=te, now=NOW,
-    )
+    qidx, slots = _answer(ft, path, qkeys, alo, ahi, ts, te)
     recs_map = dict(enumerate(recs))
     for i in range(B):
         want = sorted(
@@ -120,16 +112,17 @@ def test_fastpath_matches_oracle(use_pallas):
             )
         )
         got = sorted(set(slots[qidx == i].tolist()))
-        assert got == want, f"query {i} (pallas={use_pallas})"
+        assert got == want, f"query {i} ({path})"
 
 
 @pytest.mark.parametrize("max_words", [1 << 14, 64, 8])
 def test_fused_path_matches_oracle(max_words):
     """The fused on-device decode path (submit/collect) must produce
     exactly the oracle result sets, including when the compaction
-    buffer overflows (max_words small -> legacy-path fallback)."""
+    buffer overflows (max_words small -> the kernel again at the hard
+    bound)."""
     rng = np.random.default_rng(43)
-    recs, ft = _mk_table(rng, 250, slot_exact=True)
+    recs, ft = _mk_table(rng, 250)
     B, W = 8, 16
     qkeys = np.full((B, W), -1, np.int32)
     alo = np.full(B, -np.inf, np.float32)
@@ -171,7 +164,7 @@ def test_fused_pipelined_submit_collect():
     """Many batches in flight at once resolve to the same results as
     one-at-a-time execution."""
     rng = np.random.default_rng(44)
-    recs, ft = _mk_table(rng, 300, slot_exact=True)
+    recs, ft = _mk_table(rng, 300)
     batches = []
     for b in range(6):
         B, W = 4, 16
@@ -196,8 +189,8 @@ def test_fused_pipelined_submit_collect():
         )
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_fastpath_hot_cell_long_run(use_pallas):
+@pytest.mark.parametrize("path", PATHS)
+def test_fastpath_hot_cell_long_run(path):
     """A cell with a postings run spanning many 128-blocks must return
     every entity (regression: the old fixed 2-block window dropped the
     tail of runs longer than ~256)."""
@@ -213,72 +206,80 @@ def test_fastpath_hot_cell_long_run(use_pallas):
         np.full(n + 10, NOW - HOUR, np.int64),
         np.full(n + 10, NOW + HOUR, np.int64),
         np.ones(n + 10, bool),
+        slot_exact=dict(
+            alt_lo=np.zeros(n, np.float32),
+            alt_hi=np.full(n, 100.0, np.float32),
+            t0=np.full(n, NOW - HOUR, np.int64),
+            t1=np.full(n, NOW + HOUR, np.int64),
+            live=np.ones(n, bool),
+        ),
     )
     qkeys = np.full((1, 16), -1, np.int32)
     qkeys[0, 0] = 7
-    qidx, offs = ft.query_batch(
-        qkeys,
+    _, slots = _answer(
+        ft, path, qkeys,
         np.full(1, -np.inf, np.float32),
         np.full(1, np.inf, np.float32),
         np.full(1, NO_TIME_LO, np.int64),
         np.full(1, NO_TIME_HI, np.int64),
-        now=NOW,
-        use_pallas=use_pallas,
-        interpret=use_pallas,
     )
-    slots = np.unique(ft.host_ent[offs])
+    slots = np.unique(slots)
     assert len(slots) == n, f"lost {n - len(slots)} of {n} entities"
 
 
-def test_fastpath_tombstones_and_subsecond_edges():
+@pytest.mark.parametrize("path", PATHS)
+def test_fastpath_tombstones_and_subsecond_edges(path):
+    """Both paths compare exact nanoseconds: an entity that ends 1 ns
+    before the window or starts 1 ns after it is out, one that touches
+    either end is in (a path that rounded to seconds would have to
+    keep all four and re-check), and a tombstone never appears."""
     rng = np.random.default_rng(1)
     recs, _ = _mk_table(rng, 20)
-    # one entity ends 1ns before the query window: quantization rounds
-    # its end UP to the next second (conservative), exact filter must
-    # then drop it
     t_q = NOW + HOUR
-    recs[0] = Record(
-        entity_id="edge",
-        keys=np.asarray([7], np.int32),
-        alt_lo=0.0,
-        alt_hi=100.0,
-        t_start=NOW - HOUR,
-        t_end=t_q - 1,  # ends 1ns before the window
-        owner_id=0,
-    )
-    pk, pe = [], []
-    for slot, r in enumerate(recs):
-        pk.extend(int(k) for k in r.keys)
-        pe.extend([slot] * len(r.keys))
-    pk, pe = np.asarray(pk, np.int32), np.asarray(pe, np.int32)
-    order = np.argsort(pk, kind="stable")
-    pk, pe = pk[order], pe[order]
-    live = pe != 3  # tombstone slot 3
-    ft = FastTable(
-        pk, pe,
-        np.asarray([recs[s].alt_lo for s in pe], np.float32),
-        np.asarray([recs[s].alt_hi for s in pe], np.float32),
-        np.asarray([recs[s].t_start for s in pe], np.int64),
-        np.asarray([recs[s].t_end for s in pe], np.int64),
-        live,
-    )
+
+    def edge(name, t0, t1):
+        return Record(
+            entity_id=name, keys=np.asarray([7], np.int32),
+            alt_lo=0.0, alt_hi=100.0, t_start=t0, t_end=t1, owner_id=0,
+        )
+
+    recs[0] = edge("ends-1ns-early", NOW - HOUR, t_q - 1)
+    recs[1] = edge("ends-at-the-start", NOW - HOUR, t_q)
+    recs[2] = edge("starts-at-the-end", t_q + HOUR, t_q + 2 * HOUR)
+    recs[4] = edge("starts-1ns-late", t_q + HOUR + 1, t_q + 2 * HOUR)
+    recs[3] = edge("tombstoned", NOW - HOUR, t_q + HOUR)
+    live = np.ones(len(recs), bool)
+    live[3] = False
+    ft = _pack(recs, live)
     qkeys = np.full((1, 16), -1, np.int32)
     qkeys[0, 0] = 7
     alo = np.full(1, -np.inf, np.float32)
     ahi = np.full(1, np.inf, np.float32)
     ts = np.asarray([t_q], np.int64)
     te = np.asarray([t_q + HOUR], np.int64)
-    qidx, offs = ft.query_batch(qkeys, alo, ahi, ts, te, now=NOW)
-    ex = _exact_arrays(recs)
-    ex["records_live"][3] = False
-    qidx2, slots = ft.exact_filter(
-        qidx, offs, **ex, alt_lo=alo, alt_hi=ahi, t_start=ts, t_end=te,
-        now=NOW,
+    _, slots = _answer(ft, path, qkeys, alo, ahi, ts, te)
+    got = sorted(set(slots.tolist()))
+    assert {1, 2} <= set(got) and not {0, 3, 4} & set(got)
+    del recs[3]  # the oracle knows no tombstones; slots above 3 shift
+    want = oracle.search(
+        dict(enumerate(recs)), np.asarray([7], np.int32), None, None,
+        int(ts[0]), int(te[0]), NOW,
     )
-    # the 1ns-early entity passed the coarse filter but not the exact one
-    assert 0 not in slots.tolist()
-    # tombstoned slot 3 never appears
-    assert 3 not in slots.tolist()
+    assert [g - (g > 3) for g in got] == sorted(want)
+
+
+def test_device_holds_the_exact_columns_only():
+    """24 B a padded posting on the device (two f32 altitudes, two i64
+    instants), and no table without the per-slot exact columns."""
+    recs, ft = _mk_table(np.random.default_rng(5), 40)
+    assert ft.device_bytes() == ft.n_blocks * BLOCK * 24
+    assert ft.n_blocks * BLOCK >= ft.n_postings
+    z = np.zeros(1, np.float32)
+    with pytest.raises(TypeError):
+        FastTable(
+            np.zeros(1, np.int32), np.zeros(1, np.int32), z, z,
+            np.zeros(1, np.int64), np.ones(1, np.int64), np.ones(1, bool),
+        )
 
 
 def test_query_host_matches_fused():

@@ -4,7 +4,12 @@ from __future__ import annotations
 
 import pytest
 
-from dss_tpu.obs.metrics import STAGE_NAMES, MetricsRegistry
+from dss_tpu.obs.metrics import (
+    STAGE_BUCKETS,
+    STAGE_NAMES,
+    MetricsRegistry,
+    stage_hist_quantile,
+)
 
 
 def test_render_counters_gauges_and_info():
@@ -151,3 +156,46 @@ def test_device_memory_gauges(monkeypatch, devices, want):
 
     monkeypatch.setattr(jax, "local_devices", lambda: devices)
     assert device_memory_stats() == want
+
+
+# -- stage_hist_quantile: the interpolation's edge cases -----------------
+
+
+def _hist_row(durations_ms):
+    """Cumulative stage-histogram row (counts, sum_s, cnt) exactly as
+    MetricsRegistry.observe_stage accumulates it."""
+    counts = [0] * len(STAGE_BUCKETS)
+    total = 0.0
+    for ms in durations_ms:
+        s = ms / 1000.0
+        for i, edge in enumerate(STAGE_BUCKETS):
+            if s <= edge:
+                counts[i] += 1
+        total += s
+    return tuple(counts), total, len(durations_ms)
+
+
+def test_quantile_empty_histogram_returns_none():
+    assert stage_hist_quantile((0,) * len(STAGE_BUCKETS), 0, 0.5) is None
+    assert stage_hist_quantile((), 0, 0.99) is None
+
+
+def test_quantile_single_occupied_bucket_interpolates():
+    """All mass in one bucket: quantiles interpolate linearly from the
+    previous edge, exactly like any other bucket."""
+    counts, _, cnt = _hist_row([3.0] * 100)  # all in (0.0025, 0.005]
+    q50 = stage_hist_quantile(counts, cnt, 0.50)
+    q99 = stage_hist_quantile(counts, cnt, 0.99)
+    assert 0.0025 < q50 < q99 <= 0.005
+    assert q50 == pytest.approx(0.0025 + 0.5 * 0.0025)
+
+
+def test_quantile_all_overflow_returns_last_edge_floor():
+    """Durations past the last bucket edge land in no bucket; the
+    quantile reports the last edge as a FLOOR rather than inventing a
+    number beyond the histogram's resolution."""
+    counts, _, cnt = _hist_row([5000.0] * 10)  # 5 s >> 1 s last edge
+    assert all(c == 0 for c in counts)
+    assert cnt == 10
+    assert stage_hist_quantile(counts, cnt, 0.99) == STAGE_BUCKETS[-1]
+    assert stage_hist_quantile(counts, cnt, 0.50) == STAGE_BUCKETS[-1]

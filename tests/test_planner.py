@@ -442,3 +442,46 @@ def test_plan_counters_in_stats_are_stable_keys():
     finally:
         co.close()
         table.close()
+
+
+# -- 4. the offline profile's provenance (plan/autotune.py) ---------------------
+
+
+def test_profile_staleness_flags_age_and_host_class():
+    from dss_tpu.plan.autotune import host_class, profile_staleness
+
+    now = 1_700_000_000.0
+    fresh = {"host_class": host_class(),
+             "measured_at": now - 3600.0}
+    st = profile_staleness(fresh, now=now)
+    assert st["has_timestamp"]
+    assert st["age_s"] == pytest.approx(3600.0)
+    assert st["host_class_match"]
+    stale = {"host_class": "somewhere-else/gpu", "measured_at": now}
+    st = profile_staleness(stale, now=now)
+    assert not st["host_class_match"]
+    # pre-versioning profile without a timestamp: age reads 0 (fresh)
+    # but the flag lets boot warn that nothing is actually known
+    st = profile_staleness({"host_class": host_class()}, now=now)
+    assert not st["has_timestamp"]
+    assert st["age_s"] == 0.0
+
+
+def test_autotune_profiles_carry_measured_at(monkeypatch, tmp_path):
+    """autotune() stamps measured_at so profile_staleness can age it;
+    the knob payload itself stays on the KNOB_KEYS allowlist."""
+    from dss_tpu.plan import autotune as at
+
+    def fake_measure(*a, **k):
+        return {"floor_ms": 2.0, "item_ms": 0.002, "chunk_ms": 0.2}
+
+    # keep the test off real kernel timing: patch the measurement core
+    # if present, otherwise run the real (CPU-cheap) path
+    for name in ("measure_device", "_measure"):
+        if hasattr(at, name):
+            monkeypatch.setattr(at, name, fake_measure)
+            break
+    prof = at.autotune()
+    assert "measured_at" in prof
+    assert prof["measured_at"] > 1_600_000_000.0
+    assert set(prof["knobs"]) <= set(at.KNOB_KEYS)

@@ -483,7 +483,7 @@ def test_match_feeds_stage_duration_histogram():
     histogram (route class "push") when the stage is given a registry
     handle — match runs on writer/pipeline threads with no
     thread-local stage sink, so the direct observe_stage call is the
-    only way the tuner/attribution ever sees it."""
+    only way the stage attribution ever sees it."""
     from dss_tpu.obs.metrics import MetricsRegistry
 
     store, clock = _seeded_store("memory")

@@ -27,6 +27,7 @@ from dss_tpu.ops.resident import (
     ResidentLoop,
     max_words_for,
 )
+from tests.test_deadline_routing import _host_route, _route
 
 NOW = 1_700_000_000_000_000_000
 HOUR = 3_600_000_000_000
@@ -370,21 +371,21 @@ def test_router_three_way_choice_without_live_device():
         co._res_loop = _NullLoop()
         batch = [object()] * 200
         # bulk (no deadlines): resident beats cold dispatch
-        assert co._choose_route(batch, None) == "resident"
+        assert _route(co, batch, None) == "resident"
         # rich headroom: resident latency fits the budget
-        assert co._choose_route(batch, 20.0) == "resident"
+        assert _route(co, batch, 20.0) == "resident"
         # headroom too tight even for resident (3 ms pred vs 1 ms
         # budget) and host cheaper -> hostchunk
-        assert co._choose_route(batch, 2.0) == "hostchunk"
+        assert _route(co, batch, 2.0) == "hostchunk"
         # ring full: resident inadmissible, cold device blows the
         # budget, host wins
         co._res_loop = _NullLoop(space=False)
-        assert co._choose_route(batch, 20.0) == "hostchunk"
+        assert _route(co, batch, 20.0) == "hostchunk"
         # no loop at all: identical to the two-route PR5 router
         co._res_loop = None
-        assert co._choose_route(batch, 20.0) == "hostchunk"
-        assert co._choose_route(batch, None) == "device"
-        assert co._choose_host_route(batch, 20.0) is True
+        assert _route(co, batch, 20.0) == "hostchunk"
+        assert _route(co, batch, None) == "device"
+        assert _host_route(co, batch, 20.0) is True
     finally:
         co.close()
         table.close()
@@ -402,9 +403,9 @@ def test_queued_resident_work_counts_in_prediction():
     try:
         co._res_loop = _NullLoop()
         batch = [object()] * 64
-        assert co._choose_route(batch, 20.0) == "resident"
+        assert _route(co, batch, 20.0) == "resident"
         co._inflight_resident = 8  # 9 floors = 36 ms > 10 ms budget
-        assert co._choose_route(batch, 20.0) == "hostchunk"
+        assert _route(co, batch, 20.0) == "hostchunk"
     finally:
         co.close()
         table.close()

@@ -191,7 +191,7 @@ def test_replica_tails_live_wal_into_sharded_dar(tmp_path):
     from dss_tpu.dar.dss_store import DSSStore
     from dss_tpu.geo import covering as geo_covering
     from dss_tpu.geo import s2cell
-    from dss_tpu.parallel.replica import ShardedOpReplica
+    from dss_tpu.parallel.replica import ShardedReplica
     from dss_tpu.services.scd import SCDService
 
     wal = tmp_path / "dss.wal"
@@ -199,7 +199,7 @@ def test_replica_tails_live_wal_into_sharded_dar(tmp_path):
     scd = SCDService(store.scd, store.clock)
 
     mesh = make_mesh(8, dp=2, sp=4)
-    rep = ShardedOpReplica(mesh, wal_path=str(wal))
+    rep = ShardedReplica(mesh, wal_path=str(wal))
 
     # first wave of ops
     ids1 = [str(uuid.uuid4()) for _ in range(5)]
@@ -421,12 +421,12 @@ def test_replica_demand_paced_refresh(tmp_path):
     restores the historical always-rebuild loop."""
     import time as _t
 
-    from dss_tpu.parallel.replica import ShardedOpReplica
+    from dss_tpu.parallel.replica import ShardedReplica
 
     wal = tmp_path / "dss.wal"
     wal.touch()
     mesh = make_mesh(8, dp=2, sp=4)
-    rep = ShardedOpReplica(mesh, wal_path=str(wal))
+    rep = ShardedReplica(mesh, wal_path=str(wal))
     rep.demand_pace_s = 5.0
     now = _t.monotonic()
 
