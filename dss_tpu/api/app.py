@@ -367,7 +367,9 @@ def _freshness_json_response(request, data) -> web.Response:
     """json_response carrying the X-DSS-Freshness header when the
     service call left a note: region epoch + DAR write generation +
     cache hit/miss, so operators can verify the version fence from
-    the wire without reading code.  When the store's degradation
+    the wire without reading code.  `data` as `bytes` is a body the
+    service has already encoded (the two record searches) and is
+    written as it is.  When the store's degradation
     ladder is non-healthy the header additionally carries
     `;mode=<condition>` — a degraded answer (hostchunk-only serving,
     fenced-cache reads during a region outage) is honest about it."""
@@ -413,6 +415,11 @@ def _freshness_json_response(request, data) -> web.Response:
             if fed["mode"] == "stale":
                 val += f";lag={fed['lag_s']:.3f}"
         headers = {"X-DSS-Freshness": val}
+    if isinstance(data, bytes):
+        return web.Response(
+            body=data, headers=headers,
+            content_type="application/json", charset="utf-8",
+        )
     return web.json_response(data, headers=headers)
 
 

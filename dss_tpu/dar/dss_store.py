@@ -82,11 +82,14 @@ def _copy_rec(rec):
     ~1.5x cheaper — per-record assembly is the read path's largest
     single cost at poll-heavy hit rates.
 
-    Only the RECORD-level depth of a search copies (`_assemble`: the
-    callers that hand records to a service or a precheck).  The
+    Only the COPYING record-level depth of a search does this
+    (`_assemble`: the callers that change or keep what they get — the
+    OVN precheck, the conflict listing, federation).  The stored depth
+    (`_stored`: the services' encoder, which only reads) and the
     id-level depth (`_id_answer`: what DSSStore.shm_serve puts in a
-    ring slot) never does — a slot carries ids and end times, and the
-    worker that asked assembles the records from its own replica."""
+    ring slot) never do — a slot carries ids and end times, and the
+    worker that asked joins the answer from its own replica's
+    records."""
     return copy.copy(rec)
 
 
@@ -213,14 +216,27 @@ class _CachedSearchMixin:
     then) unless the answer came from the bounded-stale mesh replica,
     which must never be stamped as fresh.
 
-    One search, two depths.  Each class's `_<cls>_ids` defines the
+    One search, three depths.  Each class's `_<cls>_ids` defines the
     search once and returns what `_cached_ids` found; the record level
-    (`_assemble`) copies the records out for a service or a precheck,
-    the id level (`_id_answer`) pairs each id with its end time for a
-    shared-memory ring slot.  Neither depth knows who calls it."""
+    copies the records out for a caller that changes or keeps them
+    (`_assemble`: a precheck, the conflict listing, federation) or
+    hands them out as they are stored to one that only reads
+    (`_stored`: the services' encoder); the id level (`_id_answer`)
+    pairs each id with its end time for a shared-memory ring slot.  No
+    depth knows who calls it."""
 
     _cache: Optional[rcache.ReadCache] = None
     _epoch_fn = staticmethod(lambda: "")
+    # records of this store's search answers, by whether their wire
+    # bytes were remembered or encoded (serialization.*_body)
+    _wire_memo_hits = 0
+    _wire_memo_misses = 0
+
+    def note_wire_memo(self, hits: int, misses: int) -> None:
+        # unlocked read-modify-write from the service's threads: a
+        # lost update is a count off by one answer, never a wrong one
+        self._wire_memo_hits += hits
+        self._wire_memo_misses += misses
 
     def _init_cache(self, cache, epoch_fn):
         self._cache = cache
@@ -337,17 +353,18 @@ class _CachedSearchMixin:
         return ids, t1s
 
     @staticmethod
-    def _assemble(ids, recs: dict) -> list:
-        """The record-level depth: a defensive copy of each found id's
-        record, in the order given.  .get(): a concurrent delete
-        between the index query and this assembly must skip, not
-        KeyError (reads are lock-free)."""
-        out = []
-        for i in ids:
-            rec = recs.get(i)
-            if rec is not None:
-                out.append(_copy_rec(rec))
-        return out
+    def _stored(ids, recs: dict) -> list:
+        """The record-level depth for a caller that only reads: each
+        found id's record as it is stored, in the order given.  .get():
+        a concurrent delete between the index query and this pass must
+        skip, not KeyError (reads are lock-free)."""
+        return [rec for rec in map(recs.get, ids) if rec is not None]
+
+    @classmethod
+    def _assemble(cls, ids, recs: dict) -> list:
+        """The record-level depth for a caller that may change what it
+        gets: a defensive copy of each record `_stored` finds."""
+        return [_copy_rec(rec) for rec in cls._stored(ids, recs)]
 
 
 class TimestampOracle:
@@ -560,6 +577,12 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
             cells, earliest, latest, allow_stale=allow_stale
         )
         return self._assemble(ids, self._isas)
+
+    def stored_isas(self, cells, earliest, latest, *, allow_stale=False):
+        ids, _ = self._isa_ids(
+            cells, earliest, latest, allow_stale=allow_stale
+        )
+        return self._stored(ids, self._isas)
 
     # -- Subscriptions -------------------------------------------------------
 
@@ -929,6 +952,16 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         return self._search_ops(
             cells, alt_lo, alt_hi, earliest, latest, allow_stale=allow_stale
         )
+
+    def stored_operations(
+        self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
+    ):
+        if len(np.asarray(cells).ravel()) == 0:
+            raise errors.bad_request("missing cell IDs for query")
+        ids, _ = self._op_ids(
+            cells, alt_lo, alt_hi, earliest, latest, allow_stale=allow_stale
+        )
+        return self._stored(sorted(ids), self._ops)
 
     def _cst_ids(
         self, cells, alt_lo, alt_hi, earliest, latest, *, allow_stale=False
@@ -1895,6 +1928,15 @@ class DSSStore:
         # cache is enabled or not — dashboards expect the series)
         for k, v in self.cache.stats().items():
             out[f"dss_cache_{k}"] = v
+        # search answers' records by whether this process remembered
+        # their wire bytes or encoded them (a --workers front counts
+        # in the shared block instead: dss_shm_worker_wire_memo_*)
+        out["dss_wire_memo_hits"] = (
+            self.rid._wire_memo_hits + self.scd._wire_memo_hits
+        )
+        out["dss_wire_memo_misses"] = (
+            self.rid._wire_memo_misses + self.scd._wire_memo_misses
+        )
         # per-key-range load accounting (the skew-aware rebalancer's
         # measurement input)
         for k, v in self.range_load.stats().items():

@@ -28,7 +28,11 @@ Record assembly happens HERE, from the worker's replica dicts, in the
 exact per-class order the leader-side store methods use — so a
 worker-served response is bit-identical to a leader-served one at the
 same state (tests/test_shmring.py pins this across folds, compactions
-and tombstones).
+and tombstones).  Two depths, as in the leader's store: `stored_*`
+hands the replica's own records, uncopied, to the services' encoder
+(which only reads, and remembers each record's wire bytes on it:
+services/serialization.py); `search_*` copies them for a caller that
+changes or keeps what it gets.
 
 Subscription classes (rid_sub / scd_sub) deliberately skip the
 worker-local cache: their records carry notification indexes that
@@ -324,20 +328,24 @@ class ShmSearchFront:
             )
             off_ns += ns
 
-    def assemble(self, ids: List[str], recs: dict) -> list:
-        """Order-preserving record assembly from the worker replica's
-        dict — the same shallow-copy discipline as the leader's
-        search assembly.  A missing record (replica catchup timed out
-        mid-burst) is skipped and counted, exactly like the leader's
-        vanished-mid-assembly case."""
-        out = []
-        for i in ids:
-            rec = recs.get(i)
-            if rec is None:
-                self.client.stat_add(shmring.WS_ASSEMBLY_MISSES)
-                continue
-            out.append(copy.copy(rec))
+    def stored(self, ids: List[str], recs: dict) -> list:
+        """The answer's records as the worker replica's dict holds
+        them, in the answer's order, for a caller that only reads (the
+        services' encoder).  A missing record (replica catchup timed
+        out mid-burst) is skipped and counted, exactly like the
+        leader's vanished-mid-assembly case."""
+        out = [rec for rec in map(recs.get, ids) if rec is not None]
+        if len(out) != len(ids):
+            self.client.stat_add(
+                shmring.WS_ASSEMBLY_MISSES, len(ids) - len(out)
+            )
         return out
+
+    def assemble(self, ids: List[str], recs: dict) -> list:
+        """`stored` for a caller that changes or keeps what it gets —
+        the same shallow-copy discipline as the leader's search
+        assembly."""
+        return [copy.copy(rec) for rec in self.stored(ids, recs)]
 
 
 class _Wrapper:
@@ -351,12 +359,30 @@ class _Wrapper:
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
+    def note_wire_memo(self, hits: int, misses: int) -> None:
+        # in the shared block, so that a scrape of either worker reads
+        # the front's truth (the inner replica store's own pair stays 0)
+        self._front.client.stat_add(shmring.WS_WIRE_MEMO_HITS, hits)
+        self._front.client.stat_add(shmring.WS_WIRE_MEMO_MISSES, misses)
+
 
 class ShmRIDStore(_Wrapper):
     """RID search surface over the ring; every other method delegates
     to the WAL-tail replica store."""
 
     def search_isas(self, cells, earliest, latest, *, allow_stale=False):
+        return self._front.assemble(
+            self._isa_ids(cells, earliest, latest, allow_stale),
+            self._inner._isas,
+        )
+
+    def stored_isas(self, cells, earliest, latest, *, allow_stale=False):
+        return self._front.stored(
+            self._isa_ids(cells, earliest, latest, allow_stale),
+            self._inner._isas,
+        )
+
+    def _isa_ids(self, cells, earliest, latest, allow_stale):
         if len(np.asarray(cells).ravel()) == 0:
             raise errors.bad_request("missing cell IDs for query")
         if earliest is None:
@@ -369,12 +395,11 @@ class ShmRIDStore(_Wrapper):
         # drives the t_end >= now filter the cache re-applies at
         # lookup — keying it would make every repeat poll a unique,
         # never-hit line
-        ids = self._front.serve(
+        return self._front.serve(
             "isa", cells, qkey=(l_ns,), now_ns=e_ns,
             t0_ns=e_ns, t1_ns=l_ns, allow_stale=allow_stale,
             cacheable=True,
         )
-        return self._front.assemble(ids, self._inner._isas)
 
     def search_subscriptions_by_owner(self, cells, owner):
         if len(np.asarray(cells).ravel()) == 0:
@@ -404,25 +429,33 @@ class ShmSCDStore(_Wrapper):
 
     def search_operations(self, cells, alt_lo, alt_hi, earliest,
                           latest, *, allow_stale=False):
-        if len(np.asarray(cells).ravel()) == 0:
-            raise errors.bad_request("missing cell IDs for query")
-        return self._search_ops_ids_to_recs(
-            canonical_cells(cells), alt_lo, alt_hi,
-            None if earliest is None else to_nanos(earliest),
-            None if latest is None else to_nanos(latest),
-            self._front.now_ns(), allow_stale,
+        return self._front.assemble(
+            self._op_ids(cells, alt_lo, alt_hi, earliest, latest,
+                         allow_stale),
+            self._inner._ops,
         )
 
-    def _search_ops_ids_to_recs(self, cells, alt_lo, alt_hi, t0_ns,
-                                t1_ns, now_ns, allow_stale):
-        ids = self._front.serve(
-            "op", cells,
+    def stored_operations(self, cells, alt_lo, alt_hi, earliest,
+                          latest, *, allow_stale=False):
+        return self._front.stored(
+            self._op_ids(cells, alt_lo, alt_hi, earliest, latest,
+                         allow_stale),
+            self._inner._ops,
+        )
+
+    def _op_ids(self, cells, alt_lo, alt_hi, earliest, latest,
+                allow_stale):
+        if len(np.asarray(cells).ravel()) == 0:
+            raise errors.bad_request("missing cell IDs for query")
+        t0_ns = None if earliest is None else to_nanos(earliest)
+        t1_ns = None if latest is None else to_nanos(latest)
+        return self._front.serve(
+            "op", canonical_cells(cells),
             qkey=self._op_qkey(alt_lo, alt_hi, t0_ns, t1_ns),
-            now_ns=now_ns, alt_lo=alt_lo, alt_hi=alt_hi,
+            now_ns=self._front.now_ns(), alt_lo=alt_lo, alt_hi=alt_hi,
             t0_ns=t0_ns, t1_ns=t1_ns, allow_stale=allow_stale,
             cacheable=True,
         )
-        return self._front.assemble(ids, self._inner._ops)
 
     def search_constraints(self, cells, alt_lo, alt_hi, earliest,
                            latest, *, allow_stale=False):

@@ -68,6 +68,21 @@ class RIDStore(abc.ABC):
         """ISAs intersecting cells with ends_at >= earliest and
         (starts_at <= latest or latest is None)."""
 
+    def stored_isas(self, cells, earliest, latest, *, allow_stale=False):
+        """`search_isas` for a caller that only READS what it gets (the
+        service's encoder, serialization.isas_body): a store that keeps
+        its records hands them out as they are stored, uncopied, and
+        what is encoded for a record is then remembered with it.  This
+        default serves a store without that depth from its copies."""
+        return self.search_isas(
+            cells, earliest, latest, allow_stale=allow_stale
+        )
+
+    def note_wire_memo(self, hits: int, misses: int) -> None:
+        """Count a search answer's records by whether their bytes were
+        remembered (hits) or encoded (misses).  A store that exports
+        the pair overrides this."""
+
     # Subscriptions
     @abc.abstractmethod
     def get_subscription(self, id: str) -> Optional[ridm.Subscription]:
@@ -158,6 +173,19 @@ class SCDStore(abc.ABC):
         latest: Optional[datetime],
     ) -> List[scdm.Operation]:
         ...
+
+    def stored_operations(self, cells, alt_lo, alt_hi, earliest, latest,
+                          *, allow_stale=False):
+        """`search_operations` for a caller that only READS what it
+        gets (serialization.operations_body); see
+        RIDStore.stored_isas."""
+        return self.search_operations(
+            cells, alt_lo, alt_hi, earliest, latest,
+            allow_stale=allow_stale,
+        )
+
+    def note_wire_memo(self, hits: int, misses: int) -> None:
+        """See RIDStore.note_wire_memo."""
 
     # Subscriptions
     @abc.abstractmethod

@@ -179,6 +179,11 @@ WS_PLAN_PROXY = 13
 # until the wait's backstop ran out (a lost or late wake)
 WS_WAKES = 14
 WS_WAKE_BACKSTOPS = 15
+# records of this worker's search answers, by whether their wire bytes
+# were joined from what the record remembered or encoded (and then
+# remembered): services/serialization.py isas_body, operations_body
+WS_WIRE_MEMO_HITS = 16
+WS_WIRE_MEMO_MISSES = 17
 WSTAT_NAMES = {
     WS_ENQUEUED: "enqueued",
     WS_SERVED: "served",
@@ -194,6 +199,8 @@ WSTAT_NAMES = {
     WS_PLAN_PROXY: "plan_proxy",
     WS_WAKES: "wakes",
     WS_WAKE_BACKSTOPS: "wake_backstops",
+    WS_WIRE_MEMO_HITS: "wire_memo_hits",
+    WS_WIRE_MEMO_MISSES: "wire_memo_misses",
 }
 
 _OWNER_MAX = 120  # bytes of utf-8 owner scope a slot can carry

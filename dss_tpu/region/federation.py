@@ -1205,6 +1205,9 @@ class FederatedRIDStore(RIDStore):
     def transaction(self):
         return self._local.transaction()
 
+    def note_wire_memo(self, hits, misses):
+        self._local.note_wire_memo(hits, misses)
+
     # -- point reads / write-path internals: local -------------------------
 
     def get_isa(self, id):
@@ -1293,6 +1296,9 @@ class FederatedSCDStore(SCDStore):
 
     def transaction(self):
         return self._local.transaction()
+
+    def note_wire_memo(self, hits, misses):
+        self._local.note_wire_memo(hits, misses)
 
     # -- point reads: local ------------------------------------------------
 

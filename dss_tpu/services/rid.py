@@ -157,7 +157,10 @@ class RIDService:
         area: str,
         earliest_time: Optional[str] = None,
         latest_time: Optional[str] = None,
-    ) -> dict:
+    ) -> bytes:
+        """-> the finished body, `{"service_areas": [...]}` as JSON:
+        joined from the bytes each record was encoded to once
+        (ser.isas_body)."""
         with stages.stage("covering_ms"):
             cells = _area_to_cells(area or "")
         earliest = latest = None
@@ -177,12 +180,16 @@ class RIDService:
             earliest = now
         with stages.stage("store_ms"):
             # allow_stale: a public search may ride the mesh replica
-            # when its batch is oversized and the replica is fresh
-            isas = self.store.search_isas(
+            # when its batch is oversized and the replica is fresh.
+            # stored_: the encoder only reads, so a store that keeps
+            # its records hands them out uncopied
+            isas = self.store.stored_isas(
                 cells, earliest, latest, allow_stale=True
             )
         with stages.stage("serialize_ms"):
-            return {"service_areas": [ser.isa_to_json(i) for i in isas]}
+            body, hits = ser.isas_body(isas)
+            self.store.note_wire_memo(hits, len(isas) - hits)
+            return body
 
     # -- Subscriptions (subscription_handler.go + application/subscription.go)
 

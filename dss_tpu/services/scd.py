@@ -256,24 +256,26 @@ class SCDService:
             "subscribers": ser.scd_subscribers_to_notify_json(subs),
         }
 
-    def search_operations(self, params: dict, owner: str) -> dict:
+    def search_operations(self, params: dict, owner: str) -> bytes:
+        """-> the finished body, `{"operation_references": [...]}` as
+        JSON: joined from the bytes each record was encoded to once
+        (ser.operations_body), OVNs of other owners' records blank."""
         vol4, cells = _aoi_to_covering(params)
         sv = vol4.spatial_volume
         # allow_stale: public search may ride the mesh replica for
         # oversized batches (the conflict-response listing at :117 must
-        # NOT — it feeds the OVN key the client will retry with)
+        # NOT — it feeds the OVN key the client will retry with).
+        # stored_: the encoder only reads, so a store that keeps its
+        # records hands them out uncopied
         with stages.stage("store_ms"):
-            ops = self.store.search_operations(
+            ops = self.store.stored_operations(
                 cells, sv.altitude_lo, sv.altitude_hi, vol4.start_time,
                 vol4.end_time, allow_stale=True,
             )
         with stages.stage("serialize_ms"):
-            out = []
-            for op in ops:
-                if op.owner != owner:
-                    op.ovn = ""
-                out.append(ser.op_to_json(op))
-            return {"operation_references": out}
+            body, hits = ser.operations_body(ops, owner)
+            self.store.note_wire_memo(hits, len(ops) - hits)
+            return body
 
     # -- Subscriptions -------------------------------------------------------
 

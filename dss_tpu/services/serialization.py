@@ -9,9 +9,11 @@ altitudes as {"value": ..., "reference": "W84", "units": "M"}
 
 from __future__ import annotations
 
+import json
 import re
+import weakref
 from datetime import datetime, timezone
-from typing import Optional
+from typing import Optional, Tuple
 
 from dss_tpu import errors
 from dss_tpu.models import rid as ridm
@@ -335,3 +337,74 @@ def scd_subscribers_to_notify_json(subs) -> list:
         {"uss_base_url": url, "subscriptions": states}
         for url, states in by_url.items()
     ]
+
+
+# ---------------------------------------------------------------------------
+# Search bodies: the wire form of a record, encoded once per record object
+# ---------------------------------------------------------------------------
+
+# A stored record gives the same bytes until it is written again, and a
+# write installs a NEW object under the id (dar/dss_store.py never
+# stores a field of a stored record), so what was encoded is remembered
+# ON the object, outside its dataclass fields, and dies with it: there
+# is nothing to invalidate.  The entry opens with a weak reference to
+# the object it was made for and counts only while that `is` the record
+# in hand: `copy.copy` carries `__dict__` along, and a copy (which a
+# caller may have changed) must never answer with its original's bytes.
+_WIRE = "_wire"
+
+
+def _joined(
+    key: bytes, recs, public_json, owner_json, owner
+) -> Tuple[bytes, int]:
+    """-> (`json.dumps({key: [doc of each record]})` byte for byte,
+    each record's element taken from what the record remembers or
+    encoded now and remembered; how many were taken).  A record
+    remembers two forms: `public_json(rec)`, and from the first time
+    its owner asks `owner_json(rec)`.  Which one a requester gets is
+    decided here, per record, from `rec.owner == owner` alone: a
+    non-owner cannot reach the owner's form."""
+    parts, hits = [], 0
+    for rec in recs:
+        own = rec.owner == owner
+        memo = rec.__dict__.get(_WIRE)
+        if memo is not None and memo[0]() is rec:
+            enc = memo[2] if own else memo[1]
+            if enc is not None:
+                hits += 1
+                parts.append(enc)
+                continue
+            ref, public, private = memo
+        else:
+            ref, public, private = weakref.ref(rec), None, None
+        # what web.json_response would have written for this element
+        enc = json.dumps(
+            owner_json(rec) if own else public_json(rec)
+        ).encode("utf-8")
+        if own:
+            private = enc
+        else:
+            public = enc
+        rec.__dict__[_WIRE] = (ref, public, private)
+        parts.append(enc)
+    return b'{"' + key + b'": [' + b", ".join(parts) + b"]}", hits
+
+
+def isas_body(isas) -> Tuple[bytes, int]:
+    """-> (the search answer `json.dumps({"service_areas":
+    [isa_to_json(i) ...]})` gives, byte for byte; how many of its
+    records were joined from remembered bytes).  An ISA has one form."""
+    return _joined(b"service_areas", isas, isa_to_json, isa_to_json, None)
+
+
+def _op_public_json(op: scdm.Operation) -> dict:
+    return {**op_to_json(op), "ovn": ""}  # OVNs are private to the owner
+
+
+def operations_body(ops, owner: str) -> Tuple[bytes, int]:
+    """-> (`json.dumps({"operation_references": [op_to_json(op) ...]})`
+    with the OVN of every record `owner` does not own blanked, byte for
+    byte; how many records were joined from remembered bytes)."""
+    return _joined(
+        b"operation_references", ops, _op_public_json, op_to_json, owner
+    )

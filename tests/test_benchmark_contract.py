@@ -152,3 +152,17 @@ def test_the_program_exports_what_the_metric_reads(metric, served,
             f"dssbench/metrics/{name}.json reads {pat} from the "
             f"{args['proc']}'s /metrics: the program exports no such family"
         )
+
+
+@pytest.mark.parametrize("stage", ["store_ms", "serialize_ms"])
+def test_a_search_is_still_timed_around_its_store_and_its_encoder(
+        stage, served):
+    """`http_host_ms_mean` reads `serialize_ms`, and PERF.md's
+    breakdowns `store_ms` less the ring: both stages stay around what
+    they named when a search answer became a join of remembered bytes
+    (the records found; the body made), observed on every search."""
+    fam = "dss_stage_duration_seconds_count"
+    n = served["front"].get(f'{fam}{{route="search",stage="{stage}"}}', 0)
+    assert n > 0 and n == served["front"].get(
+        f'{fam}{{route="search",stage="service_ms"}}'
+    ), (stage, n)

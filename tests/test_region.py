@@ -34,6 +34,7 @@ from dss_tpu.region.log_server import build_region_app
 from dss_tpu.services.rid import RIDService
 from dss_tpu.services.scd import SCDService
 from dss_tpu.services.serialization import format_time
+from tests.wire import body_json
 
 POLL_S = 0.02  # tail-poll interval for all test instances
 # generous vs the 20 ms poll: on a contended 1-core CI host the
@@ -253,9 +254,9 @@ def test_rid_update_and_search_across_instances(region):
 
     # C's search converges to v2
     def see_v2():
-        hits = services[2].search_isas(
+        hits = body_json(services[2].search_isas(
             "37.0,-122.0,37.06,-122.0,37.06,-122.06,37.0,-122.06"
-        )["service_areas"]
+        ))["service_areas"]
         return next(
             (h for h in hits if h["id"] == isa_id and h["version"] == v2), None
         )
@@ -500,9 +501,9 @@ def test_region_mode_on_tpu_storage(region):
             "uss1",
         )
         # visible via the fused path on the tpu instance itself
-        hits = svc.search_isas(
+        hits = body_json(svc.search_isas(
             "37.0,-122.0,37.06,-122.0,37.06,-122.06,37.0,-122.06"
-        )["service_areas"]
+        ))["service_areas"]
         assert any(h["id"] == isa_id for h in hits)
         # and on a memory-backed peer
         wait_until(lambda: stores[0].rid.get_isa(isa_id))

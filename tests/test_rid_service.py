@@ -11,6 +11,7 @@ from dss_tpu.dar.dss_store import DSSStore
 from dss_tpu.services.rid import RIDService
 from dss_tpu.services.serialization import format_time
 from tests.test_store_contract import T0
+from tests.wire import body_json
 
 ISA_ID = "11111111-1111-4111-8111-111111111111"
 SUB_ID = "22222222-2222-4222-8222-222222222222"
@@ -68,7 +69,7 @@ def test_isa_crud_lifecycle(svc):
     got = svc.get_isa(ISA_ID)["service_area"]
     assert got["version"] == isa["version"]
 
-    found = svc.search_isas(AREA)
+    found = body_json(svc.search_isas(AREA))
     assert [a["id"] for a in found["service_areas"]] == [ISA_ID]
 
     updated = svc.update_isa(ISA_ID, isa["version"], isa_params(), "uss1")

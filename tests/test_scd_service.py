@@ -12,6 +12,7 @@ from dss_tpu.dar.dss_store import DSSStore
 from dss_tpu.services.scd import SCDService
 from dss_tpu.services.serialization import format_time
 from tests.test_store_contract import T0
+from tests.wire import body_json
 
 OP1 = "aaaaaaaa-aaaa-4aaa-8aaa-aaaaaaaaaaa1"
 OP2 = "aaaaaaaa-aaaa-4aaa-8aaa-aaaaaaaaaaa2"
@@ -130,15 +131,15 @@ def test_op_update_requires_own_ovn(svc):
 
 def test_op_search(svc):
     svc.put_operation(OP1, op_params(), "uss1")
-    found = svc.search_operations(
+    found = body_json(svc.search_operations(
         {"area_of_interest": scd_extent()}, "uss2"
-    )["operation_references"]
+    ))["operation_references"]
     assert [o["id"] for o in found] == [OP1]
     assert found[0]["ovn"] == ""  # stripped for non-owner
     # disjoint area
-    found = svc.search_operations(
+    found = body_json(svc.search_operations(
         {"area_of_interest": scd_extent(lat=-40.0, lng=100.0)}, "uss2"
-    )["operation_references"]
+    ))["operation_references"]
     assert found == []
     with pytest.raises(errors.StatusError):
         svc.search_operations({}, "uss2")

@@ -37,6 +37,7 @@ from dss_tpu.dar.dss_store import DSSStore
 from dss_tpu.services.rid import RIDService
 from dss_tpu.services.scd import SCDService
 from dss_tpu.services.serialization import format_time
+from tests.wire import body_json
 
 BASE_LAT, BASE_LNG = 40.0, -100.0
 
@@ -82,7 +83,9 @@ def _search_area(rng):
 def _norm_outcome(fn, *args):
     """-> ('ok', normalized-result) or ('err', status, code)."""
     try:
-        return ("ok", fn(*args))
+        out = fn(*args)
+        # the record searches answer with the finished body
+        return ("ok", body_json(out) if isinstance(out, bytes) else out)
     except errors.StatusError as e:
         return ("err", e.http_status, int(e.code))
 
