@@ -7,6 +7,25 @@ on wrong answers is 0.  A refusal (429/503/504) or a late answer is
 late, not wrong: it costs latency and goodput; only an answer that
 never comes, or says the wrong thing, is for `correct`.
 
+Where the traffic writes (planned flights, traffic.py `scd_put`), the
+reference changes under the window: beside the WAL's static sets stands
+deploy.Written, the flights of this run with the instants they were
+first sent and acknowledged.  Every exchange, a search or one PUT of a
+chain, sent at s and done at d, is then judged by must / may:
+
+  must  the static answer and every flight acknowledged before s that
+        matches the volume;
+  may   every matching flight whose [first sent, acknowledged or never)
+        overlaps [s, d];
+
+a search or a 409's listing is right iff must <= got <= must | may; a
+200 is wrong where its key misses a must conflict, a 409 where its key
+held must | may entirely; a 200's subscribers are held to the same
+rule over the subscriptions that share a cell with the flight.  That is
+"reads: strong" as far as a run can show: an acknowledged write is read
+back by every later search, through whichever worker answers it, and
+nothing unwritten appears.
+
 The controls are the reference put in the program's place with one
 stated guarantee broken:
 
@@ -14,7 +33,10 @@ stated guarantee broken:
            WAL's records (a replica that lags, an acknowledged write
            lost);
   lowprec  the DAR's stated widths — altitudes in float16 for float32,
-           times in float32 seconds for int64 nanoseconds.
+           times in float32 seconds for int64 nanoseconds;
+  lost_write  strong reads of the window's own writes — every 100th
+           flight is acknowledged and then forgotten (only where the
+           traffic writes: a read-only cell has nothing to lose).
 
 `python -m dssbench.check --workload <cell> --seeds a,b,c` puts each,
 on the cell's own data and the window's own requests, through the same
@@ -55,24 +77,132 @@ def expected(req, comp: dict, metro, ref, now_ns: int) -> set:
     )
 
 
+def _is_op(comp: dict) -> bool:
+    return tr.ENDPOINTS[comp["endpoint"]]["class"] == "op"
+
+
 def answered_ids(comp: dict, body: bytes) -> set:
     doc = json.loads(body)
     return {e["id"] for e in doc[tr.ENDPOINTS[comp["endpoint"]]["answer"]]}
 
 
-def compare(traffic: dict, requests: list, out, metro, ref) -> dict:
+def notified(body: bytes) -> tuple:
+    """(the subscription ids a 200 says to notify, the flight's own)."""
+    doc = json.loads(body)
+    return ({s["subscription_id"] for grp in doc["subscribers"]
+             for s in grp["subscriptions"]},
+            doc["operation_reference"]["subscription_id"])
+
+
+def matching(cols: dict, req, cells_only: bool = False) -> np.ndarray:
+    """Which of the written flights a request's volume meets, by the
+    plain semantics of EntitySet.search: shares a cell (both are
+    rectangles of metro cells) AND altitudes overlap AND times overlap.
+    An untimed search starts at the server's now, hours before any
+    flight opens."""
+    i, j, w, h = req.rect
+    hit = ((cols["i0"] < i + w) & (cols["i1"] > i)
+           & (cols["j0"] < j + h) & (cols["j1"] > j))
+    if cells_only:
+        return hit
+    if req.alt is not None:
+        hit &= (cols["hi"] >= req.alt[0]) & (cols["lo"] <= req.alt[1])
+    if req.when is not None:
+        hit &= (cols["t1"] >= req.when[0]) & (cols["t0"] <= req.when[1])
+    return hit
+
+
+def must_may(cols: dict, hit: np.ndarray, s: float, d: float) -> tuple:
+    """Of the flights `hit`, (those acknowledged before s, those that
+    may or may not stand for an exchange sent at s and done at d)."""
+    must = hit & (cols["acked"] < s)
+    return must, hit & ~must & (cols["first_sent"] <= d)
+
+
+def compare(traffic: dict, requests: list, out, metro, ref,
+            written=None) -> dict:
     """-> {"good": per-request bool (a right answer, whenever it came),
-    "numbers": {name: value}, "first_wrong": str}.  The reference is
-    taken after the answers: both clocks only move forward and no
-    record ends within an hour of the run."""
+    "numbers": {name: value}, "first_wrong": str, "read_back": per
+    request, whether a flight of this run was due in its answer}.  The
+    static reference is taken after the answers: both clocks only move
+    forward and no record ends within an hour of the run; `written`
+    (deploy.Written) is what the run itself wrote, by must / may."""
     now_ns = time.time_ns()
     comps = traffic["components"]
+    cols = (written or deploy.Written()).columns()
+    row_of = {i: n for n, i in enumerate(cols["ids"])}
+    known = np.array([x is not None for x in cols["subs"]], bool)
     good = np.zeros(len(requests), bool)
+    read_back = np.zeros(len(requests), bool)
     memo = {}
     wrong = never = refused = compared = 0
     first = ""
+
+    def static(req):
+        key = (req.comp, req.rect, req.alt, req.when)
+        if key not in memo:
+            memo[key] = expected(req, comps[req.comp], metro, ref, now_ns)
+        return memo[key]
+
+    def others(req, s, d, cells_only=False):
+        """must_may over the flights that req meets, itself left out."""
+        hit = matching(cols, req, cells_only)
+        if req.id in row_of:
+            hit[row_of[req.id]] = False
+        return must_may(cols, hit, s, d)
+
+    def sets(req, s, d):
+        """(must, must | may, whether a flight is in must) of an
+        exchange over req's own volume."""
+        want = static(req)
+        if not len(row_of) or not _is_op(comps[req.comp]):
+            return want, want, False
+        must, may = others(req, s, d)
+        want = want | set(cols["ids"][must])
+        return want, want | set(cols["ids"][may]), bool(must.any())
+
+    def chain_fault(req, chain) -> str:
+        """What is wrong with a chain's exchanges, '' if nothing."""
+        for n, ex in enumerate(chain):
+            s, d = out.t_open + ex.sent, out.t_open + ex.done
+            must, allowed, _ = sets(req, s, d)
+            key = set(ex.key)
+            if ex.status == 409 and ex.listed is not None:
+                got = set(ex.listed)
+                if not must <= got <= allowed:
+                    return (f"PUT {n} listed {len(got - allowed)} "
+                            f"unexpected, {len(must - got)} missing of "
+                            f"{len(must)} conflicts")
+                if allowed <= key:
+                    return f"PUT {n} refused though its key held all"
+            elif ex.status == 200:
+                if not must <= key:
+                    return (f"PUT {n} accepted past {len(must - key)} "
+                            "conflicts its key did not hold")
+                try:
+                    got, own = notified(ex.body)
+                except (ValueError, KeyError, TypeError):
+                    return f"PUT {n}: unreadable answer"
+                before, during = others(req, s, d, cells_only=True)
+                must_s = ref["scd_sub"].search(
+                    metro.rect_flat(*req.rect), now=now_ns) | {own} | set(
+                    cols["subs"][before & known])
+                extra = got - must_s - set(cols["subs"][during & known])
+                if not must_s <= got or len(extra) > int(
+                        (during & ~known).sum()):
+                    return (f"PUT {n} subscribers: {len(extra)} unexpected, "
+                            f"{len(must_s - got)} missing of {len(must_s)}")
+        return ""
+
     for k, req in enumerate(requests):
         status = int(out.status[k])
+        chain = out.chain[k] if out.chain else None
+        fault = chain_fault(req, chain) if chain else ""
+        if fault:
+            compared += 1
+            wrong += 1
+            first = first or f"request {k} {req.rect} flight {req.id}: {fault}"
+            continue
         if status in REFUSALS:
             refused += 1
             continue
@@ -80,31 +210,34 @@ def compare(traffic: dict, requests: list, out, metro, ref) -> dict:
             never += 1
             first = first or f"request {k}: status {status}, no answer"
             continue
-        comp = comps[req.comp]
-        key = (req.comp, req.rect, req.alt, req.when)
-        if key not in memo:
-            memo[key] = expected(req, comp, metro, ref, now_ns)
-        want = memo[key]
         compared += 1
+        if req.kind == "write":
+            good[k] = True
+            continue
+        want, allowed, read_back[k] = sets(
+            req, out.t_open + out.sent[k], out.t_open + out.done[k])
         try:
-            got = answered_ids(comp, out.body[k])
+            got = answered_ids(comps[req.comp], out.body[k])
         except (ValueError, KeyError, TypeError):
             got = None
-        if got == want:
+        if got is not None and want <= got <= allowed:
             good[k] = True
         else:
             wrong += 1
             if not first:
                 first = (f"request {k} {req.rect}: " + (
                     "unreadable answer" if got is None else
-                    f"{len(got - want)} unexpected, {len(want - got)} "
+                    f"{len(got - allowed)} unexpected, {len(want - got)} "
                     f"missing of {len(want)}"))
     return {
-        "good": good,
+        "good": good, "read_back": read_back,
         "numbers": {"wrong_answers": wrong, "never_answered": never},
         "facts": {"compared": compared, "refused": refused,
                   "distinct_answers": len(memo),
-                  "ids_expected": sum(len(v) for v in memo.values())},
+                  "ids_expected": sum(len(v) for v in memo.values()),
+                  "flights_written": len(row_of),
+                  "flights_unknown": int(np.isinf(cols["acked"]).sum()),
+                  "searches_read_back": int(read_back.sum())},
         "first_wrong": first,
     }
 
@@ -148,33 +281,99 @@ def lowprec(ref: dict) -> dict:
     return out
 
 
-CONTROLS = {"stale": stale, "lowprec": lowprec}
+class LostWrites(dict):
+    """The sound reference in the place of a server that acknowledges
+    every flight and forgets each `lose_every`-th, the first among
+    them."""
+    lose_every = 100
+
+
+def lost_write(ref: dict) -> dict:
+    return LostWrites(ref)
+
+
+CONTROLS = {"stale": stale, "lowprec": lowprec, "lost_write": lost_write}
+
+
+def writes(traffic: dict) -> bool:
+    return any(tr.is_write(c) for c in traffic["components"])
+
+
+def controls_for(traffic: dict) -> dict:
+    """The controls that have to read not correct under `traffic`."""
+    return {k: v for k, v in CONTROLS.items()
+            if k != "lost_write" or writes(traffic)}
+
+
+TICK = 1e-6  # what the stand-in takes over one exchange, seconds
 
 
 def answers_of(traffic: dict, requests: list, metro, served) -> tr.Outcome:
     """What the window would have brought back had `served` (a
     reference, sound or broken) stood in the program's place: every
-    request answered at once with status 200 and a body in the
-    endpoint's own form."""
+    request answered at the instant it was due, one after another, with
+    status 200 and a body in the endpoint's own form; a planned flight
+    by its chain (409 with the conflicts `served` and the flights that
+    landed before it give, then 200 with its subscribers)."""
     now_ns = time.time_ns()
     comps = traffic["components"]
-    body = []
-    for r in requests:
-        comp = comps[r.comp]
-        ids = expected(r, comp, metro, served, now_ns)
-        body.append(json.dumps(
-            {tr.ENDPOINTS[comp["endpoint"]]["answer"]:
-             [{"id": i} for i in sorted(ids)]}).encode())
-    n = len(requests)
-    return tr.Outcome(np.zeros(n), np.full(n, 0.01),
-                      np.full(n, 200, np.int32), body)
+    lose_every = getattr(served, "lose_every", 0)
+    landed = deploy.Written()  # what the stand-in still knows it wrote
+    acks = 0
+    out = tr.blank_outcome(len(requests), 0.0)
+
+    def holds(r) -> set:
+        ids = expected(r, comps[r.comp], metro, served, now_ns)
+        if len(landed) and _is_op(comps[r.comp]):
+            cols = landed.columns()
+            ids = ids | set(cols["ids"][matching(cols, r)])
+        return ids
+
+    for k, r in enumerate(requests):
+        t = out.sent[k] = r.due
+        if r.kind != "write":
+            body = json.dumps(
+                {tr.ENDPOINTS[comps[r.comp]["endpoint"]]["answer"]:
+                 [{"id": i} for i in sorted(holds(r))]}).encode()
+            out.done[k] = t + TICK
+        else:
+            chain = out.chain[k] = []
+            key = sorted(holds(r))
+            if key:
+                chain.append(tr.Exchange(t, t + TICK, 409, json.dumps(
+                    {"entity_conflicts": [
+                        {"operation_reference": {"id": i, "ovn": "ovn-" + i}}
+                        for i in key]}).encode(), [], list(key)))
+                t += TICK
+            sub = "sub-" + r.id
+            subs = served["scd_sub"].search(
+                metro.rect_flat(*r.rect), now=now_ns) | {sub}
+            if len(landed):
+                cols = landed.columns()
+                subs |= set(cols["subs"][matching(cols, r, cells_only=True)])
+            body = json.dumps({
+                "operation_reference": {"id": r.id, "subscription_id": sub},
+                "subscribers": [{"subscriptions": [
+                    {"subscription_id": i} for i in sorted(subs)]}],
+            }).encode()
+            chain.append(tr.Exchange(t, t + TICK, 200, body, key))
+            out.done[k] = t + TICK
+            acks += 1
+            if not (lose_every and acks % lose_every == 1):
+                landed.add(r, r.due, t + TICK, sub)
+        out.status[k] = 200
+        out.body[k] = body
+    return out
 
 
 def judge_control(traffic: dict, requests: list, metro, ref, served) -> tuple:
     """(correct, checks) of a run in which `served` answered: through
-    the comparison and the verdict that decide a run's `correct`."""
-    cmp = compare(traffic, requests,
-                  answers_of(traffic, requests, metro, served), metro, ref)
+    the overlay, the comparison and the verdict that decide a run's
+    `correct`."""
+    out = answers_of(traffic, requests, metro, served)
+    written = deploy.Written()
+    written.absorb(requests, out)
+    cmp = compare(traffic, requests, out, metro, ref, written)
     return verdict(cmp["numbers"], cmp["facts"]["compared"])
 
 
@@ -212,7 +411,8 @@ def main() -> int:
             traffic, metro, ref, tr.pools(traffic, metro, ref, seed, t_gen),
             np.random.default_rng([seed, 1]), t_gen,
             traffic["rate_rps"], seconds)
-        for name, fn in {"sound": lambda r: r, **CONTROLS}.items():
+        for name, fn in {"sound": lambda r: r,
+                         **controls_for(traffic)}.items():
             correct, checks = judge_control(traffic, reqs, metro, ref,
                                             fn(ref))
             print(json.dumps({

@@ -14,6 +14,7 @@ leader.
 
 from __future__ import annotations
 
+import base64
 import http.client
 import importlib.util
 import json
@@ -345,6 +346,63 @@ def generate(seed: int, gen: dict, t_gen: int, wal_path: str):
     return metro, ref
 
 
+class Written:
+    """What a run itself wrote, beside the WAL's EntitySets (which stay
+    as generated): per planned flight its rectangle of metro cells, its
+    altitudes and times, the id of its implicit subscription once a 200
+    has named it, and on the generator's one monotonic clock the
+    instant its first PUT was sent and the instant its 200's last byte
+    was read (`acked`; inf = unknown: no final answer, so the flight
+    may or may not stand).  One object is kept from the warm-up
+    through the window to the traced stretch."""
+
+    COLUMNS = ("i0", "i1", "j0", "j1", "lo", "hi", "t0", "t1",
+               "first_sent", "acked")
+
+    def __init__(self):
+        self.ids = []
+        self.subs = []  # implicit subscription ids; None = unknown
+        self._rows = []
+        self._cols = None
+
+    def __len__(self):
+        return len(self.ids)
+
+    def add(self, req, first_sent: float, acked: float, sub) -> None:
+        i, j, w, h = req.rect
+        self.ids.append(req.id)
+        self.subs.append(sub)
+        self._rows.append((i, i + w, j, j + h, req.alt[0], req.alt[1],
+                           req.when[0], req.when[1], first_sent, acked))
+        self._cols = None
+
+    def absorb(self, requests: list, out) -> None:
+        """Take in the chains of one offered stretch."""
+        for k, req in enumerate(requests):
+            if req.kind != "write" or np.isnan(out.sent[k]):
+                continue
+            chain = out.chain[k]
+            acked, sub = math.inf, None
+            if chain and chain[-1].status == 200:
+                acked = out.t_open + chain[-1].done
+                try:
+                    sub = json.loads(chain[-1].body)[
+                        "operation_reference"]["subscription_id"]
+                except (ValueError, KeyError, TypeError):
+                    pass  # the comparison will call the answer unreadable
+            self.add(req, out.t_open + float(out.sent[k]), acked, sub)
+
+    def columns(self) -> dict:
+        """{column: array over the flights}, with `ids` and `subs`."""
+        if self._cols is None:
+            rows = np.array(self._rows, np.float64).reshape(
+                len(self._rows), len(self.COLUMNS))
+            self._cols = dict(zip(self.COLUMNS, rows.T))
+            self._cols["ids"] = np.array(self.ids, dtype=object)
+            self._cols["subs"] = np.array(self.subs, dtype=object)
+        return self._cols
+
+
 # ---------------------------------------------------------------------------
 # set-up: native library
 # ---------------------------------------------------------------------------
@@ -372,6 +430,43 @@ def build_native() -> float:
 
 
 # ---------------------------------------------------------------------------
+# set-up: the callers' identities, where the deployment authenticates
+# ---------------------------------------------------------------------------
+
+
+def make_keys(work: str) -> tuple:
+    """(private key, path of its public half as PEM): the pair an OAuth
+    provider would hold, made anew for every run."""
+    from cryptography.hazmat.primitives import serialization
+    from cryptography.hazmat.primitives.asymmetric import rsa
+
+    key = rsa.generate_private_key(public_exponent=65537, key_size=2048)
+    path = os.path.join(work, "oauth.pem")
+    with open(path, "wb") as fh:
+        fh.write(key.public_key().public_bytes(
+            serialization.Encoding.PEM,
+            serialization.PublicFormat.SubjectPublicKeyInfo))
+    return key, path
+
+
+def mint(key, sub: str, audience: str, scope: str, ttl_s: int) -> bytes:
+    """An RS256 access token as the reference's dummy OAuth mints them
+    (cmds/dummy-oauth/main.go): `sub` is the USS, and so the owner of
+    what it writes."""
+    from cryptography.hazmat.primitives import hashes
+    from cryptography.hazmat.primitives.asymmetric import padding
+
+    def b64(raw: bytes) -> bytes:
+        return base64.urlsafe_b64encode(raw).rstrip(b"=")
+
+    signed = b64(b'{"alg":"RS256","typ":"JWT"}') + b"." + b64(json.dumps({
+        "aud": audience, "scope": scope, "iss": "dssbench", "sub": sub,
+        "exp": int(time.time()) + ttl_s}).encode())
+    return signed + b"." + b64(
+        key.sign(signed, padding.PKCS1v15(), hashes.SHA256()))
+
+
+# ---------------------------------------------------------------------------
 # the server under test
 # ---------------------------------------------------------------------------
 
@@ -391,6 +486,7 @@ class Server:
         )
         self.port = int(argv[argv.index("--addr") + 1].lstrip(":"))
         self.leader_url = ""
+        self.tokens = []  # bearer tokens, where callers authenticate
 
     def log_records(self):
         """(json records, other lines) of the combined stderr of the
@@ -464,17 +560,17 @@ class Server:
 
 
 def http_json(base: str, method: str, path: str, body=None,
-              timeout: float = 120.0):
+              timeout: float = 120.0, token: bytes = b""):
     """One request on a fresh connection -> (status, parsed body;
     the text itself when it is not JSON)."""
     host, port = base.replace("http://", "").split(":")
     conn = http.client.HTTPConnection(host, int(port), timeout=timeout)
     try:
         data = None if body is None else json.dumps(body)
-        conn.request(
-            method, path, body=data,
-            headers={"Content-Type": "application/json"} if data else {},
-        )
+        headers = {"Content-Type": "application/json"} if data else {}
+        if token:
+            headers["Authorization"] = "Bearer " + token.decode()
+        conn.request(method, path, body=data, headers=headers)
         resp = conn.getresponse()
         raw = resp.read()
         try:

@@ -135,14 +135,27 @@ def start_server(config: dict, wal: str, work: str, platform: str,
         "--wal_path", wal, "--workers", str(srv_conf["workers"]),
         *srv_conf["flags"],
     ]
+    tokens = []
+    auth = srv_conf.get("auth")
+    if auth:
+        # the deployment authenticates its callers: the key pair of its
+        # OAuth provider, and one token per USS (owners uss0, uss1, ...)
+        key, pem = deploy.make_keys(work)
+        argv += ["--public_key_files", pem,
+                 "--accepted_jwt_audiences", auth["audience"]]
+        tokens = [deploy.mint(key, f"uss{k}", auth["audience"],
+                              auth["scope"], auth["ttl_s"])
+                  for k in range(auth["owners"])]
     if trace:
         argv += ["--profile_dir", os.path.join(work, "profile")]
     # children inherit the affinity of the thread that starts them
     os.sched_setaffinity(0, server_cores)
     try:
-        return deploy.Server(argv, env, os.path.join(work, "server.stderr"))
+        srv = deploy.Server(argv, env, os.path.join(work, "server.stderr"))
     finally:
         os.sched_setaffinity(0, gen_cores)
+    srv.tokens = tokens
+    return srv
 
 
 def scrape_all(srv) -> dict:
@@ -165,7 +178,7 @@ async def warm_and_measure(srv, workers, traffic, metro, ref, seed, t_gen,
     fill = []
     for c, pool in area_pools.items():
         for rect in pool:
-            req = tr.Request(0.0, c, rect, None, None)
+            req = tr.Request(0.0, c, rect, None, None, token=tr.a_token())
             req.wire = tr.wire(comps[c], req, metro)
             fill.append(req)
     for p in range(traffic.get("prefill_passes", 0) if fill else 0):
@@ -177,6 +190,9 @@ async def warm_and_measure(srv, workers, traffic, metro, ref, seed, t_gen,
     client = tr.Client(srv.port)
     await client.balance(workers, traffic.get("connections_per_worker", 16))
     warm = traffic["warmup"]
+    # what this run writes, from its first planned flight on: the part
+    # of the reference that changes (check.compare)
+    written = deploy.Written()
     # every shape the window can meet, before anything is timed: the
     # un-pooled components' requests, closed loop, a few in flight, so
     # that the kernel's shape buckets of one to a few queries compile now
@@ -185,7 +201,8 @@ async def warm_and_measure(srv, workers, traffic, metro, ref, seed, t_gen,
         burst = tr.build({"components": loose}, metro, ref, {},
                          np.random.default_rng([seed, 4]), t_gen,
                          warm["burst_requests"], 1.0)
-        await tr.prefill(client, burst, warm["burst_in_flight"])
+        written.absorb(burst, await tr.prefill(
+            client, burst, warm["burst_in_flight"]))
         srv.check_alive("warming up")
     compiles = [deploy.scrape(srv.leader_url).get("dss_jax_compiles", 0.0)]
     chunk = 0
@@ -194,7 +211,7 @@ async def warm_and_measure(srv, workers, traffic, metro, ref, seed, t_gen,
         reqs = tr.build(traffic, metro, ref, area_pools,
                         np.random.default_rng([seed, 2, chunk]), t_gen,
                         rate, warm["chunk_s"])
-        await tr.offer(client, reqs, grace_s=0.0)
+        written.absorb(reqs, await tr.offer(client, reqs, grace_s=0.0))
         chunk += 1
         srv.check_alive("warming up")
         compiles.append(
@@ -219,11 +236,13 @@ async def warm_and_measure(srv, workers, traffic, metro, ref, seed, t_gen,
         opened["setup_s"] = process_age_s()
 
     out = await tr.offer(client, requests, on_open=on_open)
+    written.absorb(requests, out)
     if mutate is not None:
-        mutate(requests, out)
+        mutate(requests, out, written)
     s1 = scrape_all(srv)
     got = {
-        "requests": requests, "out": out, "scrape0": s0, "scrape1": s1,
+        "requests": requests, "out": out, "written": written,
+        "scrape0": s0, "scrape1": s1,
         "setup_s": opened["setup_s"], "warm_s": warm_s,
         "warm_chunks": chunk,
         "opened_in_window": client.opened - opened_before,
@@ -232,6 +251,7 @@ async def warm_and_measure(srv, workers, traffic, metro, ref, seed, t_gen,
         got.update(await traced_stretch(
             srv, client, traffic, metro, ref, area_pools, seed, t_gen, rate,
             min(seconds, TRACE_MAX_S)))
+        written.absorb(got["traced_requests"], got["traced_out"])
     got["connections"] = client.opened
     await client.close()
     return got
@@ -255,7 +275,7 @@ async def traced_stretch(srv, client, traffic, metro, ref, area_pools, seed,
         profile["answer"] = deploy.http_json(
             srv.leader_url, "POST",
             f"/debug/profile?seconds={traced_s + 2 * TRACE_PAD_S}",
-            timeout=traced_s + 2 * TRACE_PAD_S + 120)
+            timeout=traced_s + 2 * TRACE_PAD_S + 120, token=tr.a_token())
 
     thread = threading.Thread(target=capture)
     thread.start()
@@ -302,6 +322,7 @@ def booted(config: dict, seed: int, platform: str, trace: bool,
                 f"MiB in {gen_s:.1f}s")
             srv = start_server(config, wal, work, platform, trace,
                                server_cores, gen_cores)
+            tr.TOKENS[:] = srv.tokens
             boot = deploy.wait_ready(srv, config["server"]["workers"],
                                      config["server"]["boot_timeout_s"])
             log(f"serving: {boot}")
@@ -320,15 +341,18 @@ def booted(config: dict, seed: int, platform: str, trace: bool,
                           **boot},
             }
         finally:
+            tr.TOKENS.clear()
             if srv is not None:
                 srv.stop()
             os.sched_setaffinity(0, all_cores)
 
 
 # faults that dssbench/tests plant under a run to see `correct` come out
-# false: the server boots from a WAL that lost its newest records, or
-# one answer loses an id on its way out of the served path
-FAULTS = ("lose_tail", "alter_answer")
+# false: the server boots from a WAL that lost its newest records, one
+# answer loses an id on its way out of the served path, or (where the
+# traffic writes) a search answers as if a flight that this run was
+# acknowledged before it had never been written
+FAULTS = ("lose_tail", "alter_answer", "forget_write")
 
 
 def lose_tail(wal: str, share: float = 0.02) -> None:
@@ -338,7 +362,7 @@ def lose_tail(wal: str, share: float = 0.02) -> None:
         fh.writelines(lines[: len(lines) - max(1, int(len(lines) * share))])
 
 
-def alter_answer(requests: list, out) -> None:
+def alter_answer(requests: list, out, written=None) -> None:
     """Drop the first id of the first answer that holds one."""
     for k, body in enumerate(out.body):
         doc = json.loads(body) if body and out.status[k] == 200 else {}
@@ -349,6 +373,25 @@ def alter_answer(requests: list, out) -> None:
                 return
 
 
+def forget_write(requests: list, out, written) -> None:
+    """Drop the flights of this run from every search answer of the
+    window: acknowledged writes that are not read back."""
+    flights = set(written.ids)
+    dropped = 0
+    for k, req in enumerate(requests):
+        if req.kind != "search" or out.status[k] != 200:
+            continue
+        doc = json.loads(out.body[k])
+        for key, val in doc.items():
+            kept = [e for e in val if e.get("id") not in flights]
+            if len(kept) < len(val):
+                dropped += len(val) - len(kept)
+                doc[key] = kept
+                out.body[k] = json.dumps(doc).encode()
+    if not dropped:
+        raise BenchFailure("no search of the window read a flight back")
+
+
 def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
              config: dict, traffic: dict, metrics: list,
              end_to_end: list, platform: str = "tpu", fault: str = "",
@@ -357,7 +400,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     `fault` are for the tests: the command always runs on the TPU,
     unaltered."""
     rate = traffic["rate_rps"]
-    mutate = alter_answer if fault == "alter_answer" else None
+    mutate = {"alter_answer": alter_answer,
+              "forget_write": forget_write}.get(fault)
     with booted(config, seed, platform, trace, fault) as dep:
         srv, metro, ref = dep["srv"], dep["metro"], dep["ref"]
         backend = dep["backend"]
@@ -383,12 +427,14 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
             with open(os.path.join(keep, "scrapes.json"), "w") as fh:
                 json.dump([got["scrape0"], got["scrape1"]], fh)
         t_cmp = time.monotonic()
-        cmp = check.compare(traffic, got["requests"], got["out"], metro, ref)
+        cmp = check.compare(traffic, got["requests"], got["out"], metro, ref,
+                            got["written"])
         cmp_s = time.monotonic() - t_cmp
         ctx = {
             "cell": cell, "seconds": seconds, "rate": rate,
             "traffic": traffic, "requests": got["requests"],
-            "out": got["out"], "good": cmp["good"], "metro": metro,
+            "out": got["out"], "good": cmp["good"],
+            "read_back": cmp["read_back"], "metro": metro,
             "ref": ref, "scrape0": got["scrape0"],
             "scrape1": got["scrape1"], "bootlog": bootlog,
             "setup_s": got["setup_s"], "trace_file": trace_file,
@@ -410,8 +456,9 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
             ctx["traced"] = {
                 "requests": got["traced_requests"], "out": got["traced_out"],
                 "seconds": got["traced_s"],
-                "good": check.compare(traffic, got["traced_requests"],
-                                      got["traced_out"], metro, ref)["good"],
+                "good": check.compare(
+                    traffic, got["traced_requests"], got["traced_out"],
+                    metro, ref, got["written"])["good"],
             }
             t_red = time.monotonic()
             red = xreader.reduction(ctx)
@@ -435,6 +482,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     lat = tr.latencies_ms(got["requests"], got["out"], cmp["good"])
     due = tr.due_times(got["requests"])
     which = np.array([r.comp for r in got["requests"]])
+    chains = [c for c in got["out"].chain if c]
     correct, checks = check.verdict(cmp["numbers"], cmp["facts"]["compared"])
     result = {
         "correct": bool(correct),
@@ -455,11 +503,23 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
         "connections": got["connections"],
         "opened_in_window": got["opened_in_window"], "compare_s": cmp_s,
         **cmp["facts"], "first_wrong": cmp["first_wrong"],
+        "first_refusal": next(
+            (f"{int(st)}: {(got['out'].body[k] or b'')[:300]!r}"
+             for k, st in enumerate(got["out"].status)
+             if st in check.REFUSALS), ""),
         "latency_ms": {f"p{q}": tr.percentile(lat, q)
                        for q in (50, 90, 95, 99)},
         "latency_ms_by_component": [
             {f"p{q}": tr.percentile(lat[which == c], q) for q in (50, 95)}
             for c in range(len(traffic["components"]))],
+        # the planned flights' chains: how many exchanges each took, and
+        # the status each ended in
+        "chains": {
+            "rounds": {str(n): sum(len(c) == n for c in chains)
+                       for n in sorted({len(c) for c in chains})},
+            "ended": {str(st): sum(c[-1].status == st for c in chains)
+                      for st in sorted({c[-1].status for c in chains})},
+        },
         "p95_ms_by_5s": [
             tr.percentile(lat[(due >= a) & (due < a + 5)], 95)
             for a in range(0, int(seconds), 5)],

@@ -53,9 +53,9 @@ def judge(requests, out, good) -> dict:
 async def climb(dep, workers, traffic, seed, start, max_rungs):
     srv, metro, ref = dep["srv"], dep["metro"], dep["ref"]
     # set-up as a run makes it: pools, prefill, warm-up at the first rung
-    await run.warm_and_measure(
+    written = (await run.warm_and_measure(
         srv, workers, traffic, metro, ref, seed, dep["t_gen"], start, RUNG_S,
-        False, None)
+        False, None))["written"]
     area_pools = tr.pools(traffic, metro, ref, seed, dep["t_gen"])
     client = tr.Client(srv.port)
     await client.balance(workers,
@@ -67,7 +67,8 @@ async def climb(dep, workers, traffic, seed, start, max_rungs):
             traffic, metro, ref, area_pools,
             np.random.default_rng([seed, 5, k]), dep["t_gen"], rate, RUNG_S)
         out = await tr.offer(client, requests)
-        cmp = check.compare(traffic, requests, out, metro, ref)
+        written.absorb(requests, out)  # a chain is answered correctly
+        cmp = check.compare(traffic, requests, out, metro, ref, written)
         row = {"rate_rps": rate, "requests": len(requests),
                **cmp["numbers"], **judge(requests, out, cmp["good"])}
         rungs.append(row)

@@ -7,7 +7,11 @@ searches: an endpoint, rectangle sides in level-13 cells, an optional
 pool of fixed areas with a Zipf skew (and, by `answer_ids`, the number
 of ids each pooled area's answer holds), an optional floor or ceiling
 on the candidate postings under a rectangle, an altitude band and the
-share of requests that carry a time window.
+share of requests that carry a time window.  A component whose endpoint
+is `scd_put` is a population of planned flights: one request is a
+chain of PUTs on one connection (put_wire, next_put), and its draws
+come from a stream of their own, so a file without one builds what it
+always built.
 
 Every seed offers the same amount and the same set of work in another
 order: the number of requests is rate x seconds exactly (a Poisson
@@ -29,7 +33,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from .deploy import NS, BenchFailure
+from .deploy import NS, BenchFailure, _uuids
 
 DEADLINE_S = 10.0  # the reference's RPC deadline (BASELINE.md)
 
@@ -44,7 +48,21 @@ ENDPOINTS = {
         "method": "GET", "path": "/v1/dss/identification_service_areas",
         "class": "isa", "answer": "service_areas",
     },
+    # one planned flight: PUT with an empty key -> 409 with the
+    # conflicting op intents and their OVNs -> PUT with those as the key
+    "scd_put": {
+        "method": "PUT", "path": "/dss/v1/operation_references/",
+        "class": "op", "answer": "entity_conflicts", "kind": "write",
+    },
 }
+USS_URL = "https://bench.example/scd"  # where a writer is called back
+# exchanges a chain may take: the empty key, the key, and 3 more rounds
+# where another writer landed in between
+MAX_ROUNDS = 5
+# bearer tokens, one per USS, where the deployment authenticates its
+# callers (run.booted sets and clears them; deploy.mint): request k of
+# a build is sent by USS k mod their number.  Empty: no header is sent.
+TOKENS: list = []
 
 
 def iso(t_s: int) -> str:
@@ -58,7 +76,11 @@ class Request:
     rect: tuple  # (i, j, w, h) in metro cells
     alt: tuple | None  # (lo, hi) metres
     when: tuple | None  # (t0, t1) whole seconds
-    wire: bytes = b""  # the HTTP request as sent
+    wire: bytes = b""  # the HTTP request as sent (a chain's first)
+    kind: str = "search"  # or "write": a chain of PUTs
+    id: str = ""  # a planned flight's id
+    stem: bytes = b""  # a PUT's body up to its key
+    token: bytes = b""  # the bearer token of the USS that sends it
 
 
 def _deck(values: list, n: int, rng) -> list:
@@ -159,6 +181,25 @@ def pools(traffic: dict, metro, ref, seed: int, t_gen: int) -> dict:
     return out
 
 
+def a_token() -> bytes:
+    """Any USS's token, for what the harness itself asks (b"" where
+    nobody authenticates)."""
+    return TOKENS[0] if TOKENS else b""
+
+
+def is_write(comp: dict) -> bool:
+    return ENDPOINTS[comp["endpoint"]].get("kind") == "write"
+
+
+def write_stream(rng):
+    """The stream that a build's planned flights are drawn from: seeded
+    by what `rng` itself was seeded with ([seed, phase, ...]) and one
+    word more, so that it takes nothing from `rng`, and that warm-up,
+    window and traced stretch each get ids of their own."""
+    entropy = rng.bit_generator.seed_seq.entropy
+    return np.random.default_rng([*np.atleast_1d(entropy).tolist(), 8])
+
+
 def build(traffic: dict, metro, ref, area_pools: dict, rng, t_gen: int,
           rate: float, seconds: float) -> list:
     """The requests due in a window of `seconds` at `rate`, with their
@@ -168,7 +209,9 @@ def build(traffic: dict, metro, ref, area_pools: dict, rng, t_gen: int,
     per_comp = _quota([c["share"] for c in comps], n)
     which = rng.permutation(np.repeat(np.arange(len(comps)), per_comp))
     due = np.sort(rng.uniform(0.0, seconds, n))
-    rects = {}
+    writes = {c for c, comp in enumerate(comps) if is_write(comp)}
+    wrng = write_stream(rng) if writes else None
+    rects, ids = {}, {}
     for c, comp in enumerate(comps):
         m = per_comp[c]
         if c in area_pools:
@@ -180,10 +223,13 @@ def build(traffic: dict, metro, ref, area_pools: dict, rng, t_gen: int,
             )
             rects[c] = [pool[r] for r in rng.permutation(ranks)]
         else:
+            own = wrng if c in writes else rng
             rects[c] = [
-                _place(comp, w, h, metro, ref, rng)
-                for w, h in _deck(_sides(comp), m, rng)
+                _place(comp, w, h, metro, ref, own)
+                for w, h in _deck(_sides(comp), m, own)
             ]
+        if c in writes:
+            ids[c] = _uuids(wrng, m)
     out = []
     taken = [0] * len(comps)
     for k in range(n):
@@ -191,53 +237,99 @@ def build(traffic: dict, metro, ref, area_pools: dict, rng, t_gen: int,
         comp = comps[c]
         seq = taken[c]
         taken[c] += 1
+        own = wrng if c in writes else rng
         alt = when = None
         if "alt_band_m" in comp:
-            lo = float(rng.integers(0, comp["alt_ceiling_m"] * 4)) * 0.25
+            lo = float(own.integers(0, comp["alt_ceiling_m"] * 4)) * 0.25
             alt = (lo, lo + comp["alt_band_m"])
         period = comp.get("timed_every", 0)  # 5: four in five are timed
-        if period and seq % period != 0:
+        if c in writes or (period and seq % period != 0):
             a, b = comp["opens_in_s"]
             la, lb = comp["lasts_s"]
-            t0 = t_gen + a + int(rng.integers(0, b - a))
-            when = (t0, t0 + int(rng.integers(la, lb)))
+            t0 = t_gen + a + int(own.integers(0, b - a))
+            when = (t0, t0 + int(own.integers(la, lb)))
         req = Request(float(due[k]), c, rects[c][seq], alt, when)
-        req.wire = wire(comp, req, metro)
+        if TOKENS:
+            req.token = TOKENS[k % len(TOKENS)]
+        if c in writes:
+            req.kind, req.id = "write", ids[c][seq]
+            req.stem = put_stem(req, metro)
+            req.wire = put_wire(req, [])
+        else:
+            req.wire = wire(comp, req, metro)
         out.append(req)
     return out
+
+
+def _volume(req: Request, metro) -> dict:
+    """A request's 4D volume as the SCD API spells it."""
+    vol = {"volume": {
+        "outline_polygon": {"vertices": metro.rect(*req.rect)},
+        "altitude_lower": {"value": req.alt[0], "reference": "W84",
+                           "units": "M"},
+        "altitude_upper": {"value": req.alt[1], "reference": "W84",
+                           "units": "M"},
+    }}
+    if req.when:
+        vol["time_start"] = {"value": iso(req.when[0]), "format": "RFC3339"}
+        vol["time_end"] = {"value": iso(req.when[1]), "format": "RFC3339"}
+    return vol
+
+
+def _http(method: str, path: str, body: bytes, token: bytes = b"") -> bytes:
+    head = (f"{method} {path} HTTP/1.1\r\nHost: dss\r\n"
+            f"Content-Length: {len(body)}\r\n")
+    if body:
+        head += "Content-Type: application/json\r\n"
+    if token:
+        head += f"Authorization: Bearer {token.decode()}\r\n"
+    return head.encode() + b"\r\n" + body
+
+
+def put_stem(req: Request, metro) -> bytes:
+    """A planned flight's PUT body up to its key: a new op intent
+    (`old_version` 0) with an implicit subscription, the reference's
+    default flow."""
+    doc = json.dumps({
+        "extents": [_volume(req, metro)], "old_version": 0,
+        "state": "Accepted", "uss_base_url": USS_URL,
+        "new_subscription": {"uss_base_url": USS_URL},
+    })
+    return doc[:-1].encode() + b', "key": '
+
+
+def put_wire(req: Request, ovns: list) -> bytes:
+    ep = ENDPOINTS["scd_put"]
+    return _http(ep["method"], ep["path"] + req.id,
+                 req.stem + json.dumps(ovns).encode() + b"}", req.token)
+
+
+def conflicts_of(body: bytes) -> list:
+    """[(id, ovn)] of the op intents a refused PUT lists; None where
+    the answer is not an AirspaceConflictResponse."""
+    try:
+        return [(c["operation_reference"]["id"],
+                 c["operation_reference"]["ovn"])
+                for c in json.loads(body)["entity_conflicts"]
+                if "operation_reference" in c]
+    except (ValueError, KeyError, TypeError):
+        return None
 
 
 def wire(comp: dict, req: Request, metro) -> bytes:
     """The HTTP/1.1 request (keep-alive) for one search."""
     ep = ENDPOINTS[comp["endpoint"]]
-    verts = metro.rect(*req.rect)
     if comp["endpoint"] == "scd_query":
-        vol = {"volume": {
-            "outline_polygon": {"vertices": verts},
-            "altitude_lower": {"value": req.alt[0], "reference": "W84",
-                               "units": "M"},
-            "altitude_upper": {"value": req.alt[1], "reference": "W84",
-                               "units": "M"},
-        }}
-        if req.when:
-            vol["time_start"] = {"value": iso(req.when[0]),
-                                 "format": "RFC3339"}
-            vol["time_end"] = {"value": iso(req.when[1]),
-                               "format": "RFC3339"}
-        body = json.dumps({"area_of_interest": vol}).encode()
+        body = json.dumps({"area_of_interest": _volume(req, metro)}).encode()
         path = ep["path"]
     else:
         body = b""
         path = ep["path"] + "?area=" + ",".join(
-            f"{v['lat']!r},{v['lng']!r}" for v in verts)
+            f"{v['lat']!r},{v['lng']!r}" for v in metro.rect(*req.rect))
         if req.when:
             path += (f"&earliest_time={iso(req.when[0])}"
                      f"&latest_time={iso(req.when[1])}")
-    head = (f"{ep['method']} {path} HTTP/1.1\r\nHost: dss\r\n"
-            f"Content-Length: {len(body)}\r\n")
-    if body:
-        head += "Content-Type: application/json\r\n"
-    return head.encode() + b"\r\n" + body
+    return _http(ep["method"], path, body, req.token)
 
 
 # ---------------------------------------------------------------------------
@@ -246,11 +338,32 @@ def wire(comp: dict, req: Request, metro) -> bytes:
 
 
 @dataclass
+class Exchange:
+    """One PUT of a chain and its answer."""
+    sent: float  # seconds after the window opened
+    done: float  # last byte read
+    status: int
+    body: bytes
+    key: list  # the ids whose OVNs the PUT carried
+    listed: list | None = None  # the ids a 409 listed
+
+
+@dataclass
 class Outcome:
+    """Per request; of a chain, `sent` is its first exchange's, and
+    `done`, `status` and `body` its last's."""
     sent: np.ndarray  # seconds after the window opened; nan = never sent
     done: np.ndarray  # last byte read; nan = no answer
     status: np.ndarray  # HTTP status; 0 = no answer
     body: list = field(default_factory=list)  # raw bytes or None
+    # per request None, or a chain's exchanges as far as they got
+    chain: list = field(default_factory=list)
+    t_open: float = 0.0  # the window's opening on the monotonic clock
+
+
+def blank_outcome(n: int, t_open: float) -> Outcome:
+    return Outcome(np.full(n, np.nan), np.full(n, np.nan),
+                   np.zeros(n, np.int32), [None] * n, [None] * n, t_open)
 
 
 class Client:
@@ -309,9 +422,11 @@ class Client:
         n = int(low[at + 15:low.index(b"\r\n", at)])
         return status, (await reader.readexactly(n) if n else b"")
 
-    async def fetch(self, wire_bytes: bytes):
+    async def fetch(self, wire_bytes: bytes, then=None):
         """-> (status, body).  A kept connection that the server has
-        closed meanwhile is replaced once, as any HTTP client does."""
+        closed meanwhile is replaced once, as any HTTP client does.
+        `then(status, body)` may name what to send next on the same
+        connection (a chain); the last answer is returned."""
         lane = None
         for k in range(len(self._lanes)):
             cand = self._lanes[(self._turn + k) % len(self._lanes)]
@@ -333,6 +448,11 @@ class Client:
                 conn[1].close()
                 conn = await self._open()
                 status, body = await self._exchange(conn, wire_bytes)
+            while then is not None:
+                wire_bytes = then(status, body)
+                if wire_bytes is None:
+                    break
+                status, body = await self._exchange(conn, wire_bytes)
         except BaseException:
             conn[1].close()
             if lane is not None:  # the pool keeps its size
@@ -352,14 +472,54 @@ class Client:
         self._lanes, self._spare = [], []
 
 
+def next_put(req: Request, chain: list):
+    """After a chain's newest exchange: the PUT to send next, or None
+    where the chain has ended (anything but a conflict listing, or
+    MAX_ROUNDS exchanges made)."""
+    last = chain[-1]
+    if last.status != 409:
+        return None
+    pairs = conflicts_of(last.body)
+    if pairs is None:
+        return None  # refused for another reason than its key
+    last.listed = [i for i, _ in pairs]
+    if len(chain) >= MAX_ROUNDS:
+        return None
+    return put_wire(req, [ovn for _, ovn in pairs])
+
+
+async def _send(client: Client, req: Request, k: int, out: Outcome, clock):
+    """One request, or one chain, into row k of `out`."""
+    try:
+        out.sent[k] = clock()
+        if req.kind == "write":
+            chain = out.chain[k] = []
+            begun = [out.sent[k]]
+
+            def then(status, body):
+                now = clock()
+                chain.append(Exchange(
+                    begun[0], now, status, body,
+                    chain[-1].listed if chain else []))
+                begun[0] = now  # the next PUT leaves at once
+                return next_put(req, chain)
+
+            status, body = await client.fetch(req.wire, then)
+            out.done[k] = chain[-1].done
+        else:
+            status, body = await client.fetch(req.wire)
+            out.done[k] = clock()
+        out.status[k] = status
+        out.body[k] = body
+    except (OSError, asyncio.IncompleteReadError, asyncio.LimitOverrunError):
+        pass  # no answer: stays status 0
+
+
 async def offer(client: Client, requests: list, *, grace_s: float = 60.0,
                 on_open=None) -> Outcome:
     """Send every request at its due instant whether or not earlier
     ones were answered; wait up to `grace_s` past the last due instant
     for answers.  Times are relative to the window's opening."""
-    n = len(requests)
-    out = Outcome(np.full(n, np.nan), np.full(n, np.nan),
-                  np.zeros(n, np.int32), [None] * n)
     loop = asyncio.get_running_loop()
     # no collector pause inside the window: what set-up built is frozen,
     # and what the window allocates (answers) is kept anyway
@@ -367,18 +527,12 @@ async def offer(client: Client, requests: list, *, grace_s: float = 60.0,
     gc.freeze()
     gc.disable()
     t_open = loop.time()
+    out = blank_outcome(len(requests), t_open)
     if on_open is not None:
         on_open()
 
-    async def one(k: int):
-        try:
-            out.sent[k] = loop.time() - t_open
-            status, body = await client.fetch(requests[k].wire)
-            out.done[k] = loop.time() - t_open
-            out.status[k] = status
-            out.body[k] = body
-        except (OSError, asyncio.IncompleteReadError, asyncio.LimitOverrunError):
-            pass  # no answer: stays status 0
+    def clock():
+        return loop.time() - t_open
 
     tasks = []
     try:
@@ -390,7 +544,8 @@ async def offer(client: Client, requests: list, *, grace_s: float = 60.0,
                 # a timer wakes up to a millisecond late: sleep short of
                 # the instant, then yield to the loop until it has come
                 await asyncio.sleep(delay - 0.002 if delay > 0.002 else 0)
-            tasks.append(asyncio.create_task(one(k)))
+            tasks.append(asyncio.create_task(
+                _send(client, req, k, out, clock)))
         if tasks:
             _, pending = await asyncio.wait(
                 tasks, timeout=grace_s + DEADLINE_S)
@@ -403,19 +558,21 @@ async def offer(client: Client, requests: list, *, grace_s: float = 60.0,
     return out
 
 
-async def prefill(client: Client, requests: list, in_flight: int = 32):
+async def prefill(client: Client, requests: list,
+                  in_flight: int = 32) -> Outcome:
     """Closed loop over `requests`, `in_flight` at a time (fills caches
-    during set-up; nothing is timed)."""
-    it = iter(requests)
+    during set-up; nothing is timed, but what was written is kept)."""
+    loop = asyncio.get_running_loop()
+    t_open = loop.time()
+    out = blank_outcome(len(requests), t_open)
+    it = iter(enumerate(requests))
 
     async def lane():
-        for req in it:
-            try:
-                await client.fetch(req.wire)
-            except (OSError, asyncio.IncompleteReadError):
-                pass
+        for k, req in it:
+            await _send(client, req, k, out, lambda: loop.time() - t_open)
 
     await asyncio.gather(*(lane() for _ in range(in_flight)))
+    return out
 
 
 # ---------------------------------------------------------------------------
