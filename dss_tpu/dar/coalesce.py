@@ -433,15 +433,29 @@ class QueryCoalescer:
             kern = self._res_loop.kernel
 
             def warm_hook(ft, _kern=kern):
-                # fold-time warm: only tables big enough to route to
-                # the device are worth AOT grid compiles — the tiny L1
-                # tiers a minor fold rebuilds serve from the host path
-                # anyway, and their block count changes every fold.
+                # fold-time warm: the AOT grid is compiled again only
+                # for a tier a drain can send to the device (one with
+                # more postings than the host scan's cap: a smaller
+                # tier is the host scan's whatever up to HOST_MAX_BATCH
+                # queries ask of it, and its block count moves at
+                # every fold), in a class that has sent a query there.
+                # Warming a write deployment's op L1 tier (20-27k
+                # postings) or a city's subscription L0 (~80k, of which
+                # a flight's match reads ~1,000, on the host) would
+                # compile the grid at every fold that moves a block
+                # count: seconds of compiler threads beside served
+                # requests, for executables no submit selects.  A class
+                # sent to the device after all rides the shared jit, as
+                # any unwarmed bucket does, and its folds warm from
+                # then on.
                 # ASYNC on purpose: a synchronous grid compile inside
                 # the fold would re-introduce the O(table) stall the
                 # tiered snapshots removed; until a bucket lands,
                 # submits ride the shared jit exactly as before.
-                if ft.n_postings >= 1 << 14:
+                if (
+                    ft.n_postings > self._fast_cls.HOST_MAX_CANDIDATES
+                    and self._stat_device_members > 0
+                ):
                     _kern.warm_async(ft)
 
             set_warm(warm_hook)

@@ -38,7 +38,7 @@ from dss_tpu import chaos, errors
 from dss_tpu.clock import Clock, to_nanos
 from dss_tpu.dar import codec
 from dss_tpu.dar import readcache as rcache
-from dss_tpu.obs import trace
+from dss_tpu.obs import stages, trace
 from dss_tpu.dar.index import MemorySpatialIndex, TpuSpatialIndex
 from dss_tpu.dar.store import RIDStore, SCDStore
 from dss_tpu.dar.wal import WriteAheadLog
@@ -118,6 +118,19 @@ def _bump_sub(subs: Dict[str, object], sub_id: str):
     return bumped
 
 
+@contextlib.contextmanager
+def _write_leg(seam: str, stage: str):
+    """One leg of a notifying write: `dss.<seam>` on a running capture
+    and stage `<stage>` of the request that writes (as match.py marks
+    push_match_ms: a no-op without a sink, so replay pays nothing)."""
+    t0 = time.perf_counter()
+    try:
+        with trace.annotate(seam):
+            yield
+    finally:
+        stages.mark(stage, (time.perf_counter() - t0) * 1000.0)
+
+
 class _PushMixin:
     """Reverse-query push wiring shared by both sub-stores
     (dss_tpu/push/): DSSStore.attach_push hands the pipeline to the
@@ -161,12 +174,13 @@ class _PushMixin:
         push = self._push
         if push is None or not push.bound:
             return
-        push.offer(
-            trigger, entity, subs, removed=removed,
-            emergency=emergency, alt_lo=alt_lo, alt_hi=alt_hi,
-            t_start_ns=None if t_start is None else to_nanos(t_start),
-            t_end_ns=None if t_end is None else to_nanos(t_end),
-        )
+        with _write_leg("push.offer", "push_offer_ms"):
+            push.offer(
+                trigger, entity, subs, removed=removed,
+                emergency=emergency, alt_lo=alt_lo, alt_hi=alt_hi,
+                t_start_ns=None if t_start is None else to_nanos(t_start),
+                t_end_ns=None if t_end is None else to_nanos(t_end),
+            )
 
 
 class _TxnTimeMixin:
@@ -735,6 +749,12 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
 
 
 class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
+    # writes that had subscribers to tell, and the subscribers they
+    # bumped and returned (dss_scd_notifying_writes_total,
+    # dss_scd_subscribers_notified_total)
+    _notifying_writes = 0
+    _subs_notified = 0
+
     def index_stats(self) -> dict:
         return self._op_index.stats()
 
@@ -1034,27 +1054,34 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         want_constraints = trigger == "constraints"
         out = []
         undo = []
-        for i in sorted(ids):
-            prev = self._subs.get(i)
-            if prev is None:
-                continue
-            if want_constraints:
-                if not prev.notify_for_constraints:
+        # the bump and its journal record: O(matched) under the write
+        # lock (stage sub_bump_ms; dss.sub.bump on a capture)
+        with _write_leg("sub.bump", "sub_bump_ms"):
+            for i in sorted(ids):
+                prev = self._subs.get(i)
+                if prev is None:
                     continue
-            elif not prev.notify_for_operations:
-                continue
-            if self._capture_undo:
-                undo.append(
-                    {"t": "scd_sub_put", "doc": codec.scd_sub_to_doc(prev)}
-                )
-            bumped = _bump_sub(self._subs, i)
-            if bumped is not None:
-                out.append(dataclasses.replace(bumped))
+                if want_constraints:
+                    if not prev.notify_for_constraints:
+                        continue
+                elif not prev.notify_for_operations:
+                    continue
+                if self._capture_undo:
+                    undo.append({
+                        "t": "scd_sub_put",
+                        "doc": codec.scd_sub_to_doc(prev),
+                    })
+                bumped = _bump_sub(self._subs, i)
+                if bumped is not None:
+                    out.append(dataclasses.replace(bumped))
+            if out:
+                rec = {"t": "scd_sub_bump", "ids": [s.id for s in out]}
+                if self._capture_undo:
+                    rec["undo"] = undo
+                self._journal(rec)
         if out:
-            rec = {"t": "scd_sub_bump", "ids": [s.id for s in out]}
-            if self._capture_undo:
-                rec["undo"] = undo
-            self._journal(rec)
+            self._notifying_writes += 1
+            self._subs_notified += len(out)
         return out
 
     def _precheck_op_upsert(self, op, key, *, check_key: bool = True):
@@ -1937,6 +1964,11 @@ class DSSStore:
         out["dss_wire_memo_misses"] = (
             self.rid._wire_memo_misses + self.scd._wire_memo_misses
         )
+        # the SCD write path's subscription leg: writes that found
+        # subscribers, and the subscribers they bumped and returned
+        scd = getattr(self.scd, "_local", self.scd)
+        out["dss_scd_notifying_writes_total"] = scd._notifying_writes
+        out["dss_scd_subscribers_notified_total"] = scd._subs_notified
         # per-key-range load accounting (the skew-aware rebalancer's
         # measurement input)
         for k, v in self.range_load.stats().items():

@@ -57,6 +57,10 @@ STAGE_NAMES = (
     "auth_ms", "covering_ms", "store_ms", "serialize_ms", "service_ms",
     "coalesce_wait_ms", "shm_ring_ms", "proxy_ms", "catchup_ms",
     "push_match_ms", "push_deliver_ms",
+    # a notifying write's two legs after the match (dar/dss_store.py):
+    # the subscribers' index bump with its journal record, and the
+    # hand-off to the delivery pipeline
+    "sub_bump_ms", "push_offer_ms",
     # the ring round trip at its seams (dar/shmfront.py): enqueue ->
     # claim -> pickup -> response write -> seen; they sum to shm_ring_ms
     "ring_pickup_ms", "ring_queue_ms", "ring_serve_ms", "ring_return_ms",

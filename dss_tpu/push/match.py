@@ -27,7 +27,7 @@ import numpy as np
 
 from dss_tpu import chaos
 from dss_tpu.geo import s2cell
-from dss_tpu.obs import stages
+from dss_tpu.obs import stages, trace
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
 from dss_tpu.plan.planner import BatchShape, Planner
 
@@ -70,6 +70,7 @@ class MatchStage:
         self.batches = 0
         self.queries = 0
         self.absorbed = 0  # device-class faults re-served on the host
+        self.device_batches = 0  # batches that launched the kernel
 
     # -- planning ---------------------------------------------------------
 
@@ -109,10 +110,17 @@ class MatchStage:
     def _run_table(self, queries, now_ns: int,
                    host_route: bool) -> List[List[str]]:
         keys_list, alt_lo, alt_hi, t0, t1 = self._pack(queries)
-        return self._table.query_many(
+        # the table's own halves, so that the stage can say whether the
+        # batch launched the kernel: under the host scan's candidate
+        # cap every tier answers from its host postings copy, whatever
+        # route the planner named
+        pq = self._table.query_many_submit(
             keys_list, alt_lo, alt_hi, t0, t1,
             now=int(now_ns), host_route=host_route,
         )
+        if pq is not None and pq.used_device():
+            self.device_batches += 1
+        return self._table.query_many_collect(pq)
 
     def _run_oracle(self, queries, now_ns: int) -> List[List[str]]:
         if self._table is not None:
@@ -138,6 +146,11 @@ class MatchStage:
         b = len(queries)
         if b == 0:
             return []
+        with trace.annotate("push.match"):
+            return self._match_many(queries, now_ns)
+
+    def _match_many(self, queries, now_ns: int) -> List[List[str]]:
+        b = len(queries)
         t0 = time.perf_counter()
         state = self._planner.capture(device_ok=self._device_ok())
         plan = self._planner.plan(
@@ -195,4 +208,5 @@ class MatchStage:
             "match_batches": self.batches,
             "match_queries": self.queries,
             "match_absorbed": self.absorbed,
+            "match_device_batches": self.device_batches,
         }

@@ -420,6 +420,7 @@ class PushPipeline:
                 self.log.oldest_pending_age_s(), 3
             ),
             "dss_push_skipped_total": self.skipped,
+            "dss_push_offers_total": self.offers,
             "dss_push_fed_forwarded_total": self.fed_forwarded,
             "dss_push_fed_ingested_total": self.fed_ingested,
             "dss_push_match_batches_total": sum(
@@ -430,6 +431,9 @@ class PushPipeline:
             ),
             "dss_push_match_absorbed_total": sum(
                 st.absorbed for st in self._stages.values()
+            ),
+            "dss_push_match_device_total": sum(
+                st.device_batches for st in self._stages.values()
             ),
             "dss_push_breaker_state": dict(p["breaker_state"]),
         }
@@ -455,10 +459,12 @@ def empty_stats() -> dict:
         "dss_push_delivery_lag_p99_ms": 0.0,
         "dss_push_oldest_pending_s": 0.0,
         "dss_push_skipped_total": 0,
+        "dss_push_offers_total": 0,
         "dss_push_fed_forwarded_total": 0,
         "dss_push_fed_ingested_total": 0,
         "dss_push_match_batches_total": 0,
         "dss_push_match_queries_total": 0,
         "dss_push_match_absorbed_total": 0,
+        "dss_push_match_device_total": 0,
         "dss_push_breaker_state": {},
     }

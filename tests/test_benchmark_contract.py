@@ -3,15 +3,19 @@
 `dssbench/metrics/*.json` name counter and gauge families on `/metrics`
 and lines of the leader's boot log; a PR that deletes one of them used
 to find out from a `null` in the ledger a day later.  One case per
-metric file whose reader is `scrape_ratio`, `scrape_rate` or `bootlog`,
-against one boot of the served topology on the CPU backend, started
+metric file whose reader is `scrape_ratio`, `scrape_rate`, `stage_mean`
+or `bootlog`, against one boot of the served topology on the CPU backend
+(with `--push` and tokens, as the write cells' deployments), started
 and awaited by the harness's own code (`dssbench.run.start_server`,
 `dssbench.deploy.wait_ready`: the leader's "resident AOT warm:" line is
 part of the contract, the harness sends nothing before it) and scraped
 by its parser.  Every family pattern the file names has to match a key
 of the process it names (`leader`: the device owner's loopback port;
 `front`: the public port), by the rule of `readers/scrape_ratio.delta`;
-a `bootlog` file is handed to its reader and has to read a number.
+a `stage_mean` file's (route, stage) row has to be there, which the
+program exports once it has observed it: the boot has answered
+searches and planned flights, each flight's 200 a notifying write; a
+`bootlog` file is handed to its reader and has to read a number.
 """
 
 from __future__ import annotations
@@ -30,7 +34,7 @@ import pytest
 from dssbench import deploy, run, traffic as tr
 from dssbench.readers import bootlog
 
-READERS = ("scrape_ratio", "scrape_rate", "bootlog")
+READERS = ("scrape_ratio", "scrape_rate", "stage_mean", "bootlog")
 
 
 def _metric_files() -> list:
@@ -57,20 +61,32 @@ CONFIG = {
             "scd_sub": {"n": 10, "cells": [4, 12]},
         },
     },
+    # the write cells' server: every caller a USS with a token (one
+    # anonymous writer would meet the quota of 10 subscriptions a cell),
+    # and the push pipeline attached, no webhook registered
     "server": {
-        "flags": ["--enable_scd", "--insecure_no_auth"], "workers": 2,
+        "flags": ["--enable_scd", "--push"], "workers": 2,
         "env": {"DSS_RES_BATCH_BUCKETS": "16",
                 "DSS_RES_WINDOW_BUCKETS": "1024"},
+        "auth": {"owners": 8, "audience": "localhost", "ttl_s": 3000,
+                 "scope": "utm.strategic_coordination "
+                          "dss.read.identification_service_areas"},
     },
 }
-# one RID poll and one small op-intent check in turn: every stage of a
-# search through the ring is observed; none reaches the device route
+# a RID poll, a small op-intent check and a planned flight (PUT, 409 ->
+# key -> 200) in turn: every stage of a search through the ring and of
+# a notifying write through the proxy is observed; none reaches the
+# device route but the write's match (rqmatch)
 TRAFFIC = {"components": [
-    {"share": 0.5, "endpoint": "rid_search",
+    {"share": 0.4, "endpoint": "rid_search",
      "w_cells": [1, 2], "h_cells": [1, 2]},
-    {"share": 0.5, "endpoint": "scd_query",
+    {"share": 0.4, "endpoint": "scd_query",
      "w_cells": [2, 3], "h_cells": [2, 3],
      "alt_band_m": 60, "alt_ceiling_m": 2900, "timed_every": 2,
+     "opens_in_s": [7200, 14400], "lasts_s": [900, 3600]},
+    {"share": 0.2, "endpoint": "scd_put",
+     "w_cells": [1, 2], "h_cells": [1, 2],
+     "alt_band_m": 40, "alt_ceiling_m": 2900,
      "opens_in_s": [7200, 14400], "lasts_s": [900, 3600]},
 ]}
 BOOT_TIMEOUT_S = 300
@@ -79,7 +95,7 @@ BOOT_TIMEOUT_S = 300
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """{'leader': keys, 'front': keys, 'bootlog': records} of one boot
-    that has answered 20 searches."""
+    that has answered 16 searches and 4 planned flights."""
     work = str(tmp_path_factory.mktemp("served"))
     wal = os.path.join(work, "dss.wal")
     t_gen = int(time.time())
@@ -91,6 +107,7 @@ def served(tmp_path_factory):
         srv = run.start_server(CONFIG, wal, work, "cpu", False, cores, cores)
     try:
         deploy.wait_ready(srv, CONFIG["server"]["workers"], BOOT_TIMEOUT_S)
+        tr.TOKENS[:] = srv.tokens
         requests = tr.build(TRAFFIC, metro, ref, {},
                             np.random.default_rng(1), t_gen, 10, 2.0)
 
@@ -99,15 +116,32 @@ def served(tmp_path_factory):
             # come (a worker may still be binding; the merged families
             # do not care which one answered)
             client = tr.Client(srv.port)
-            await tr.prefill(client, requests, 2)
+            out = await tr.prefill(client, requests, 2)
             await client.close()
+            return out
 
-        asyncio.run(ask())
+        out = asyncio.run(ask())
         got = run.scrape_all(srv)
         got["bootlog"], _ = srv.log_records()
+        got["ended"] = [int(c[-1].status) for c in out.chain if c]
+        got["statuses"] = [int(st) for st in out.status]
     finally:
+        tr.TOKENS.clear()
         srv.stop()
     return got
+
+
+def test_the_boot_answered_searches_and_notifying_writes(served):
+    """What the cases below rest on: every request answered, every
+    planned flight accepted (its 200 names at least its own implicit
+    subscription, so each is a notifying write on the rqmatch route)."""
+    assert served["statuses"] == [200] * 20
+    assert served["ended"] == [200] * 4
+    leader = served["leader"]
+    assert leader["dss_scd_notifying_writes_total"] == 4
+    assert leader["dss_scd_subscribers_notified_total"] >= 4
+    assert leader["dss_push_offers_total"] == 4
+    assert leader["dss_dar_scd_sub_co_plan_rqmatch"] == 4
 
 
 def _allocator_peak(monkeypatch) -> bool:
@@ -141,7 +175,15 @@ def test_the_program_exports_what_the_metric_reads(metric, served,
             f"for {args}: no such line was logged"
         )
         return
-    keys = served[args["proc"]]
+    keys = served[args.get("proc", "front")]
+    if metric["reader"] == "stage_mean":
+        for fam in ("sum", "count"):
+            row = (f'dss_stage_duration_seconds_{fam}{{route="'
+                   f'{args["route"]}",stage="{args["stage"]}"}}')
+            assert keys.get(row, 0) > 0, (
+                f"dssbench/metrics/{name}.json reads {row} from the "
+                "front's /metrics: no such row was observed")
+        return
     for pat in args.get("num", []) + args.get("den", []) + args.get(
             "names", []):
         found = pat in keys or (

@@ -620,3 +620,42 @@ def test_configure_toggles_resident_loop():
     finally:
         co.close()
         table.close()
+
+
+@pytest.mark.parametrize("postings, sent_to_device, warmed", [
+    (20_000, 5, False),  # a write cell's op L1 tier: the host scan's
+    (fastpath.FastTable.HOST_MAX_CANDIDATES, 5, False),
+    (fastpath.FastTable.HOST_MAX_CANDIDATES + 1, 5, True),
+    (800_000, 5, True),  # an L0 of a class the kernel serves
+    (80_000, 0, False),  # a city's subscriptions: never off the host
+])
+def test_fold_time_warm_skips_what_the_host_scan_answers(
+        postings, sent_to_device, warmed, monkeypatch):
+    """The fold-time hook compiles the AOT grid only for a tier that a
+    drain can send to the device (one holding more postings than the
+    host scan's cap), in a class that has sent a query there.  A
+    smaller tier's block count moves at every fold, and a class the
+    host answers alone rebuilds its L0 at every major compaction:
+    warming either compiled beside served requests for nothing."""
+    hooks = []
+
+    class Table(_GatedTable):
+        def set_resident_warm(self, fn):
+            hooks.append(fn)
+
+    inner = DarTable()
+    co = QueryCoalescer(Table(inner), resident=True)
+    try:
+        asked = []
+        monkeypatch.setattr(type(co.resident_loop().kernel), "warm_async",
+                            lambda self, ft: asked.append(ft))
+        co._stat_device_members = sent_to_device
+
+        class Tier:
+            n_postings = postings
+
+        hooks[-1](Tier)
+        assert (asked == [Tier]) is warmed
+    finally:
+        co.close()
+        inner.close()
