@@ -1671,8 +1671,19 @@ class QueryCoalescer:
                 "co_batch_shrinks": self._ctl.shrinks,
                 "co_slo_ms": self._slo_ms,
             }
+        # what the class's fused kernels moved across the bus (the
+        # table sums them at collect, whichever route launched):
+        # getattr: the tests' stand-in tables have none
+        io = getattr(self._table, "device_io", None)
+        launches, uploads, up_bytes, down_bytes = (
+            io() if io is not None else (0, 0, 0, 0)
+        )
         with self._slock:
             out.update(
+                co_dev_launches=launches,
+                co_dev_uploads=uploads,
+                co_dev_h2d_bytes=up_bytes,
+                co_dev_d2h_bytes=down_bytes,
                 co_batches=self._stat_batches,
                 co_items=self._stat_items,
                 co_inline=self._stat_inline,

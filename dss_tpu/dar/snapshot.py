@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -310,6 +310,12 @@ class _PendingQuery:
         so tier-accounting changes can't desync them."""
         return any(p is not None for p in self.tier_pending)
 
+    def device_io(self) -> Tuple[int, int, int, int]:
+        """(launches, uploads, bytes up, bytes down) of this batch's
+        kernels, overflow re-runs included: read after collect."""
+        io = [p.io for p in self.tier_pending if p is not None]
+        return tuple(sum(col) for col in zip(*io)) if io else (0, 0, 0, 0)
+
     def candidates(self) -> np.ndarray:
         """i64[tiers, B]: each member's candidate postings in each
         tier of the state this batch ran against.  The host gate sums
@@ -384,6 +390,12 @@ class DarTable:
         # scheduled (async — compiles land on a background thread and
         # must never stall the fold) as early as possible
         self._resident_warm = None
+        # what the fused kernel moved across the bus for this class,
+        # summed at collect over every route (inline, drained,
+        # resident stream, direct query_many): launches, uploads,
+        # bytes up, bytes down (device_io; co_dev_* on /metrics)
+        self._io_lock = threading.Lock()
+        self._device_io = (0, 0, 0, 0)
         self._stats_folds = 0
         self._stats_fold_ms = 0.0
         self._stats_swap_ms = 0.0
@@ -871,11 +883,22 @@ class DarTable:
             )
             _scatter_hits(out_sets, oq, oent, st.overlay.ids)
 
+        io = pq.device_io()
+        if io[0]:
+            with self._io_lock:
+                self._device_io = tuple(
+                    a + b for a, b in zip(self._device_io, io)
+                )
         # an entity updated since a tier was built appears via a newer
         # tier or the overlay only (its old slot is in that tier's dead
         # set); sets dedup any transient double-sighting.  Sorted for
         # deterministic responses.
         return [sorted(s) for s in out_sets]
+
+    def device_io(self) -> Tuple[int, int, int, int]:
+        """(launches, uploads, bytes up, bytes down) of every fused
+        kernel this class has collected."""
+        return self._device_io
 
     def query_many(
         self,

@@ -327,14 +327,15 @@ def query_host(
 
 def pack_windows(
     host_key, qk_flat, w: int, block: int, pow2_bucket,
-    sample=None, stride: int = 64, sample0=None,
+    sample=None, stride: int = 64, sample0=None, tail: int = 0,
 ):
     """Native FastTable._pack_windows: postings-range binary searches +
     window expansion + meta packing in two GIL-released calls (~22 ms
     -> ~3 ms per 8k-query batch at 1M postings).  Returns
-    (wins, win_q, win_blk, nw) with bit-identical contents to the
-    numpy path, or None when the lib is unavailable.  qk_flat must be
-    contiguous i32; wins pad rows are zero exactly like the numpy
+    (packed, win_q, win_blk, nw) with bit-identical contents to the
+    numpy path (packed: the two window rows, flat, then `tail` zero
+    words), or None when the lib is unavailable.  qk_flat must be
+    contiguous i32; pad windows are zero exactly like the numpy
     path (start == end == 0 -> no lanes match).  sample (optional) is
     the caller-cached host_key[::stride] copy that keeps the search's
     top levels L2-resident; sample0 (optional, requires sample) must
@@ -365,19 +366,20 @@ def pack_windows(
         empty = np.zeros(0, np.int32)
         return None, empty, empty, 0
     bucket = pow2_bucket(int(nw))
-    wins = np.zeros((2, bucket), np.int32)
+    packed = np.zeros(2 * bucket + tail, np.int32)
     win_q = np.empty(nw, np.int32)
     win_blk = np.empty(nw, np.int32)
     rc = lib.dss_win_expand(
         _ptr(lo, ctypes.c_int64), _ptr(hi, ctypes.c_int64), np.int64(n),
         np.int32(w), np.int64(block),
-        _ptr(wins[0], ctypes.c_int32), _ptr(wins[1], ctypes.c_int32),
+        _ptr(packed, ctypes.c_int32),
+        _ptr(packed[bucket:], ctypes.c_int32),
         _ptr(win_q, ctypes.c_int32), _ptr(win_blk, ctypes.c_int32),
         np.int64(nw),
     )
     if rc != nw:  # pragma: no cover — count/expand disagreement
         return None
-    return wins, win_q, win_blk, int(nw)
+    return packed, win_q, win_blk, int(nw)
 
 
 def decode_hits(

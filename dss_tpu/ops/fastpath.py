@@ -12,8 +12,13 @@ Division of labor (each side doing what its hardware is good at):
                  bit-packed to u32 words, and the non-empty words
                  compacted on device (hand-rolled cumsum+scatter — NOT
                  jnp.nonzero, whose searchsorted lowering is ~20x
-                 slower on TPU) so the D2H transfer is proportional to
-                 hits, not windows scanned.
+                 slower on TPU) so the result's useful part is
+                 proportional to hits, not windows scanned.
+
+A query crosses the bus twice: one packed i32 buffer up (the windows,
+then the per-query bounds as their f32 / i64 bit patterns; pack_bounds)
+and one flat i32 result down, sized by the window bucket the query
+scans (max_words_for).
 
 This replaces the reference's per-query SQL conflict scan
 (pkg/scd/store/cockroach/operations.go:374-435) and the RID
@@ -136,15 +141,95 @@ def _expand_hit_words(bits_u32: np.ndarray):
     return wi, bitpos
 
 
+def max_words_for(window_bucket: int) -> int:
+    """Hit-word slots of the result for one window bucket: THE sizing
+    rule (submit's auto size; ops/resident.py compiles its grid by it).
+    A window yields at most WORDS non-empty words, so up to 16,384
+    windows the buffer is that hard bound and cannot overflow; above,
+    one word a window is the typical ceiling and collect() retries at
+    the hard bound."""
+    wb = int(window_bucket)
+    return min(WORDS * wb, max(1 << 16, wb))
+
+
+# the query's wire format, host -> device: ONE i32 buffer
+#   [0, W)          window block index        (W = window bucket)
+#   [W, 2W)         window meta: start | end<<8 | qidx<<16
+#   [2W, 2W+B)      alt_lo, f32 bit patterns  (B = batch bucket)
+#   [2W+B, 2W+2B)   alt_hi, f32 bit patterns
+#   [2W+2B, 2W+4B)  t0_eff, i64 as (low, high) i32 pairs
+#   [2W+4B, 2W+6B)  t_end,  i64 as (low, high) i32 pairs
+# little-endian, as every platform JAX runs on; pad queries stay zero
+# (inert: no window's meta names an index >= the real batch).
+QUERY_WORDS = 6  # i32 words per padded query after the windows
+
+
+def packed_words(windows: int, batch_bucket: int) -> int:
+    """Length of the packed query buffer for one shape bucket."""
+    return 2 * int(windows) + QUERY_WORDS * int(batch_bucket)
+
+
+def pack_bounds(packed, windows, alt_lo, alt_hi, t0_eff, t_end) -> None:
+    """Write the per-query bounds behind the windows of `packed`
+    (_pack_windows left QUERY_WORDS * batch bucket zero words there).
+    Bit patterns only: nothing is rounded or quantised."""
+    o = 2 * windows
+    bb = (len(packed) - o) // QUERY_WORDS
+    n = len(alt_lo)
+    packed[o:o + n] = np.asarray(alt_lo, np.float32).view(np.int32)
+    o += bb
+    packed[o:o + n] = np.asarray(alt_hi, np.float32).view(np.int32)
+    o += bb
+    packed[o:o + 2 * n] = np.ascontiguousarray(t0_eff, np.int64).view(
+        np.int32
+    )
+    o += 2 * bb
+    packed[o:o + 2 * n] = np.ascontiguousarray(t_end, np.int64).view(
+        np.int32
+    )
+
+
+def unpack_query(packed, windows):
+    """-> (wins (2, W), q_alo f32[B], q_ahi f32[B], q_t0 i64[B], q_t1
+    i64[B]) of one packed query buffer: a numpy buffer on the host
+    (views, for tests and tools) or the traced one inside the kernel."""
+    w2 = 2 * windows
+    bb = (packed.shape[0] - w2) // QUERY_WORDS
+    wins = packed[:w2].reshape(2, windows)
+    parts = (
+        packed[w2:w2 + bb],
+        packed[w2 + bb:w2 + 2 * bb],
+        packed[w2 + 2 * bb:w2 + 4 * bb],
+        packed[w2 + 4 * bb:],
+    )
+    if isinstance(packed, np.ndarray):
+        return (wins, parts[0].view(np.float32), parts[1].view(np.float32),
+                parts[2].view(np.int64), parts[3].view(np.int64))
+
+    def i64(x):  # plain arithmetic: the TPU's 64-bit lowering has it
+        x = x.reshape(bb, 2)
+        return (x[:, 1].astype(jnp.int64) << 32) | (
+            x[:, 0].astype(jnp.int64) & 0xFFFFFFFF
+        )
+
+    return (
+        wins,
+        jax.lax.bitcast_convert_type(parts[0], jnp.float32),
+        jax.lax.bitcast_convert_type(parts[1], jnp.float32),
+        i64(parts[2]),
+        i64(parts[3]),
+    )
+
+
 def fused_window_filter(
     b_alo, b_ahi, b_t0, b_t1,  # (NB, 128) exact block columns
-    wins,  # (2, NWpad) i32: [block index, start | end<<8 | qidx<<16]
-    q_alo, q_ahi,  # exact per-query f32[B]
-    q_t0, q_t1,  # exact per-query i64[B]; q_t0 pre-folded with now
-    #              host-side: t0_eff = max(t_start, now), so
-    #              `t_end >= t0_eff` covers both the window test and
-    #              the `ends at/after now` liveness rule, per query
-    *, max_words, chunk=16384,
+    packed,  # i32[2W + 6B]: the query's one upload (layout above).
+    #          wins (2, W): [block index, start | end<<8 | qidx<<16];
+    #          exact per-query f32 altitudes and i64 instants, the
+    #          lower one pre-folded with now host-side: t0_eff =
+    #          max(t_start, now), so `t_end >= t0_eff` covers both the
+    #          window test and the `ends at/after now` liveness rule
+    *, windows, max_words, chunk=16384,
 ):
     """Exact window filter + hit bit-packing + word compaction, all
     on device — the fused kernel's pure function, at module level so
@@ -160,16 +245,17 @@ def fused_window_filter(
       out[1 : 1+max_words]       = flat word positions (window*4+w)
       out[1+max_words : ]        = u32 hit bits per word (as i32)
 
-    The D2H transfer is proportional to hit words, not windows
-    scanned.  Compaction is a hand-rolled cumsum+scatter (~35x
-    faster than jnp.nonzero's searchsorted lowering on TPU)."""
+    The result is sized by the window bucket (max_words_for), its
+    useful part by the hits.  Compaction is a hand-rolled
+    cumsum+scatter (~35x faster than jnp.nonzero's searchsorted
+    lowering on TPU)."""
     # named scopes put the kernel and its three phases into the op
-    # names of a profiler capture.  Metadata only, and the operations
-    # are traced in the order they always were, so the compiled
-    # program and its persistent-cache key are unchanged
+    # names of a profiler capture.  Metadata only: they change neither
+    # the compiled program nor its persistent-cache key
     # (jax_compilation_cache_include_metadata_in_key is off).
     with jax.named_scope("dss.fused_window_filter"):
-        nw = wins.shape[1]
+        wins, q_alo, q_ahi, q_t0, q_t1 = unpack_query(packed, windows)
+        nw = windows
         win_blk, meta = wins[0], wins[1]
         win_q = meta >> 16
         lanes = jnp.arange(BLOCK, dtype=jnp.int32)
@@ -255,7 +341,8 @@ def warmup(device=None) -> None:
     traffic.  Point lookups (batch <= HOST_MAX_BATCH) answer from the
     host postings copy and never touch the device, so this warms the
     FIRST device shapes a coalesced burst beyond that threshold hits
-    (batch bucket 128; window buckets 256 and 1024; word bucket 2^16)
+    (batch bucket 128; window buckets 256 and 1024, each with its
+    max_words_for result)
     — the multi-second XLA compiles stay off request deadlines.
     Servers call this from a background thread at startup."""
     n = BLOCK
@@ -305,11 +392,11 @@ class PendingBatch:
 
     __slots__ = (
         "out", "win_q", "win_blk", "host_inputs", "nw", "max_words",
-        "kernel",
+        "kernel", "io",
     )
 
     def __init__(self, out, win_q, win_blk, host_inputs, nw, max_words,
-                 kernel=None):
+                 kernel=None, up_bytes=0):
         self.out = out  # device flat i32: [n_words, wordpos..., bits...]
         self.win_q = win_q
         self.win_blk = win_blk
@@ -317,6 +404,10 @@ class PendingBatch:
         self.nw = nw
         self.max_words = max_words
         self.kernel = kernel  # resident AOT selector (overflow retry)
+        # what this batch moved across the bus: (launches, uploads,
+        # bytes up, bytes down); collect() adds an overflow re-run's.
+        # DarTable sums them per entity class (co_dev_* on /metrics)
+        self.io = (1, 1, int(up_bytes), int(out.nbytes))
 
     def ready(self) -> None:
         """Block until the device computation has completed (readiness
@@ -421,7 +512,9 @@ class FastTable:
     # the resident path compiles its own donated AOT twin of the same
     # function (ops/resident.py) so both trace identically
     _fused_xla = staticmethod(
-        partial(jax.jit, static_argnames=("max_words", "chunk"))(
+        partial(
+            jax.jit, static_argnames=("windows", "max_words", "chunk")
+        )(
             fused_window_filter
         )
     )
@@ -487,10 +580,13 @@ class FastTable:
             )
         return hk, sample, sample0
 
-    def _pack_windows(self, qkeys: np.ndarray):
-        """Expand + pack windows for the fused kernel: one (2, bucket)
-        i32 upload [blk, start|end<<8|qidx<<16].  Returns
-        (wins, win_q, win_blk, nw); nw == 0 means no work.
+    def _pack_windows(self, qkeys: np.ndarray, tail: int = 0):
+        """Expand + pack windows for the fused kernel into the head of
+        the query's one upload: a flat i32 buffer of the (2, bucket)
+        windows [blk | start|end<<8|qidx<<16], row after row, then
+        `tail` zero words for the caller (submit's per-query bounds,
+        pack_bounds).  Returns (packed, win_q, win_blk, nw); nw == 0
+        means no work (packed None); bucket == (len(packed) - tail) // 2.
 
         Prefers the native (C++) kernel — the binary searches + ragged
         expansion cost ~22 ms per 8k-query batch at 1M postings in
@@ -505,7 +601,7 @@ class FastTable:
             hk, sample, sample0 = self._sample_index()
             res = nat.pack_windows(
                 hk, qk.ravel(), qk.shape[1], BLOCK, pow2_bucket,
-                sample=sample, sample0=sample0,
+                sample=sample, sample0=sample0, tail=tail,
             )
             if res is not None:
                 return res
@@ -517,11 +613,40 @@ class FastTable:
         # <= 2^15 batch gate above keeps the sign bit clear so
         # meta >> 16 recovers it intact
         bucket = pow2_bucket(nw)
-        wins = np.zeros((2, bucket), np.int32)
-        wins[0, :nw] = win_blk
+        packed = np.zeros(2 * bucket + tail, np.int32)
+        packed[:nw] = win_blk
         # pad rows keep meta 0 -> start == end == 0 -> no lanes match
-        wins[1, :nw] = win_start | (win_end << 8) | (win_q << 16)
-        return wins, win_q, win_blk, nw
+        packed[bucket:bucket + nw] = (
+            win_start | (win_end << 8) | (win_q << 16)
+        )
+        return packed, win_q, win_blk, nw
+
+    def _pack_query(self, qkeys, alt_lo, alt_hi, t_start, t_end, now):
+        """The query batch's one upload, whole: windows, then bounds.
+        -> (packed, window bucket, batch bucket, win_q, win_blk, nw);
+        nw == 0 means no work (packed None)."""
+        # pad the batch axis to a pow2 bucket too: the coalescer drains
+        # arbitrary batch sizes, and an unpadded (B,) shape would force
+        # a fresh XLA compile per distinct B.  Pad queries are inert —
+        # no window's meta references an index >= B.
+        b = len(qkeys)
+        bb = pow2_bucket(b, lo=16)
+        packed, win_q, win_blk, nw = self._pack_windows(
+            qkeys, tail=QUERY_WORDS * bb
+        )
+        if nw == 0:
+            return None, 0, bb, win_q, win_blk, 0
+        bucket = (len(packed) - QUERY_WORDS * bb) // 2
+        # fold the liveness rule into the lower time bound per query:
+        # t_end >= max(t_start, now) == (t_end >= t_start) & (t_end >= now)
+        t0_eff = np.maximum(
+            np.asarray(t_start, np.int64), np.asarray(now, np.int64)
+        )
+        pack_bounds(
+            packed, bucket, alt_lo, alt_hi,
+            np.broadcast_to(t0_eff, (b,)), t_end,
+        )
+        return packed, bucket, bb, win_q, win_blk, nw
 
     def submit(
         self,
@@ -542,43 +667,20 @@ class FastTable:
         Returns None when no query key has any postings (empty
         result).
 
-        max_words=None auto-sizes the compacted-hit-word buffer to a
-        pow2 bucket >= the window count (one non-empty word per window
-        is the typical ceiling; 4*nw is the hard one).  collect()
-        retries at the 4*nw hard bound on overflow."""
-        wins, win_q, win_blk, nw = self._pack_windows(qkeys)
+        The query goes up as ONE numpy buffer, handed to the
+        executable as it is (the dispatch path transfers it; no
+        per-array jnp.asarray), and comes back as one buffer of
+        max_words_for(window bucket) hit-word slots: the hard bound of
+        four words a window up to 16,384 windows, so only larger
+        buckets (or an explicit max_words) can overflow; collect()
+        then retries at the hard bound."""
+        packed, bucket, bb, win_q, win_blk, nw = self._pack_query(
+            qkeys, alt_lo, alt_hi, t_start, t_end, now
+        )
         if nw == 0:
             return None
         if max_words is None:
-            max_words = pow2_bucket(nw, lo=1 << 16)
-
-        # fold the liveness rule into the lower time bound per query:
-        # t_end >= max(t_start, now) == (t_end >= t_start) & (t_end >= now)
-        t0_eff = np.maximum(
-            np.asarray(t_start, np.int64), np.asarray(now, np.int64)
-        )
-        # pad the batch axis to a pow2 bucket too: the coalescer drains
-        # arbitrary batch sizes, and an unpadded (B,) shape would force
-        # a fresh XLA compile per distinct B.  Pad queries are inert —
-        # no window's meta references an index >= B.
-        b = len(qkeys)
-        bpad = pow2_bucket(b, lo=16) - b
-
-        def qpad(a, dtype):
-            a = np.asarray(a, dtype)
-            return np.concatenate([a, np.zeros(bpad, dtype)]) if bpad else a
-
-        args = (
-            self.b_alo,
-            self.b_ahi,
-            self.b_t0,
-            self.b_t1,
-            jnp.asarray(wins),
-            jnp.asarray(qpad(alt_lo, np.float32)),
-            jnp.asarray(qpad(alt_hi, np.float32)),
-            jnp.asarray(qpad(np.broadcast_to(t0_eff, (b,)), np.int64)),
-            jnp.asarray(qpad(t_end, np.int64)),
-        )
+            max_words = max_words_for(bucket)
         # resident path: a pre-compiled (AOT, donated-I/O) executable
         # for exactly this (blocks, window bucket, batch bucket,
         # max_words) shape — no trace, no compile, no per-call output
@@ -586,13 +688,14 @@ class FastTable:
         # back to the shared jit, which is today's behavior.
         fn = None
         if kernel is not None:
-            fn = kernel.lookup(
-                self, wins.shape[1], b + bpad, max_words
-            )
+            fn = kernel.lookup(self, bucket, bb, max_words)
         if fn is not None:
-            out = fn(*args)
+            out = fn(self.b_alo, self.b_ahi, self.b_t0, self.b_t1, packed)
         else:
-            out = self._fused_xla(*args, max_words=max_words)
+            out = self._fused_xla(
+                self.b_alo, self.b_ahi, self.b_t0, self.b_t1, packed,
+                windows=bucket, max_words=max_words,
+            )
         out.copy_to_host_async()
         return PendingBatch(
             out,
@@ -602,6 +705,7 @@ class FastTable:
             nw,
             max_words,
             kernel,
+            packed.nbytes,
         )
 
     def collect(
@@ -620,13 +724,14 @@ class FastTable:
             # cannot overflow.  Exact same semantics, one extra round
             # trip.
             qkeys, alt_lo, alt_hi, t_start, t_end, now = pending.host_inputs
-            hard = pow2_bucket(4 * pending.nw, lo=1 << 16)
-            return self.collect(
-                self.submit(
-                    qkeys, alt_lo, alt_hi, t_start, t_end,
-                    now=now, max_words=hard, kernel=pending.kernel,
-                )
+            hard = WORDS * pow2_bucket(pending.nw)
+            rerun = self.submit(
+                qkeys, alt_lo, alt_hi, t_start, t_end,
+                now=now, max_words=hard, kernel=pending.kernel,
             )
+            res = self.collect(rerun)
+            pending.io = tuple(a + b for a, b in zip(pending.io, rerun.io))
+            return res
         wordpos = out[1 : 1 + n_words]
         bits = out[1 + mw : 1 + mw + n_words].astype(np.int32)
         if n_words == 0:

@@ -21,8 +21,8 @@ to chunked host scans; this subsystem *shrinks* it, with three parts:
   a fixed layout — size it from the live miss counters.
 
   DONATED, PRE-PINNED I/O (the AOT twin's donate_argnums) — the
-  query-side arrays (windows + per-query bounds) are donated to the
-  executable, so in steady state XLA re-uses their device memory for
+  query's one packed buffer (windows + per-query bounds) is donated to
+  the executable, so in steady state XLA can re-use its device memory for
   the output instead of allocating per call; the table-side postings
   blocks stay resident in HBM exactly as the kernel consumes them (the
   pjit pitfall the SNIPPETS.md reference warns about: outputs of one
@@ -112,11 +112,9 @@ def window_bucket_grid() -> Tuple[int, ...]:
     )
 
 
-def max_words_for(window_bucket: int) -> int:
-    """submit() auto-sizes the compacted-hit-word buffer to
-    pow2_bucket(nw, lo=2^16); for every window bucket <= 2^16 that is
-    the constant 2^16, above it the bucket itself."""
-    return max(1 << 16, int(window_bucket))
+# the grid's executables are keyed by what submit() auto-sizes to: ONE
+# rule, fastpath's (tests/test_packed_query.py holds the two together)
+max_words_for = fastpath.max_words_for
 
 
 class AotCache:
@@ -157,15 +155,14 @@ class AotCache:
 
     def _donating_jit(self):
         # one jit object for every bucket: lower() specializes per
-        # shape.  Donated positions are the query-side arrays only
-        # (wins, q_alo, q_ahi, q_t0, q_t1) — donating the table's
-        # postings columns would free the snapshot under every other
-        # reader.
+        # shape.  The donated position is the query's one packed
+        # buffer — donating the table's postings columns would free
+        # the snapshot under every other reader.
         if self._jit is None:
             self._jit = jax.jit(
                 fastpath.fused_window_filter,
-                static_argnames=("max_words", "chunk"),
-                donate_argnums=(4, 5, 6, 7, 8),
+                static_argnames=("windows", "max_words", "chunk"),
+                donate_argnums=(4,),
             )
         return self._jit
 
@@ -208,11 +205,10 @@ class AotCache:
             sds((nb, fastpath.BLOCK), jnp.float32),  # b_ahi
             sds((nb, fastpath.BLOCK), jnp.int64),  # b_t0
             sds((nb, fastpath.BLOCK), jnp.int64),  # b_t1
-            sds((2, int(window_bucket)), jnp.int32),  # wins
-            sds((int(batch_bucket),), jnp.float32),  # q_alo
-            sds((int(batch_bucket),), jnp.float32),  # q_ahi
-            sds((int(batch_bucket),), jnp.int64),  # q_t0
-            sds((int(batch_bucket),), jnp.int64),  # q_t1
+            sds(  # packed: the windows, then the per-query bounds
+                (fastpath.packed_words(window_bucket, batch_bucket),),
+                jnp.int32,
+            ),
         )
         t0 = time.perf_counter()
         # chaos seam: an injected failure models an XLA compile error
@@ -221,7 +217,10 @@ class AotCache:
         fault_point("aot.compile", detail=str(key))
         exe = (
             self._donating_jit()
-            .lower(*args, max_words=int(max_words))
+            .lower(
+                *args, windows=int(window_bucket),
+                max_words=int(max_words),
+            )
             .compile()
         )
         dt = (time.perf_counter() - t0) * 1000
