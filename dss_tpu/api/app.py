@@ -431,6 +431,7 @@ _GAUGE_VEC_LABELS = {
     "dss_fed_peer_state": "region",
     "dss_fed_mirror_lag_s": "region",
     "dss_push_breaker_state": "uss",
+    "dss_boot_seconds": "stage",
     # shared-memory front per-worker counters (parallel/shmring.py):
     # the leader aggregates every worker's shm stats block so ONE
     # scrape sees the whole front, keyed by the worker's process id

@@ -359,6 +359,29 @@ def resolve_backend(storage: str) -> dict:
     }
 
 
+def _log_boot(log, boot: dict) -> None:
+    """The leader's replay by stage, between `backend:` and `store
+    ready:` (dssbench/metrics/boot_*.json read the numbers; the same
+    are dss_boot_records and dss_boot_seconds{stage} on /metrics)."""
+    if boot.get("mode") == "bulk":
+        log.info(
+            "boot parse: %d records read, decoded and resolved in %.2f s",
+            boot["records"], boot["parse_s"],
+        )
+        log.info(
+            "boot build: %d postings in tables of %d bytes on the device,"
+            " built and uploaded in %.2f s (%.0f records/s over both"
+            " stages)",
+            boot["postings"], boot["device_bytes"], boot["build_s"],
+            boot["records"] / max(boot["parse_s"] + boot["build_s"], 1e-9),
+        )
+    elif boot:
+        log.info(
+            "boot loop: %d records applied one by one (the bulk path"
+            " refused the log: see the warning above)", boot["records"],
+        )
+
+
 def build_worker(args) -> web.Application:
     """A read worker: local WAL-tail replica serves searches; every
     other route proxies to the leader.  Runs on the CPU backend — the
@@ -388,7 +411,16 @@ def build_worker(args) -> web.Application:
     follower = WalFollower(
         store, args.wal_path, interval_s=args.follower_poll_interval
     )
+    t_replica = time.perf_counter()
     follower.start()
+    # the first catch-up is the worker's boot: the whole log as one
+    # batch (mode bulk), or record by record where that was refused
+    log.info(
+        "worker replica ready: %d records in %.2f s (%s)",
+        store.boot_stats.get("records", 0),
+        time.perf_counter() - t_replica,
+        store.boot_stats.get("mode", "empty log"),
+    )
     log.info(
         "read worker up: replica from %s every %.0f ms, leader %s",
         args.wal_path, args.follower_poll_interval * 1000, args.leader_url,
@@ -577,6 +609,7 @@ def build(args) -> web.Application:
         region_snapshot_every=args.region_snapshot_every,
         instance_id=args.instance_id or None,
     )
+    _log_boot(log, store.boot_stats)
     log.info(
         "store ready: storage=%s wal=%s scd=%s region=%s",
         args.storage,

@@ -27,6 +27,7 @@ from __future__ import annotations
 import contextlib
 import copy
 import dataclasses
+import logging
 import threading
 import time
 from datetime import datetime, timedelta, timezone
@@ -36,7 +37,7 @@ import numpy as np
 
 from dss_tpu import chaos, errors
 from dss_tpu.clock import Clock, to_nanos
-from dss_tpu.dar import codec
+from dss_tpu.dar import boot, codec
 from dss_tpu.dar import readcache as rcache
 from dss_tpu.obs import stages, trace
 from dss_tpu.dar.index import MemorySpatialIndex, TpuSpatialIndex
@@ -1533,7 +1534,17 @@ class DSSStore:
         # X-DSS-Freshness, and the dss_degraded_mode gauge.
         self.health = chaos.DegradationLadder()
         self.health.on_recover("device_lost", self._rewarm_after_device_loss)
-        self.wal = WriteAheadLog(None if region_url else wal_path, fsync=wal_fsync)
+        # one interner for both sub-stores; made before the log is
+        # opened because the boot resolves the log while the WAL's
+        # recovery pass reads it (_replay commits what it resolved)
+        owners = OwnerInterner()
+        self._boot_resolver = None
+        if wal_path and not region_url:
+            self._boot_resolver = boot.Resolver(owners)
+        self.wal = WriteAheadLog(
+            None if region_url else wal_path, fsync=wal_fsync,
+            sink=self._boot_resolver and self._boot_resolver.consume,
+        )
         self._lock = threading.RLock()
         self.region = None
         txn = None
@@ -1564,7 +1575,6 @@ class DSSStore:
 
         self.range_load = _tiersmod.RangeLoad()
         ts = TimestampOracle(self.clock)
-        owners = OwnerInterner()
         self.rid = RIDStoreImpl(
             clock=self.clock,
             ts_oracle=ts,
@@ -1619,6 +1629,10 @@ class DSSStore:
         # until attach_shm_front makes this process the device owner
         self._shm_owner = None
         self._replaying = False
+        # how the log was applied at boot (_replay, or a worker's first
+        # catch-up): mode "bulk" or "loop", records, seconds by stage
+        # (dss_boot_* on /metrics; cmds/server.py logs them)
+        self.boot_stats: dict = {}
         if region_url:
             self.region = RegionCoordinator(
                 self._region_client,
@@ -1668,11 +1682,44 @@ class DSSStore:
         else:
             self.scd.apply_wal(rec)
 
+    def boot_resolver(self) -> boot.Resolver:
+        """A resolver of a whole log on this store's owner interner
+        (dar/boot.py): feed it the log, then apply_log_bulk."""
+        return boot.Resolver(self.scd._owners)
+
+    def apply_log_bulk(self, resolved: boot.Resolver) -> bool:
+        """Fill this EMPTY store with a log's resolved end state as one
+        batch: the boot's path, and a worker's first catch-up.  Caller
+        holds the lock.  -> False, with nothing changed and a warning
+        logged, where the log cannot be taken so (a record type the
+        bulk path does not know, a document the codec refuses): the
+        caller then applies the log record by record."""
+        try:
+            done = resolved.commit(self)
+        except Exception as e:  # noqa: BLE001 — the loop decides
+            if not boot.is_empty(self):
+                raise  # half a boot is no state to go on from
+            logging.getLogger("dss.dar").warning(
+                "bulk boot refused (%s): applying the log's %d records "
+                "one by one", e, resolved.records,
+            )
+            self.boot_stats = {"mode": "loop", "records": resolved.records}
+            return False
+        self.boot_stats = {"mode": "bulk", **done}
+        return True
+
     def _replay(self):
+        """The boot: the log's end state as one batch, resolved by
+        `_boot_resolver` while the WAL's recovery pass read the file
+        (its one read); record by record where that was refused."""
+        resolved, self._boot_resolver = self._boot_resolver, None
+        if resolved is None or not resolved.records:
+            return
         self._replaying = True
         try:
-            for rec in self.wal.replay():
-                self.apply_log_record(rec)
+            if not self.apply_log_bulk(resolved):
+                for rec in self.wal.replay():
+                    self.apply_log_record(rec)
         finally:
             self._replaying = False
 
@@ -1969,6 +2016,13 @@ class DSSStore:
         scd = getattr(self.scd, "_local", self.scd)
         out["dss_scd_notifying_writes_total"] = scd._notifying_writes
         out["dss_scd_subscribers_notified_total"] = scd._subs_notified
+        # the boot: records of the log, and seconds by stage (a boot
+        # that fell back to the loop reports the records alone)
+        out["dss_boot_records"] = self.boot_stats.get("records", 0)
+        out["dss_boot_seconds"] = {
+            stage: self.boot_stats.get(f"{stage}_s", 0.0)
+            for stage in ("parse", "build")
+        }
         # per-key-range load accounting (the skew-aware rebalancer's
         # measurement input)
         for k, v in self.range_load.stats().items():

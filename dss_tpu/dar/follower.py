@@ -17,6 +17,7 @@ import threading
 import time
 from typing import Optional
 
+from dss_tpu.dar import boot
 from dss_tpu.parallel.replica import _WalTail
 
 log = logging.getLogger("dss.follower")
@@ -44,11 +45,18 @@ class WalFollower:
 
     def poll_once(self) -> int:
         """Apply any new records; -> count applied.  A single bad
-        record is skipped and counted — it must not wedge the tail."""
+        record is skipped and counted — it must not wedge the tail.
+        The first catch-up of an empty replica takes the log as one
+        batch (the leader's boot path, dar/boot.py); the tail after
+        it, and a log that path refuses, go record by record."""
+        store = self._store
+        if self._applied_seq == 0 and boot.is_empty(store):
+            n = self._catch_up_in_bulk()
+            if n is not None:
+                return n
         recs = self._tail.poll()
         if not recs:
             return 0
-        store = self._store
         with store._lock:
             store._replaying = True
             try:
@@ -63,12 +71,29 @@ class WalFollower:
                         )
             finally:
                 store._replaying = False
-        with self._seq_cond:
-            self._applied_seq = max(
-                self._applied_seq, max(r.get("seq", 0) for r in recs)
-            )
-            self._seq_cond.notify_all()
+        self._note_applied(max(r.get("seq", 0) for r in recs))
         return len(recs)
+
+    def _catch_up_in_bulk(self) -> Optional[int]:
+        """The whole log so far as one batch -> records applied; None
+        where the batch was refused: the tail has not moved, and
+        the caller reads the same records again for the loop."""
+        store = self._store
+        resolved = store.boot_resolver()
+        seq, end = self._tail.read_ahead(resolved.consume)
+        if not resolved.records:
+            return 0
+        with store._lock:
+            if not store.apply_log_bulk(resolved):
+                return None
+        self._tail.advance(end)
+        self._note_applied(seq)
+        return resolved.records
+
+    def _note_applied(self, seq: int) -> None:
+        with self._seq_cond:
+            self._applied_seq = max(self._applied_seq, seq)
+            self._seq_cond.notify_all()
 
     def wait_for(self, seq: int, timeout_s: float = 1.0) -> bool:
         """Block until the replica has applied WAL seq >= seq (the

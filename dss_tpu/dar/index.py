@@ -58,6 +58,13 @@ class MemorySpatialIndex:
         if old is not None:
             self.cell_clock.bump(old.keys)
 
+    def bulk_load(self, records: List[Record]) -> None:
+        """Replace the contents with `records` at once (the bulk boot,
+        dar/boot.py); the clock's floor is raised instead of a stamp
+        per covering, as DarTable.bulk_load does."""
+        self._recs = {r.entity_id: r for r in records}
+        self.cell_clock.bump_all()
+
     def clock_fence(self, cells_u64) -> "tuple[int, int, int, int]":
         """(incarnation, max stamp, generation, floor) over the
         covering — the read cache's O(|cells|) validity check."""
@@ -120,6 +127,11 @@ class TpuSpatialIndex:
 
     def remove(self, id):
         self._table.remove(id)
+
+    def bulk_load(self, records: List[Record]) -> None:
+        """Replace the contents with `records` in one table build and
+        one upload (the bulk boot, dar/boot.py)."""
+        self._table.bulk_load(records)
 
     def query_ids(
         self,

@@ -13,7 +13,7 @@ from __future__ import annotations
 import json
 import os
 import threading
-from typing import Callable, Iterator, Optional
+from typing import Callable, Iterable, Iterator, Optional
 
 from dss_tpu.chaos import fault_point
 
@@ -59,9 +59,79 @@ def check_format_record(rec: Optional[dict], path: str) -> None:
         )
 
 
+class LogScan:
+    """ONE pass over a log file from byte `offset` (a record boundary):
+    iterate it for the records in order; once exhausted, `valid` is the
+    end of the valid prefix in bytes and `seq` the highest sequence
+    number.  Blank lines pass; the first undecodable, non-object or
+    newline-less line ends the prefix (a torn tail, or rot: the caller
+    tells them apart).  A scan from offset 0 applies the head format
+    gate and raises LogFormatError on an unsupported version.  Format
+    records are validated metadata: they count into the prefix and are
+    not yielded.
+
+    A record is yielded as soon as it is decoded and not kept: a
+    consumer that keeps what it needs of each (dar/boot.py) holds the
+    log's end state in memory, never the log."""
+
+    def __init__(self, path: str, offset: int = 0):
+        self.path = path
+        self.valid = offset
+        self.seq = 0
+        self._first = offset == 0
+        self._records = self._read()  # opens the file at the first next()
+
+    def __iter__(self) -> Iterator[dict]:
+        """The pass itself: a second iteration goes on where the
+        first stopped."""
+        return self._records
+
+    def _read(self) -> Iterator[dict]:
+        loads = json.loads
+        # line by line off the buffered reader: each line is freed
+        # before the next is read
+        with open(self.path, "rb", buffering=1 << 20) as fh:
+            fh.seek(self.valid)
+            for line in fh:
+                if not line.endswith(b"\n"):
+                    return  # torn tail (no newline): not complete
+                try:
+                    rec = loads(line)
+                except ValueError:
+                    # JSONDecodeError, or UnicodeDecodeError from
+                    # json's encoding sniff on rotted bytes (e.g. NUL
+                    # runs look like UTF-32) — both ValueError
+                    if not line.isspace():
+                        return
+                    rec = None  # a blank line
+                if rec is not None:
+                    if not isinstance(rec, dict):
+                        return  # rot that decodes as a JSON scalar
+                    if self._first:
+                        self._first = False
+                        check_format_record(rec, self.path)
+                    if rec.get("t") != FORMAT_RECORD_TYPE:
+                        s = rec.get("seq", 0)
+                        if s > self.seq:
+                            self.seq = s
+                        yield rec
+                self.valid += len(line)
+
+    def drain(self) -> None:
+        """Run the pass to its end for `valid` and `seq` alone."""
+        for _ in self._records:
+            pass
+
+
 class WriteAheadLog:
-    def __init__(self, path: Optional[str], fsync: bool = False):
-        """path=None -> disabled (in-memory deployments / tests)."""
+    def __init__(self, path: Optional[str], fsync: bool = False,
+                 sink: Optional[Callable[[Iterable[dict]], None]] = None):
+        """path=None -> disabled (in-memory deployments / tests).
+        `sink` is handed the recovery pass itself, an iterable of the
+        log's records in order, so that a boot reads and decodes its
+        log ONCE (DSSStore resolves the log's end state through it);
+        without it the pass finds the valid prefix and the sequence
+        and keeps nothing.  A sink that stops early is drained."""
         self.path = path
         self.fsync = fsync
         self._lock = threading.Lock()
@@ -88,7 +158,11 @@ class WriteAheadLog:
                 # after an undecodable line mean mid-log corruption,
                 # and deleting them would be silent loss of
                 # fsync-acked writes — refuse to start instead.
-                valid, self._seq = self._recover(path)
+                scan = LogScan(path)
+                if sink is not None:
+                    sink(scan)
+                scan.drain()
+                valid, self._seq = scan.valid, scan.seq
                 if valid < os.path.getsize(path):
                     if self._valid_records_after(path, valid):
                         raise LogCorruptError(
@@ -115,41 +189,6 @@ class WriteAheadLog:
                     + "\n"
                 )
                 self._fh.flush()
-
-    @staticmethod
-    def _recover(path: str) -> tuple:
-        """-> (valid prefix bytes, max seq) in ONE pass, mirroring
-        replay()'s tolerance exactly (blank lines pass; the first
-        undecodable or newline-less line ends the prefix).  Applies
-        the head format gate — an unsupported log version raises
-        LogFormatError here, refusing boot."""
-        valid = 0
-        seq = 0
-        first = True
-        with open(path, "rb") as fh:
-            while True:
-                line = fh.readline()
-                if not line:
-                    break
-                if not line.endswith(b"\n"):
-                    break  # torn tail (no newline): not complete
-                stripped = line.strip()
-                if stripped:
-                    try:
-                        rec = json.loads(stripped)
-                    except ValueError:
-                        # JSONDecodeError, or UnicodeDecodeError from
-                        # json's encoding sniff on rotted bytes (e.g.
-                        # NUL runs look like UTF-32) — both ValueError
-                        break
-                    if not isinstance(rec, dict):
-                        break  # rot that decodes as a JSON scalar
-                    if first:
-                        first = False
-                        check_format_record(rec, path)
-                    seq = max(seq, rec.get("seq", 0))
-                valid = fh.tell()
-        return valid, seq
 
     @staticmethod
     def _valid_records_after(path: str, offset: int) -> bool:
@@ -215,25 +254,7 @@ class WriteAheadLog:
         format (the boot gate)."""
         if self.path is None or not os.path.exists(self.path):
             return
-        first = True
-        with open(self.path, "r", encoding="utf-8") as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    # torn tail write (crash mid-append): stop replay here
-                    return
-                if not isinstance(rec, dict):
-                    return  # same: not a complete record
-                if first:
-                    first = False
-                    check_format_record(rec, self.path)
-                if rec.get("t") == FORMAT_RECORD_TYPE:
-                    continue  # gate metadata, not store state
-                yield rec
+        yield from LogScan(self.path)
 
     def adopt(self, tmp_path: str, seq: int) -> None:
         """Swap a fully-written, fsynced replacement log into place:

@@ -130,6 +130,30 @@ class _WalTail:
         except OSError:
             return not os.path.exists(self.path)
 
+    def read_ahead(self, sink) -> "tuple[int, int]":
+        """Everything appended since the last read, in one pass that
+        keeps nothing (wal.LogScan): `sink` is handed the records as an
+        iterable, in order.  For a replica's first catch-up over a log
+        of a million records.  -> (highest seq among them, byte offset
+        the pass ended at); the tail itself stays where it was until
+        advance() is told that the records were taken.  What poll()
+        would return, but for a line that is not a JSON object: it
+        ends the read here, as it ends the leader's own recovery."""
+        from dss_tpu.dar import wal as _walmod
+
+        if not os.path.exists(self.path):
+            return 0, self._offset
+        scan = _walmod.LogScan(self.path, self._offset)
+        sink(scan)
+        scan.drain()
+        return scan.seq, scan.valid
+
+    def advance(self, offset: int) -> None:
+        """Move the tail to where a read_ahead() ended."""
+        if offset > self._offset:
+            self._offset = offset
+            self._checked_head = True
+
     def poll(self, limit: Optional[int] = None) -> List[dict]:
         """`limit` stops consumption at that byte offset (a follower
         tailing to the leader's broadcast cut, never past it)."""
