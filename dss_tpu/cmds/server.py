@@ -485,12 +485,55 @@ def build_worker(args) -> web.Application:
 
     metrics.set_info("dss_build_info", {**build_info(), **backend})
 
+    leader_wait_s = 0.0
+
     def stats_fn():
         out = store.stats()
+        out["dss_boot_seconds"]["leader_wait"] = leader_wait_s
         out.update(follower.stats())
         if front is not None:
             out.update(front.stats())
         return out
+
+    def wait_for_leader() -> None:
+        """Block until the leader serves: its loopback answers /healthy
+        and the owner's heartbeat in the region is fresh (no region
+        with DSS_SHM_ENABLE=0: the loopback's answer alone).  main()
+        binds the public port only after this, so /healthy there means
+        the whole front is up however the processes' boots fall.  No
+        time-out of its own: the loopback has listened since before
+        this worker was born, so a probe sent early waits in its
+        backlog and is answered the moment the leader serves, and a
+        REFUSED one means the leader is gone (as _watch_parent, which
+        cannot see a leader that died before this process could look
+        for it)."""
+        nonlocal leader_wait_s
+        import http.client
+        from urllib.parse import urlsplit
+
+        t0 = time.perf_counter()
+        netloc = urlsplit(args.leader_url).netloc
+        while True:
+            conn = http.client.HTTPConnection(netloc, timeout=5.0)
+            try:
+                conn.request("GET", "/healthy")
+                up = conn.getresponse().status == 200
+            except ConnectionRefusedError:
+                raise SystemExit(
+                    f"leader {args.leader_url} is gone: worker exits"
+                ) from None
+            except (OSError, http.client.HTTPException):
+                up = False  # still booting: the probe timed out
+            finally:
+                conn.close()
+            if up and (
+                front is None
+                or front.region.owner_heartbeat_age_s() < front.owner_ttl_s
+            ):
+                break
+            time.sleep(0.05)
+        leader_wait_s = time.perf_counter() - t0
+        log.info("worker waited for the leader: %.2f s", leader_wait_s)
 
     app = build_app(
         rid,
@@ -525,6 +568,7 @@ def build_worker(args) -> web.Application:
     from dss_tpu.runtime import freeze_boot_heap
 
     freeze_boot_heap()
+    app["dss_wait_for_leader"] = wait_for_leader
     return app
 
 
@@ -931,6 +975,18 @@ def _public_socket(addr: str, reuse_port: bool):
     return s
 
 
+def _process_age_s() -> float:
+    """Seconds since the kernel started this process (interpreter
+    start-up and this module's imports, most of what precedes main(),
+    included)."""
+    with open("/proc/self/stat", "r", encoding="ascii") as fh:
+        start_ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+    return (
+        time.clock_gettime(time.CLOCK_BOOTTIME)
+        - start_ticks / os.sysconf("SC_CLK_TCK")
+    )
+
+
 def _watch_parent():
     """Read workers exit when the leader dies (no orphaned listeners
     competing on the port)."""
@@ -1071,6 +1127,9 @@ def main():
     if args.worker_reader:
         _watch_parent()
         app = build(args)
+        # replica caught up, ring opened: nothing listens on the public
+        # port until the leader serves too
+        app["dss_wait_for_leader"]()
         sock = _public_socket(args.addr, reuse_port=True)
         web.run_app(
             app,
@@ -1115,53 +1174,47 @@ def main():
                 shm_path, nworkers=args.workers, **shmring.env_knobs()
             )
         args._shm_path = shm_path
-        app = build(args)
-        owner = None
-        if region is not None:
-            owner = app["dss_store"].attach_shm_front(
-                region,
-                threads=int(
-                    os.environ.get("DSS_SHM_OWNER_THREADS", 0)
-                ) or None,
-                worker_ttl_s=float(
-                    os.environ.get("DSS_SHM_WORKER_TTL_S", 5.0)
-                ),
-            )
-            # the leader's stage observations (loopback-proxied
-            # writes) land in block N; its /metrics also renders the
-            # merged whole-front stage histograms
-            app["dss_metrics"].attach_stage_writer(
-                shmring.StageHistWriter(region, args.workers)
-            )
-            app["dss_metrics"].set_stage_agg(
-                lambda _r=region: shmring.shm_stage_hist(_r)
-            )
-        # With the shm front attached the leader is a PURE device
-        # owner: it serves the ring plus the loopback port the workers
-        # proxy writes to, and leaves the public port entirely to the
-        # workers.  A public connection landing on the leader would be
-        # served at single-process latency AND steal owner CPU from
-        # the ring drain — measured, that one topology leak capped the
-        # whole front near the r06 ceiling.  Plain SO_REUSEPORT mode
-        # (DSS_SHM_ENABLE=0) keeps the historical shared public bind.
+        # the loopback the workers proxy writes to: bound and listening
+        # before they are born, served once build() has an app
         internal = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         internal.bind(("127.0.0.1", 0))
         internal.listen(1024)
         leader_url = f"http://127.0.0.1:{internal.getsockname()[1]}"
-        if region is not None:
-            leader_socks = [internal]
-        else:
-            leader_socks = [
-                _public_socket(args.addr, reuse_port=True), internal,
-            ]
+
         def spawn_worker(i):
             return subprocess.Popen(
                 [sys.executable, "-m", "dss_tpu.cmds.server"]
                 + _forward_args(args, leader_url, worker_index=i)
             )
 
-        children = [spawn_worker(i) for i in range(args.workers)]
+        from dss_tpu.obs.logging import get_logger
+
+        children: list = []
         stopping = threading.Event()
+
+        def reap():
+            stopping.set()
+            for c in children:
+                if c.poll() is None:
+                    c.terminate()
+            for c in children:
+                try:
+                    c.wait(timeout=args.shutdown_grace + 5)
+                except subprocess.TimeoutExpired:
+                    c.kill()
+
+        # The front boots side by side: the workers are born BEFORE
+        # the leader's build(), so the three processes import, read
+        # the log and build their state at the same time.  A worker
+        # needs nothing the leader computes (the log's path, the
+        # region and the loopback URL all exist by now), and one that
+        # is ready first keeps the public port closed until the leader
+        # serves (build_worker's wait_for_leader), so /healthy still
+        # means the whole front is up.  reap is registered first: a
+        # leader that exits in build() takes its workers with it.
+        atexit.register(reap)
+        children.extend(spawn_worker(i) for i in range(args.workers))
+        owner = None  # the ring's drain, once build() has a store
 
         # a dead worker's in-flight ring slots are reclaimed the
         # moment the leader reaps it (the heartbeat TTL is the
@@ -1171,11 +1224,10 @@ def main():
         # would permanently shrink — and at zero workers eliminate —
         # the service's public listeners.  A crash-looping worker
         # (died within 10s of spawn) backs off exponentially to 30s;
-        # one that served a while restarts on the next tick.
+        # one that served a while restarts on the next tick.  The
+        # watch runs through the leader's own boot too.
         def watch_children():
             import time as _time
-
-            from dss_tpu.obs.logging import get_logger
 
             log = get_logger("dss.server")
             backoff = [0.5] * len(children)
@@ -1216,19 +1268,45 @@ def main():
         threading.Thread(
             target=watch_children, name="worker-watch", daemon=True
         ).start()
+        get_logger("dss.server").info(
+            "workers spawned: %d at %.2f s",
+            args.workers, _process_age_s(),
+        )
 
-        def reap():
-            stopping.set()
-            for c in children:
-                if c.poll() is None:
-                    c.terminate()
-            for c in children:
-                try:
-                    c.wait(timeout=args.shutdown_grace + 5)
-                except subprocess.TimeoutExpired:
-                    c.kill()
-
-        atexit.register(reap)
+        app = build(args)
+        if region is not None:
+            owner = app["dss_store"].attach_shm_front(
+                region,
+                threads=int(
+                    os.environ.get("DSS_SHM_OWNER_THREADS", 0)
+                ) or None,
+                worker_ttl_s=float(
+                    os.environ.get("DSS_SHM_WORKER_TTL_S", 5.0)
+                ),
+            )
+            # the leader's stage observations (loopback-proxied
+            # writes) land in block N; its /metrics also renders the
+            # merged whole-front stage histograms
+            app["dss_metrics"].attach_stage_writer(
+                shmring.StageHistWriter(region, args.workers)
+            )
+            app["dss_metrics"].set_stage_agg(
+                lambda _r=region: shmring.shm_stage_hist(_r)
+            )
+        # With the shm front attached the leader is a PURE device
+        # owner: it serves the ring plus the loopback port, and leaves
+        # the public port entirely to the workers.  A public
+        # connection landing on the leader would be served at
+        # single-process latency AND steal owner CPU from the ring
+        # drain — measured, that one topology leak capped the whole
+        # front near the r06 ceiling.  Plain SO_REUSEPORT mode
+        # (DSS_SHM_ENABLE=0) keeps the historical shared public bind.
+        if region is not None:
+            leader_socks = [internal]
+        else:
+            leader_socks = [
+                _public_socket(args.addr, reuse_port=True), internal,
+            ]
         web.run_app(
             app,
             sock=leader_socks,
