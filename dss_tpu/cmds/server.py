@@ -642,17 +642,22 @@ def build(args) -> web.Application:
     if not region_token and args.region_token_file:
         with open(args.region_token_file, "r", encoding="utf-8") as fh:
             region_token = fh.read().strip()
-    store = DSSStore(
-        storage=args.storage,
-        clock=clock,
-        wal_path=args.wal_path or None,
-        wal_fsync=args.wal_fsync,
-        region_url=args.region_url or None,
-        region_token=region_token or None,
-        region_poll_interval_s=args.region_poll_interval,
-        region_snapshot_every=args.region_snapshot_every,
-        instance_id=args.instance_id or None,
-    )
+    from dss_tpu.ops import compile_site as _compile_site
+
+    # the replay's build compiles on this thread before any request
+    # can wait for it: the boot's (dss_jax_compiles_boot_warm)
+    with _compile_site("boot_warm"):
+        store = DSSStore(
+            storage=args.storage,
+            clock=clock,
+            wal_path=args.wal_path or None,
+            wal_fsync=args.wal_fsync,
+            region_url=args.region_url or None,
+            region_token=region_token or None,
+            region_poll_interval_s=args.region_poll_interval,
+            region_snapshot_every=args.region_snapshot_every,
+            instance_id=args.instance_id or None,
+        )
     _log_boot(log, store.boot_stats)
     log.info(
         "store ready: storage=%s wal=%s scd=%s region=%s",
@@ -722,6 +727,9 @@ def build(args) -> web.Application:
         # waits on the same in-flight compile — never a double compile)
         from dss_tpu.ops.fastpath import warmup as _fastpath_warmup
 
+        # this thread's compiles are the boot's, no request's
+        # (dss_jax_compiles_boot_warm)
+        @_compile_site("boot_warm")
         def _warm():
             try:
                 t0 = time.perf_counter()
@@ -1292,6 +1300,12 @@ def main():
             )
             app["dss_metrics"].set_stage_agg(
                 lambda _r=region: shmring.shm_stage_hist(_r)
+            )
+            # a proxied write is observed twice, by the worker around
+            # its hop and here: the owner's own interval gets a name
+            # of its own beside the merged handler_ms
+            app["dss_metrics"].handler_stages = (
+                "handler_ms", "leader_handler_ms",
             )
         # With the shm front attached the leader is a PURE device
         # owner: it serves the ring plus the loopback port, and leaves

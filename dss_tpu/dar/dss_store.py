@@ -119,19 +119,6 @@ def _bump_sub(subs: Dict[str, object], sub_id: str):
     return bumped
 
 
-@contextlib.contextmanager
-def _write_leg(seam: str, stage: str):
-    """One leg of a notifying write: `dss.<seam>` on a running capture
-    and stage `<stage>` of the request that writes (as match.py marks
-    push_match_ms: a no-op without a sink, so replay pays nothing)."""
-    t0 = time.perf_counter()
-    try:
-        with trace.annotate(seam):
-            yield
-    finally:
-        stages.mark(stage, (time.perf_counter() - t0) * 1000.0)
-
-
 class _PushMixin:
     """Reverse-query push wiring shared by both sub-stores
     (dss_tpu/push/): DSSStore.attach_push hands the pipeline to the
@@ -161,10 +148,14 @@ class _PushMixin:
                 t_start_ns=t_start_ns, t_end_ns=t_end_ns,
                 now_ns=self._now_ns(),
             )
-        return self._sub_index.query_ids(
-            cells, alt_lo=alt_lo, alt_hi=alt_hi,
-            t_start=t_start_ns, t_end=t_end_ns, now=self._now_ns(),
-        )
+        # no pipeline: the match is a query of the subscription index
+        # (stage sub_match_ms; the pipeline's own match marks
+        # push_match_ms)
+        with stages.stage("sub_match_ms", "write.sub_match"):
+            return self._sub_index.query_ids(
+                cells, alt_lo=alt_lo, alt_hi=alt_hi,
+                t_start=t_start_ns, t_end=t_end_ns, now=self._now_ns(),
+            )
 
     def _offer_push(self, trigger, entity, subs, *, removed=False,
                     emergency=False, alt_lo=None, alt_hi=None,
@@ -175,7 +166,7 @@ class _PushMixin:
         push = self._push
         if push is None or not push.bound:
             return
-        with _write_leg("push.offer", "push_offer_ms"):
+        with stages.stage("push_offer_ms", "push.offer"):
             push.offer(
                 trigger, entity, subs, removed=removed,
                 emergency=emergency, alt_lo=alt_lo, alt_hi=alt_hi,
@@ -197,16 +188,25 @@ class _TxnTimeMixin:
 
     @contextlib.contextmanager
     def _txn_scope(self):
-        with self._txn():
-            tl = self._txn_time
-            outer = getattr(tl, "now", None) is None
-            if outer:
-                tl.now = to_nanos(self._clock.now())
+        tl = self._txn_time
+        if getattr(tl, "now", None) is not None:
+            # a re-entry (the thread holds the lock): nothing to pin,
+            # nothing waited for
+            with self._txn():
+                yield
+            return
+        with contextlib.ExitStack() as held:
+            # the outermost entry: from asking for the store's lock
+            # (region mode: whatever the region's txn waits for) to
+            # holding it is stage txn_wait_ms, so a queue behind the
+            # lock and a slow write read differently
+            with stages.stage("txn_wait_ms", "write.lock_wait"):
+                held.enter_context(self._txn())
+            tl.now = to_nanos(self._clock.now())
             try:
                 yield
             finally:
-                if outer:
-                    tl.now = None
+                tl.now = None
 
     def _now_ns(self) -> int:
         pinned = getattr(self._txn_time, "now", None)
@@ -1057,7 +1057,7 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         undo = []
         # the bump and its journal record: O(matched) under the write
         # lock (stage sub_bump_ms; dss.sub.bump on a capture)
-        with _write_leg("sub.bump", "sub_bump_ms"):
+        with stages.stage("sub_bump_ms", "sub.bump"):
             for i in sorted(ids):
                 prev = self._subs.get(i)
                 if prev is None:
@@ -1106,35 +1106,44 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         op.validate_time_range()
 
         if check_key and op.state in scdm.OperationState.REQUIRES_KEY:
-            conflicting = self._search_ops(
-                op.cells,
-                op.altitude_lower,
-                op.altitude_upper,
-                op.start_time,
-                op.end_time,
-            )
-            key_set = set(key)
-            missing = [c for c in conflicting if c.ovn not in key_set]
-            if op.constraint_aware:
-                # constraint-aware deconfliction: the op's USS consumes
-                # constraint updates, so its key must also cover every
-                # intersecting constraint's OVN — a stale view of an
-                # airspace closure is exactly the conflict the key
-                # check exists to catch
-                missing.extend(
-                    c
-                    for c in self._search_csts(
-                        op.cells,
-                        op.altitude_lower,
-                        op.altitude_upper,
-                        op.start_time,
-                        op.end_time,
-                    )
-                    if c.ovn not in key_set
-                )
-            if missing:
-                raise errors.missing_ovns(missing)
+            # the conflict search and the key comparison: stage
+            # precheck_ms, on both PUTs of a planned flight
+            with stages.stage("precheck_ms", "write.precheck"):
+                self._check_key(op, key)
         return old
+
+    def _check_key(self, op, key) -> None:
+        """The OVN key check: every operation (and, for a
+        constraint-aware op, every constraint) that intersects the
+        op's volume must have its OVN in `key`, else MISSING_OVNS."""
+        conflicting = self._search_ops(
+            op.cells,
+            op.altitude_lower,
+            op.altitude_upper,
+            op.start_time,
+            op.end_time,
+        )
+        key_set = set(key)
+        missing = [c for c in conflicting if c.ovn not in key_set]
+        if op.constraint_aware:
+            # constraint-aware deconfliction: the op's USS consumes
+            # constraint updates, so its key must also cover every
+            # intersecting constraint's OVN — a stale view of an
+            # airspace closure is exactly the conflict the key
+            # check exists to catch
+            missing.extend(
+                c
+                for c in self._search_csts(
+                    op.cells,
+                    op.altitude_lower,
+                    op.altitude_upper,
+                    op.start_time,
+                    op.end_time,
+                )
+                if c.ovn not in key_set
+            )
+        if missing:
+            raise errors.missing_ovns(missing)
 
     def validate_operation_upsert(self, op, key):
         """Read-only precheck, run by the service BEFORE any journaled
@@ -1168,7 +1177,10 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
                     else {"t": "scd_op_del", "id": stored.id}
                 ]
             self._ops[stored.id] = stored
-            self._index_op(stored)
+            # the scd_op table's overlay splice, its wait for the
+            # table's write lock (a fold's swap holds it) included
+            with stages.stage("op_index_ms", "write.op_index"):
+                self._index_op(stored)
             rec = {"t": "scd_op_put", "doc": codec.op_to_doc(stored)}
             if self._capture_undo:
                 rec["undo"] = undo
@@ -1350,43 +1362,57 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
                 raise errors.permission_denied(
                     f"Subscription is owned by {old.owner}"
                 )
-            count = self._sub_index.max_owner_count(
-                sub.cells, self._owners.intern(sub.owner), now=self._now_ns()
-            )
-            if count >= MAX_SCD_SUBSCRIPTIONS_PER_AREA:
-                msg = "too many existing subscriptions in this area already"
-                if old is not None:
-                    msg += ", rejecting update request"
-                raise errors.exhausted(msg)
-            stored = dataclasses.replace(
-                sub, version=(old.version if old else 0) + 1
-            )
-            if self._capture_undo:
-                # exact inverse: raw get includes an expired (invisible)
-                # record that `old` (visibility-filtered) misses
-                prev_raw = self._subs.get(sub.id)
-                undo = [
-                    {"t": "scd_sub_put", "doc": codec.scd_sub_to_doc(prev_raw)}
-                    if prev_raw is not None
-                    else {"t": "scd_sub_del", "id": stored.id}
-                ]
-            self._subs[stored.id] = stored
-            self._index_scd_sub(stored)
+            # the quota count, the new version's record and the scd_sub
+            # table's overlay splice: stage sub_index_ms
+            with stages.stage("sub_index_ms", "write.sub_index"):
+                count = self._sub_index.max_owner_count(
+                    sub.cells, self._owners.intern(sub.owner),
+                    now=self._now_ns(),
+                )
+                if count >= MAX_SCD_SUBSCRIPTIONS_PER_AREA:
+                    msg = (
+                        "too many existing subscriptions in this area "
+                        "already"
+                    )
+                    if old is not None:
+                        msg += ", rejecting update request"
+                    raise errors.exhausted(msg)
+                stored = dataclasses.replace(
+                    sub, version=(old.version if old else 0) + 1
+                )
+                if self._capture_undo:
+                    # exact inverse: raw get includes an expired
+                    # (invisible) record that `old` (visibility-
+                    # filtered) misses
+                    prev_raw = self._subs.get(sub.id)
+                    undo = [
+                        {
+                            "t": "scd_sub_put",
+                            "doc": codec.scd_sub_to_doc(prev_raw),
+                        }
+                        if prev_raw is not None
+                        else {"t": "scd_sub_del", "id": stored.id}
+                    ]
+                self._subs[stored.id] = stored
+                self._index_scd_sub(stored)
             rec = {"t": "scd_sub_put", "doc": codec.scd_sub_to_doc(stored)}
             if self._capture_undo:
                 rec["undo"] = undo
             self._journal(rec)
-            affected = (
-                self._search_ops(
-                    stored.cells,
-                    stored.altitude_lo,
-                    stored.altitude_hi,
-                    stored.start_time,
-                    stored.end_time,
+            # the operations the subscription's volume meets (a
+            # planned flight's put_operation discards them)
+            with stages.stage("sub_affected_ms", "write.sub_affected"):
+                affected = (
+                    self._search_ops(
+                        stored.cells,
+                        stored.altitude_lo,
+                        stored.altitude_hi,
+                        stored.start_time,
+                        stored.end_time,
+                    )
+                    if len(np.asarray(stored.cells).ravel())
+                    else []
                 )
-                if len(np.asarray(stored.cells).ravel())
-                else []
-            )
             return dataclasses.replace(stored), affected
 
     def delete_subscription(self, id, owner, version):
@@ -1668,10 +1694,13 @@ class DSSStore:
     def _journal(self, rec: dict):
         if self._replaying:
             return
-        if self.region is not None:
-            self.region.journal(rec)
-        else:
-            self.wal.append(rec)
+        # stage wal_commit_ms, accumulated over a request's records
+        # (inside sub.bump: the span alone, sub_bump_ms holds it)
+        with stages.stage("wal_commit_ms", "wal.commit"):
+            if self.region is not None:
+                self.region.journal(rec)
+            else:
+                self.wal.append(rec)
 
     def apply_log_record(self, rec: dict) -> None:
         """Apply one WAL/region-log record to the right sub-store
@@ -1752,7 +1781,10 @@ class DSSStore:
         class's current tiers (ops/resident.py).  Call AFTER
         configure_serving(resident=True) attached the loops; runs the
         multi-second XLA compiles off the serving path (the server's
-        boot warm thread).  Returns executables built."""
+        boot warm thread).  Returns executables built.  Its compiles
+        count as dss_jax_compiles_boot_warm."""
+        from dss_tpu.ops import compile_site
+
         n = 0
         for index in (
             self.rid._isa_index, self.rid._sub_index,
@@ -1768,7 +1800,8 @@ class DSSStore:
                 continue
             warm = getattr(table, "warm_resident", None)
             if warm is not None:
-                n += warm(loop.kernel)
+                with compile_site("boot_warm"):
+                    n += warm(loop.kernel)
         return n
 
     # -- shared-memory serving front (parallel/shmring.py) -------------------
@@ -2016,6 +2049,10 @@ class DSSStore:
         scd = getattr(self.scd, "_local", self.scd)
         out["dss_scd_notifying_writes_total"] = scd._notifying_writes
         out["dss_scd_subscribers_notified_total"] = scd._subs_notified
+        # the journal since the process started (dar/wal.py): records,
+        # bytes, fsyncs, seconds of the append and of the fsync alone;
+        # all zero in region mode, where the region server journals
+        out.update(self.wal.stats())
         # the boot: records of the log, and seconds by stage (a boot
         # that fell back to the loop reports the records alone)
         out["dss_boot_records"] = self.boot_stats.get("records", 0)

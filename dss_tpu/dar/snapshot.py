@@ -64,7 +64,7 @@ from dss_tpu.dar.pack import pow2_at_least
 from dss_tpu.dar.tiers import Tier, TierSnapshot
 from dss_tpu.obs import trace as _trace
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
-from dss_tpu.ops import fastpath
+from dss_tpu.ops import compile_site, fastpath
 
 
 class _Overlay(NamedTuple):
@@ -502,6 +502,7 @@ class DarTable:
         if th is not None and th is not threading.current_thread():
             th.join(timeout=5)
 
+    @compile_site("fold_warm")  # the fold thread's compiles: no request's
     def _fold_loop(self):
         while not self._closed:
             triggered = self._fold_event.wait(
@@ -597,7 +598,12 @@ class DarTable:
             self._fold_removed = []
             gen0 = self._gen
         try:
-            snap = self._build_snapshot(recs)  # pack + HBM upload, unlocked
+            # the fold thread's two host stretches have names on a
+            # capture: dss.fold.build (unlocked) and dss.fold.swap
+            # (under the write lock every upsert takes)
+            with _trace.annotate("fold.build"):
+                # pack + HBM upload, unlocked
+                snap = self._build_snapshot(recs)
             if self._resident_warm is not None and snap.fast is not None:
                 try:
                     # schedule the new snapshot's AOT shape buckets
@@ -606,8 +612,10 @@ class DarTable:
                     # fall back to the shared jit).  No-op when the
                     # block count is unchanged — the process cache
                     # already holds the grid, the minor-fold common
-                    # case.
-                    self._resident_warm(snap.fast)
+                    # case.  What it compiles, here or on the thread
+                    # it hands the buckets to, counts as fold_warm.
+                    with compile_site("fold_warm"):
+                        self._resident_warm(snap.fast)
                 except Exception:  # noqa: BLE001 — warm is best-effort
                     import logging
 
@@ -615,7 +623,7 @@ class DarTable:
                         "resident warm failed"
                     )
             t_swap = time.perf_counter()
-            with self._write_lock:
+            with _trace.annotate("fold.swap"), self._write_lock:
                 if self._gen != gen0:
                     return False  # a synchronous rebuild superseded us
                 built = snap.recs

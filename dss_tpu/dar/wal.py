@@ -13,6 +13,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 from typing import Callable, Iterable, Iterator, Optional
 
 from dss_tpu.chaos import fault_point
@@ -144,6 +145,15 @@ class WriteAheadLog:
         # epoch on this signal (and ONLY this signal or promotion) so
         # clean restarts no longer fence every writer.
         self.recovered_truncation = False
+        # what the journal cost since the process started, counted
+        # where no request owns the work (under _lock; stats()):
+        # records, their bytes, fsyncs, and the seconds of the whole
+        # append and of os.fsync alone.  Recovery and replay move none.
+        self.appends = 0
+        self.bytes = 0
+        self.fsyncs = 0
+        self.append_s = 0.0
+        self.fsync_s = 0.0
         if path is not None:
             os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
             if os.path.exists(path) and os.path.getsize(path) > 0:
@@ -224,6 +234,7 @@ class WriteAheadLog:
 
     def append(self, record: dict) -> int:
         with self._lock:
+            t0 = time.perf_counter()
             # chaos seam BEFORE the seq assignment/write: an injected
             # append error leaves no half-recorded state, and a delay
             # models a slow disk stalling the writer
@@ -231,12 +242,24 @@ class WriteAheadLog:
             self._seq += 1
             record = dict(record, seq=self._seq)
             if self._fh is not None:
-                self._fh.write(json.dumps(record, separators=(",", ":")) + "\n")
+                line = json.dumps(record, separators=(",", ":")) + "\n"
+                self._fh.write(line)
                 self._fh.flush()
+                self.bytes += len(line)  # ensure_ascii: chars are bytes
                 if self.fsync:
-                    fault_point("wal.fsync")
-                    os.fsync(self._fh.fileno())
+                    self._fsync_locked()
+            self.appends += 1
+            self.append_s += time.perf_counter() - t0
             return self._seq
+
+    def _fsync_locked(self) -> None:
+        # the chaos seam inside the timer, as append's is: an injected
+        # delay models a slow disk and reads as one
+        t0 = time.perf_counter()
+        fault_point("wal.fsync")
+        os.fsync(self._fh.fileno())
+        self.fsyncs += 1
+        self.fsync_s += time.perf_counter() - t0
 
     def sync(self) -> None:
         """fsync the log regardless of the per-append fsync setting —
@@ -245,8 +268,19 @@ class WriteAheadLog:
         with self._lock:
             if self._fh is not None:
                 self._fh.flush()
-                fault_point("wal.fsync")
-                os.fsync(self._fh.fileno())
+                self._fsync_locked()
+
+    def stats(self) -> dict:
+        """The dss_wal_* counters (DSSStore.stats, the leader's
+        /metrics): a rising fsync mean is the disk."""
+        with self._lock:
+            return {
+                "dss_wal_appends_total": self.appends,
+                "dss_wal_bytes_total": self.bytes,
+                "dss_wal_fsyncs_total": self.fsyncs,
+                "dss_wal_append_seconds_total": round(self.append_s, 6),
+                "dss_wal_fsync_seconds_total": round(self.fsync_s, 6),
+            }
 
     def replay(self) -> Iterator[dict]:
         """Yield records in order; tolerates a torn final line.  Raises

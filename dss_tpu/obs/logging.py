@@ -179,8 +179,10 @@ def make_access_log_middleware(metrics=None, dump_requests: bool = False,
                 # the same interval as a stage too: the per-process
                 # request histogram cannot be read across a front whose
                 # scrapes land on whichever worker the kernel picks
-                metrics.observe_stage(route, "handler_ms", dur)
-                for st, ms in stages.items():
-                    metrics.observe_stage(route, st, ms / 1000.0)
+                observed = [(st, dur) for st in metrics.handler_stages]
+                observed.extend(
+                    (st, ms / 1000.0) for st, ms in stages.items()
+                )
+                metrics.observe_stages(route, observed)
 
     return access_log

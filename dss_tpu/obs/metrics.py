@@ -72,6 +72,23 @@ STAGE_NAMES = (
     # dss_request_duration_seconds times per process, here merged
     # across the front (obs/logging.py access_log)
     "handler_ms",
+    # a write on the store's owner, as disjoint stages (obs/stages.py
+    # stage; dar/dss_store.py, services/scd.py): the extents' decoding
+    # and union, the wait for the store's lock, the conflict search
+    # with its key comparison, the 409's listing, the implicit
+    # subscription's quota count and index splice, its closing search,
+    # the operation's index splice, the journal's appends, and the
+    # subscribers' match where no push pipeline runs it.  With
+    # covering_ms, serialize_ms, push_match_ms, sub_bump_ms,
+    # push_offer_ms and exec_wait_ms they sum to no more than
+    # service_ms
+    "parse_ms", "txn_wait_ms", "precheck_ms", "conflict_list_ms",
+    "sub_index_ms", "sub_affected_ms", "op_index_ms", "wal_commit_ms",
+    "sub_match_ms",
+    # handler_ms as the store's owner behind a --workers front saw it:
+    # a name the worker's own observation (which holds its proxy hop)
+    # cannot merge into
+    "leader_handler_ms",
     "other",
 )
 _STAGE_SET = frozenset(STAGE_NAMES)
@@ -163,6 +180,12 @@ class MetricsRegistry:
         # then exports the SAME coherent family, the dss_shm_worker_*
         # pattern) instead of the local-only histograms
         self._stage_agg = None
+        # the stage names access_log observes a request's whole
+        # handler under: the store's owner behind a --workers front
+        # adds leader_handler_ms (cmds/server.py), since the merged
+        # handler_ms there is the mean of two different intervals, the
+        # worker's around its proxy hop and the leader's own
+        self.handler_stages: Tuple[str, ...] = ("handler_ms",)
 
     def observe_request(
         self, method: str, path: str, status: int, duration_s: float
@@ -187,23 +210,32 @@ class MetricsRegistry:
         store/serialize) so the p50 breakdown is measured, not guessed:
         the dss_stage_duration_seconds{stage,route} histogram — tail
         percentiles per stage, and _sum / _count for the mean."""
+        self.observe_stages(route, ((stage, duration_s),))
+
+    def observe_stages(self, route: str, observed) -> None:
+        """observe_stage for every (stage, seconds) of one request:
+        the route is templatized and the lock taken once (a write's
+        sink holds a dozen stages, and this runs before its answer
+        leaves)."""
         rt = route_template(route)
         with self._lock:
-            hk = (rt, stage_name(stage))
-            row = self._shist.get(hk)
-            if row is None:
-                row = self._shist[hk] = [0] * (len(STAGE_BUCKETS) + 2)
-            # cumulative buckets: every edge at or past the duration
-            for i in range(
-                bisect_left(STAGE_BUCKETS, duration_s), len(STAGE_BUCKETS)
-            ):
-                row[i] += 1
-            row[-2] += duration_s
-            row[-1] += 1
+            for stage, duration_s in observed:
+                hk = (rt, stage_name(stage))
+                row = self._shist.get(hk)
+                if row is None:
+                    row = self._shist[hk] = [0] * (len(STAGE_BUCKETS) + 2)
+                # cumulative buckets: every edge at or past the duration
+                for i in range(
+                    bisect_left(STAGE_BUCKETS, duration_s),
+                    len(STAGE_BUCKETS),
+                ):
+                    row[i] += 1
+                row[-2] += duration_s
+                row[-1] += 1
         if self._stage_writer is not None:
             # outside the lock: the shm block is single-writer per
             # process and numpy increments are cheap
-            self._stage_writer.observe(rt, stage, duration_s)
+            self._stage_writer.observe_many(rt, observed)
 
     def attach_stage_writer(self, writer) -> None:
         """Mirror every stage observation into this process's shared

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import threading
 import time
-from contextlib import contextmanager
+from typing import Optional
 
 from dss_tpu.obs import trace
 
@@ -35,6 +35,10 @@ def mark(name: str, duration_ms: float, span: bool = True) -> None:
     (no-op without one).  For callers that cannot bracket the timed
     region with `stage` — e.g. the coalescer recording how long an
     item waited for its micro-batch.  Repeated marks accumulate.
+    A mark is outside `stage`'s depth rule: what it measured lies
+    inside whatever stage is open (coalesce_wait_ms inside store_ms or
+    precheck_ms), so a marked name is a detail of that stage and no
+    term of a sum of stages.
     When a trace is recording on this thread the mark also lands as a
     span (start back-dated by the duration); span=False skips that for
     callers that record a richer span of their own for the same
@@ -53,25 +57,50 @@ def mark(name: str, duration_ms: float, span: bool = True) -> None:
         )
 
 
-@contextmanager
-def stage(name: str):
-    """Time a block into the current sink (without a sink: only an
-    annotation of a running capture).  Repeated stages accumulate.
-    When a trace is recording on this thread the block is also a span
-    — service phases (covering/store/serialize) become tree nodes for
-    free, with real nesting (spans opened inside the block parent
-    under it)."""
-    sink = getattr(_tls, "sink", None)
-    if sink is None:
-        with trace.annotate(name):
-            yield
-        return
-    sp = trace.span(name)
-    t0 = time.perf_counter()
-    try:
-        with sp:
-            yield
-    finally:
-        sink[name] = round(
-            sink.get(name, 0.0) + (time.perf_counter() - t0) * 1000, 3
-        )
+class stage:
+    """Time a block: stage `<name>` of the request on this thread,
+    `dss.<seam>` (the name itself where no seam is given) on a running
+    capture, and a span `<name>` of a sampled request, with real
+    nesting (spans opened inside the block parent under it).  Repeated
+    stages accumulate.
+
+    THE way a block gets into the sink, and stages are disjoint by
+    construction: a stage opened inside another stage is the span
+    alone (on the capture, nested on the timeline, and in the sampled
+    request's tree) and marks NO stage, so the stages of one request
+    never overlap and their sum can be held against service_ms.
+    Without a sink (replay, boot, a follower's catch-up) a stage is
+    annotate's one global read and marks nothing."""
+
+    __slots__ = ("_name", "_seam", "_sink", "_depth", "_span", "_t0")
+
+    def __init__(self, name: str, seam: Optional[str] = None):
+        self._name = name
+        self._seam = seam
+
+    def __enter__(self) -> None:
+        self._sink = sink = getattr(_tls, "sink", None)
+        if sink is None:
+            span = trace.annotate(self._seam or self._name)
+        else:
+            self._depth = depth = getattr(_tls, "depth", 0)
+            _tls.depth = depth + 1
+            span = trace.span(self._name, seam=self._seam)
+        if span is trace.NOOP:  # no capture, no sampled request
+            span = None
+        else:
+            span.__enter__()
+        self._span = span
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dur_ms = (time.perf_counter() - self._t0) * 1000.0
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        sink = self._sink
+        if sink is not None:
+            _tls.depth = self._depth
+            if self._depth == 0:
+                name = self._name
+                sink[name] = round(sink.get(name, 0.0) + dur_ms, 3)
+        return False

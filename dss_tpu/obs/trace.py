@@ -564,6 +564,7 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+NOOP = _NOOP  # for a caller that skips entering it (obs/stages.py)
 
 
 # -- the third sink: the profiler's own timeline -----------------------------
@@ -609,17 +610,18 @@ class _Span:
     parenting children opened on the same thread while it is open."""
 
     __slots__ = ("name", "span_id", "_parent", "_ctx", "_attrs",
-                 "_t0", "_start_ns", "_prev_parent", "_ann")
+                 "_t0", "_start_ns", "_prev_parent", "_ann", "_seam")
 
-    def __init__(self, ctx, parent, name, attrs):
+    def __init__(self, ctx, parent, name, attrs, seam=None):
         self._ctx = ctx
         self._parent = parent
         self.name = name
         self._attrs = attrs
+        self._seam = seam or name
         self.span_id = _next_span_id()
 
     def __enter__(self):
-        self._ann = annotate(self.name)
+        self._ann = annotate(self._seam)
         self._ann.__enter__()
         self._start_ns = time.time_ns()
         self._t0 = time.perf_counter()
@@ -644,18 +646,20 @@ class _Span:
         return False
 
 
-def span(name: str, **attrs):
+def span(name: str, seam: Optional[str] = None, **attrs):
     """Open a child span of this thread's current span.  When tracing
     is inactive here the seam is only an annotation of a running
-    capture, else the reusable no-op (two branches, no allocation)."""
+    capture, else the reusable no-op (two branches, no allocation).
+    `seam` names the annotation where it is not the span's own name
+    (a write's stage `precheck_ms` is `dss.write.precheck` there)."""
     if not _ENABLED:
-        return annotate(name)
+        return annotate(seam or name)
     ctx = getattr(_tls, "ctx", None)
     if ctx is None or not ctx.recording:
-        return annotate(name)
+        return annotate(seam or name)
     return _Span(
         ctx, getattr(_tls, "parent", None) or ctx.root_span_id,
-        name, attrs or None,
+        name, attrs or None, seam,
     )
 
 

@@ -9,6 +9,7 @@ Importing this package also places JAX's persistent compilation cache
 it first, so the placement lands before the first compile.
 """
 
+import contextlib
 import os
 import threading
 from typing import Optional
@@ -40,24 +41,68 @@ def place_compile_cache(environ=os.environ) -> Optional[str]:
 place_compile_cache()
 
 
+# where a compile happens decides what it costs: a warm on a thread of
+# its own costs no request, a jit miss under a request stalls it for
+# seconds.  The site is the compiling thread's (compile_site).  The
+# threads that compile for no request are few and marked (the boot's
+# build and warm, the fold thread, the AOT compiler on their behalf);
+# the threads a request waits on are many (the executor pool, the
+# coalescer's and the resident pipeline's, the ring's servers), so
+# `request` is every thread nobody marked: an unmarked thread can read
+# as a stall that was none, and never hide one.  The warm-up's own
+# first requests compile there too, so it is the count's rise after
+# the warm-up that says a request waited.
+COMPILE_SITES = ("request", "fold_warm", "boot_warm")
+_site = threading.local()
+
+
+def current_compile_site() -> str:
+    return getattr(_site, "name", "request")
+
+
+@contextlib.contextmanager
+def compile_site(name: str):
+    """Count this thread's compiles under `name` while the block runs:
+    `boot_warm` around the boot's build and warm (cmds/server.py,
+    DSSStore.warm_resident), `fold_warm` on the fold thread
+    (dar/snapshot.py), around a fold's `_resident_warm` hook wherever
+    it runs and, handed on with the bucket, on the compiler thread
+    that builds it (ops/resident.py AotCache)."""
+    if name not in COMPILE_SITES:
+        raise ValueError(f"unknown compile site {name!r}")
+    prev = current_compile_site()
+    _site.name = name
+    try:
+        yield
+    finally:
+        _site.name = prev
+
+
 class _CompileCounter:
     """Process-wide count of XLA backend compiles, fed by JAX's own
     monitoring events: every executable build (jit first call or AOT
-    .compile()) and every persistent-cache hit among them.  A compile
-    after boot warm is a compile on somebody's request path — the
-    dss_jax_* gauges (DSSStore.stats) make that countable."""
+    .compile()) and every persistent-cache hit among them, in total
+    and by the site of the thread that compiled.  A compile after boot
+    warm under site `request` is a compile on somebody's request path
+    — the dss_jax_* gauges (DSSStore.stats) make that countable."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.compiles = 0
         self.compile_s = 0.0
         self.cache_hits = 0
+        self.by_site = {site: 0 for site in COMPILE_SITES}
+        self.request_s = 0.0
 
     def on_duration(self, event: str, duration_secs: float, **_kw) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
+            site = current_compile_site()
             with self._lock:
                 self.compiles += 1
                 self.compile_s += duration_secs
+                self.by_site[site] += 1
+                if site == "request":
+                    self.request_s += duration_secs
 
     def on_event(self, event: str, **_kw) -> None:
         if event == "/jax/compilation_cache/cache_hits":
@@ -70,6 +115,11 @@ class _CompileCounter:
                 "dss_jax_compiles": self.compiles,
                 "dss_jax_compile_seconds": round(self.compile_s, 3),
                 "dss_jax_compile_cache_hits": self.cache_hits,
+                **{f"dss_jax_compiles_{site}": n
+                   for site, n in self.by_site.items()},
+                "dss_jax_compile_seconds_request": round(
+                    self.request_s, 3
+                ),
             }
 
 

@@ -74,7 +74,7 @@ from dss_tpu.chaos import fault_point
 from dss_tpu.obs import trace as _trace
 from dss_tpu.ops import conflict as _conflict  # noqa: F401 — enables
 #   x64 before the first jax array touch (the kernel's i64 columns)
-from dss_tpu.ops import fastpath
+from dss_tpu.ops import compile_site, current_compile_site, fastpath
 
 # donation is advisory: backends that cannot re-use a buffer (CPU for
 # some shapes) warn and fall back to a copy — correctness never depends
@@ -255,7 +255,10 @@ class AotCache:
             if key in self._exe or key in self._pending_keys:
                 return
             self._pending_keys.add(key)
-            self._pending.append((key, nb))
+            # the compile is counted where it was asked for: a fold's
+            # warm hands its site on, a serving thread's miss is a
+            # request's (dss_jax_compiles_<site>)
+            self._pending.append((key, nb, current_compile_site()))
             if self._compiler is None or not self._compiler.is_alive():
                 self._compiler = threading.Thread(
                     target=self._compile_loop,
@@ -269,9 +272,10 @@ class AotCache:
             with self._lock:
                 if not self._pending:
                     return
-                key, nb = self._pending.popleft()
+                key, nb, site = self._pending.popleft()
             try:
-                self._compile_key(key, nb)
+                with compile_site(site):
+                    self._compile_key(key, nb)
             except Exception:  # noqa: BLE001 — a bad bucket must not
                 import logging  # kill the compiler
 

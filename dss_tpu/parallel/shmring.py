@@ -118,13 +118,14 @@ __all__ = [
 SHM_CLASSES = ("isa", "rid_sub", "op", "scd_sub", "constraint")
 
 MAGIC = 0x4453_5353_484D_5231  # "DSSSHMR1"
-VERSION = 4  # v2: trace words in the slot header + the per-process
+VERSION = 5  # v2: trace words in the slot header + the per-process
 #              stage-histogram segment (distributed tracing PR)
 #              v3: three clock stamps in every response + the stage
 #              blocks' new names (the ring split at its seams)
 #              v4: the owner's doorbell word in the header (a worker
 #              that never rings it would leave every request to the
 #              scanner's backstop)
+#              v5: the stage blocks grow by the write path's legs
 
 HEADER_BYTES = 4096
 WSTAT_BYTES = 256  # 32 i64 counters per worker
@@ -1090,18 +1091,21 @@ class StageHistWriter:
         self._row = region._shist[proc_index]
 
     def observe(self, route: str, stage: str, duration_s: float) -> None:
-        base = (
-            _ROUTE_IDX[route_class(route)] * len(STAGE_NAMES)
-            + _STAGE_IDX[stage_name(stage)]
-        ) * _SHIST_ROW
+        self.observe_many(route, ((stage, duration_s),))
+
+    def observe_many(self, route: str, observed) -> None:
+        """Every (stage, seconds) of one request, under one route."""
+        route_base = _ROUTE_IDX[route_class(route)] * len(STAGE_NAMES)
         row = self._row
-        # cumulative buckets: every edge at or past the duration, in
-        # one slice increment (this runs for every stage of every
-        # request, on the event loop for an inline read)
-        first = bisect_left(STAGE_BUCKETS, duration_s)
-        row[base + first:base + len(STAGE_BUCKETS)] += 1
-        row[base + _SHIST_ROW - 2] += int(duration_s * 1e9)
-        row[base + _SHIST_ROW - 1] += 1
+        for stage, duration_s in observed:
+            base = (route_base + _STAGE_IDX[stage_name(stage)]) * _SHIST_ROW
+            # cumulative buckets: every edge at or past the duration,
+            # in one slice increment (this runs for every stage of
+            # every request, on the event loop for an inline read)
+            first = bisect_left(STAGE_BUCKETS, duration_s)
+            row[base + first:base + len(STAGE_BUCKETS)] += 1
+            row[base + _SHIST_ROW - 2] += int(duration_s * 1e9)
+            row[base + _SHIST_ROW - 1] += 1
 
 
 def shm_stage_hist(region: ShmRegion) -> dict:

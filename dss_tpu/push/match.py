@@ -27,7 +27,7 @@ import numpy as np
 
 from dss_tpu import chaos
 from dss_tpu.geo import s2cell
-from dss_tpu.obs import stages, trace
+from dss_tpu.obs import stages
 from dss_tpu.ops.conflict import NO_TIME_HI, NO_TIME_LO
 from dss_tpu.plan.planner import BatchShape, Planner
 
@@ -146,7 +146,7 @@ class MatchStage:
         b = len(queries)
         if b == 0:
             return []
-        with trace.annotate("push.match"):
+        with stages.stage("push_match_ms", "push.match"):
             return self._match_many(queries, now_ns)
 
     def _match_many(self, queries, now_ns: int) -> List[List[str]]:
@@ -180,7 +180,6 @@ class MatchStage:
         dur_ms = (time.perf_counter() - t0) * 1000.0
         if plan.route == "rqmatch":
             self._planner.observe_rqmatch(b, dur_ms)
-        stages.mark("push_match_ms", dur_ms)
         if self._metrics is not None:
             self._metrics.observe_stage(
                 "push", "push_match_ms", dur_ms / 1000.0

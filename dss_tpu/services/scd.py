@@ -70,13 +70,15 @@ def _extents_to_covering(params: dict):
     Returns (union Volume4D, cells); raises the same wire errors for
     both entity classes so a fix to one cannot miss the other."""
     extents_json = params.get("extents") or []
-    extents = [ser.volume4d_from_scd_json(e) for e in extents_json]
-    try:
-        u_extent = union_volumes_4d(extents)
-    except geo_covering.AreaTooLargeError as e:
-        raise errors.area_too_large(str(e))
-    except (geo_covering.BadAreaError, ValueError) as e:
-        raise errors.bad_request(f"failed to union extents: {e}")
+    # the volumes' decoding and their union, before the covering
+    with stages.stage("parse_ms", "write.parse"):
+        extents = [ser.volume4d_from_scd_json(e) for e in extents_json]
+        try:
+            u_extent = union_volumes_4d(extents)
+        except geo_covering.AreaTooLargeError as e:
+            raise errors.area_too_large(str(e))
+        except (geo_covering.BadAreaError, ValueError) as e:
+            raise errors.bad_request(f"failed to union extents: {e}")
     if u_extent.start_time is None:
         raise errors.bad_request("missing time_start from extents")
     if u_extent.end_time is None:
@@ -167,25 +169,29 @@ class SCDService:
                 yield
             except errors.StatusError as e:
                 if e.code == errors.Code.MISSING_OVNS:
-                    ops = self.store.search_operations(
-                        cells,
-                        u_extent.spatial_volume.altitude_lo,
-                        u_extent.spatial_volume.altitude_hi,
-                        u_extent.start_time,
-                        u_extent.end_time,
-                    )
-                    csts = (
-                        self.store.search_constraints(
+                    # the 409's listing: the conflict search a second
+                    # time and the body's making (stage
+                    # conflict_list_ms)
+                    with stages.stage("conflict_list_ms", "write.conflicts"):
+                        ops = self.store.search_operations(
                             cells,
                             u_extent.spatial_volume.altitude_lo,
                             u_extent.spatial_volume.altitude_hi,
                             u_extent.start_time,
                             u_extent.end_time,
                         )
-                        if op.constraint_aware
-                        else []
-                    )
-                    e.details = _missing_ovns_response(ops, csts)
+                        csts = (
+                            self.store.search_constraints(
+                                cells,
+                                u_extent.spatial_volume.altitude_lo,
+                                u_extent.spatial_volume.altitude_hi,
+                                u_extent.start_time,
+                                u_extent.end_time,
+                            )
+                            if op.constraint_aware
+                            else []
+                        )
+                        e.details = _missing_ovns_response(ops, csts)
                 raise
 
         with self.store.transaction():
@@ -232,10 +238,11 @@ class SCDService:
                 stored, subs = self.store.upsert_operation(
                     op, key, key_checked=True
                 )
-        return {
-            "operation_reference": ser.op_to_json(stored),
-            "subscribers": ser.scd_subscribers_to_notify_json(subs),
-        }
+        with stages.stage("serialize_ms", "write.body"):
+            return {
+                "operation_reference": ser.op_to_json(stored),
+                "subscribers": ser.scd_subscribers_to_notify_json(subs),
+            }
 
     def get_operation(self, entity_uuid: str, owner: str) -> dict:
         if not entity_uuid:

@@ -3,9 +3,10 @@
 `dssbench/metrics/*.json` name counter and gauge families on `/metrics`
 and lines of the leader's boot log; a PR that deletes one of them used
 to find out from a `null` in the ledger a day later.  One case per
-metric file whose reader is `scrape_ratio`, `scrape_rate`, `stage_mean`
-or `bootlog`, against one boot of the served topology on the CPU backend
-(with `--push` and tokens, as the write cells' deployments), started
+metric file whose reader is `scrape_ratio`, `scrape_rate`, `stage_mean`,
+`stage_cover` or `bootlog`, against one boot of the served topology on
+the CPU backend (with `--push` and tokens, as the write cells'
+deployments), started
 and awaited by the harness's own code (`dssbench.run.start_server`,
 `dssbench.deploy.wait_ready`: the leader's "resident AOT warm:" line is
 part of the contract, the harness sends nothing before it) and scraped
@@ -34,7 +35,8 @@ import pytest
 from dssbench import deploy, run, traffic as tr
 from dssbench.readers import bootlog
 
-READERS = ("scrape_ratio", "scrape_rate", "stage_mean", "bootlog")
+READERS = ("scrape_ratio", "scrape_rate", "stage_mean", "stage_cover",
+           "bootlog")
 
 
 def _metric_files() -> list:
@@ -165,6 +167,32 @@ def _allocator_peak(monkeypatch) -> bool:
 ELSEWHERE = {"dss_device_peak_bytes_in_use": _allocator_peak}
 
 
+def _match_without_a_pipeline(monkeypatch) -> bool:
+    """Stage `sub_match_ms`: a notifying write's match through the
+    subscription index, which only a store WITHOUT `--push` runs
+    (`write-mixed`'s deployment; this boot has the pipeline, whose
+    match marks `push_match_ms`).  Checked where it is marked."""
+    import numpy as np
+
+    from dss_tpu.dar.dss_store import DSSStore
+    from dss_tpu.obs import stages
+    from dss_tpu.obs.metrics import stage_name
+
+    store = DSSStore(storage="memory")
+    sink = {}
+    stages.set_sink(sink)
+    try:
+        store.scd._notify_subs_locked(np.asarray([1], np.uint64))
+    finally:
+        stages.set_sink(None)
+        store.close()
+    return "sub_match_ms" in sink and stage_name("sub_match_ms") != "other"
+
+
+# stages this boot's deployment never runs, and where each is checked
+STAGES_ELSEWHERE = {"sub_match_ms": _match_without_a_pipeline}
+
+
 @pytest.mark.parametrize("metric", _metric_files())
 def test_the_program_exports_what_the_metric_reads(metric, served,
                                                    monkeypatch):
@@ -176,13 +204,21 @@ def test_the_program_exports_what_the_metric_reads(metric, served,
         )
         return
     keys = served[args.get("proc", "front")]
-    if metric["reader"] == "stage_mean":
-        for fam in ("sum", "count"):
-            row = (f'dss_stage_duration_seconds_{fam}{{route="'
-                   f'{args["route"]}",stage="{args["stage"]}"}}')
-            assert keys.get(row, 0) > 0, (
-                f"dssbench/metrics/{name}.json reads {row} from the "
-                "front's /metrics: no such row was observed")
+    if metric["reader"] in ("stage_mean", "stage_cover"):
+        # a stage_cover file reads the sums of its whole and of every
+        # leaf: a leaf that is gone would read as time gone dark
+        stages = ([args["stage"]] if metric["reader"] == "stage_mean"
+                  else [args["whole"]] + args["leaves"])
+        for stage in stages:
+            if stage in STAGES_ELSEWHERE:
+                assert STAGES_ELSEWHERE[stage](monkeypatch), stage
+                continue
+            for fam in ("sum", "count"):
+                row = (f'dss_stage_duration_seconds_{fam}{{route="'
+                       f'{args["route"]}",stage="{stage}"}}')
+                assert keys.get(row, 0) > 0, (
+                    f"dssbench/metrics/{name}.json reads {row} from the "
+                    "front's /metrics: no such row was observed")
         return
     for pat in args.get("num", []) + args.get("den", []) + args.get(
             "names", []):
