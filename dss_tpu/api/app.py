@@ -103,23 +103,28 @@ SCD_SCOPES = {
 }
 
 
-def _error_response(e: errors.StatusError) -> web.Response:
+def _error_payload(e: errors.StatusError):
+    """-> (status, body object, headers) of the error's response."""
     if e.code == errors.Code.MISSING_OVNS:
         # special 409 schema: the body IS the AirspaceConflictResponse
         # (cmds/http-gateway/main.go:187-200)
-        body = e.details or {"message": e.message}
-        return web.json_response(body, status=e.http_status)
+        return e.http_status, e.details or {"message": e.message}, None
     headers = None
     retry_after = getattr(e, "retry_after_s", None)
     if retry_after is not None:
         # overload shed (429): tell the client when the queue should
         # have drained; well-behaved USS clients back off accordingly
         headers = {"Retry-After": str(max(1, math.ceil(retry_after)))}
-    return web.json_response(
+    return (
+        e.http_status,
         {"error": e.message, "message": e.message, "code": int(e.code)},
-        status=e.http_status,
-        headers=headers,
+        headers,
     )
+
+
+def _error_response(e: errors.StatusError) -> web.Response:
+    status, body, headers = _error_payload(e)
+    return web.json_response(body, status=status, headers=headers)
 
 
 @web.middleware
@@ -363,6 +368,32 @@ async def _call_r(request, fn, *args):
     return await _call(fn, *args, request=request)
 
 
+def _authorize(request, authorizer: Optional[Authorizer],
+               operation: str) -> str:
+    """-> the request's owner under `operation`'s scopes (auth_ms
+    timed); no authorizer configured (unit harness) -> anonymous."""
+    if authorizer is None:
+        return "anonymous"
+    t0 = time.perf_counter()
+    t0_w = time.time_ns()
+    try:
+        owner = authorizer.authorize(
+            request.headers.get("Authorization"), operation
+        )
+    finally:
+        auth_ms = (time.perf_counter() - t0) * 1000
+        sink = request.get("dss_stages")
+        if sink is not None:
+            sink["auth_ms"] = round(auth_ms, 3)
+        th = _trace_handle(request)
+        if th is not None:
+            from dss_tpu.obs import trace as _trace
+
+            _trace.add_span(th, "auth_ms", t0_w, auth_ms)
+    request["dss_owner"] = owner
+    return owner
+
+
 def _freshness_json_response(request, data) -> web.Response:
     """json_response carrying the X-DSS-Freshness header when the
     service call left a note: region epoch + DAR write generation +
@@ -468,9 +499,74 @@ _PROXY_SKIP_HEADERS = {
     "host", "content-length", "transfer-encoding", "connection",
 }
 
+# Mutations a worker with the shared-memory front hands to the store's
+# owner in a ring slot (parallel/shmring.py's write kind), not over the
+# loopback proxy: (method, route, auth operation, the route's id
+# parameter, service, the service's method).  An entry's place in the
+# tuple is its number in the slot; every other mutation is proxied.
+RING_WRITES = (
+    ("PUT", "/dss/v1/operation_references/{entityuuid}",
+     _SCD + "PutOperationReference", "entityuuid", "scd", "put_operation"),
+)
+_RING_WRITE_ROUTES = {
+    (method, route): i for i, (method, route, *_) in enumerate(RING_WRITES)
+}
+
+
+def make_ring_write_fn(services: dict, metrics=None):
+    """The store owner's side of RING_WRITES (the ShmOwner's write_fn):
+    a mutation from a ring slot, run as the leader's own handler runs
+    it, on the write lane's thread and with no event loop or executor
+    between.  The body is decoded as `_params` decodes it, the service
+    call is stage `service_ms` with the legs inside it in the same
+    sink, every error is rendered as `error_middleware` renders it, and
+    once the answer is out the stages are observed under the route,
+    with the handler's interval (pickup to answer published) under the
+    registry's `handler_stages`.  `services`: {"scd": SCDService, ...}."""
+    from dss_tpu.dar import deadline as _deadline
+    from dss_tpu.obs import stages as _stages
+
+    def serve(req):
+        _m, route, _op, _key, service, method = RING_WRITES[req.route]
+        sink = {}
+        _stages.set_sink(sink)
+        if req.deadline_ns:
+            _deadline.set_route_deadline(req.deadline_ns / 1e9)
+        try:
+            params = _decode_params(req.body)
+            fn = getattr(services[service], method)
+            t0 = time.perf_counter()
+            try:
+                data = fn(req.entity, params, req.owner)
+            finally:
+                sink["service_ms"] = round(
+                    (time.perf_counter() - t0) * 1000, 3
+                )
+            status = 200
+        except errors.StatusError as e:
+            status, data, _headers = _error_payload(e)
+        except Exception as e:  # noqa: BLE001 — as error_middleware
+            status, data, _headers = _error_payload(errors.internal(str(e)))
+        finally:
+            _stages.set_sink(None)
+            if req.deadline_ns:
+                _deadline.set_route_deadline(None)
+        body = json.dumps(data).encode("utf-8")
+
+        def after(handler_s: float) -> None:
+            if metrics is not None:
+                observed = [(st, handler_s) for st in metrics.handler_stages]
+                observed.extend((st, ms / 1000.0) for st, ms in sink.items())
+                metrics.observe_stages(route, observed)
+
+        return status, body, after
+
+    return serve
+
 
 def make_worker_proxy_middleware(leader_url: str, follower=None,
-                                 costs=None):
+                                 costs=None, *, ring=None,
+                                 authorizer: Optional[Authorizer] = None):
     """Read-worker request routing: local serving for searches, proxy
     to the leader for everything else.  After a successful proxied
     mutation the worker waits (bounded) for its replica to reach the
@@ -485,12 +581,47 @@ def make_worker_proxy_middleware(leader_url: str, follower=None,
     erroring.  `costs` (the front's WorkerCostModel) observes the
     measured proxy round trip of each such fallback search, so the
     shm-vs-proxy price comparison learns the REAL loopback cost
-    instead of trusting the DSS_SHM_PROXY_MS seed forever."""
+    instead of trusting the DSS_SHM_PROXY_MS seed forever.
+
+    With `ring` (the worker's ShmSearchFront) a mutation of RING_WRITES
+    is authenticated here with `authorizer`, the one the leader holds,
+    and crosses to the owner's write lane; it takes the proxy only
+    where the owner never saw it (ShmFallback), and the worker's wait
+    for the leader is stage `proxy_ms` by either transport."""
     import aiohttp as _aiohttp
 
     from dss_tpu.dar.shmfront import ShmFallback
+    from dss_tpu.obs.logging import get_logger
 
     session: dict = {}
+    log = get_logger("dss.worker")
+
+    async def _ring_write(request, index: int):
+        _m, _r, operation, key, _svc, _fn = RING_WRITES[index]
+        owner = _authorize(request, authorizer, operation)
+        if request.charset not in (None, "utf-8"):
+            return None  # the leader decodes it by its charset
+        body = await request.read()
+        route_dl = request.get("dss_deadline")
+        th = _trace_handle(request)
+        try:
+            status, payload, wait_ms = await asyncio.get_running_loop(
+            ).run_in_executor(None, functools.partial(
+                ring.write, index, request.match_info[key], owner, body,
+                deadline_s=None if route_dl is None
+                else route_dl - time.monotonic(),
+                th=th,
+            ))
+        except ShmFallback as e:
+            # dss_shm_worker_write_proxied counts it; the log says why
+            log.info("write took the loopback proxy: %s", e.reason)
+            return None
+        sink = request.get("dss_stages")
+        if sink is not None:
+            sink["proxy_ms"] = round(sink.get("proxy_ms", 0.0) + wait_ms, 3)
+        return web.Response(
+            body=payload, status=status, content_type="application/json"
+        )
 
     async def _get_session():
         if "s" not in session:
@@ -508,7 +639,12 @@ def make_worker_proxy_middleware(leader_url: str, follower=None,
         )
         canonical = resource.canonical if resource is not None else None
         fell_back = False
-        if (request.method, canonical) in WORKER_LOCAL_ROUTES:
+        index = _RING_WRITE_ROUTES.get((request.method, canonical))
+        if ring is not None and index is not None:
+            resp = await _ring_write(request, index)
+            if resp is not None:
+                return resp
+        elif (request.method, canonical) in WORKER_LOCAL_ROUTES:
             try:
                 return await handler(request)
             except ShmFallback:
@@ -617,8 +753,14 @@ def _native_ready() -> bool:
 async def _params(request) -> dict:
     if request.method in ("GET", "DELETE"):
         return {}
+    return _decode_params(await request.read(), request.charset)
+
+
+def _decode_params(raw: bytes, charset: Optional[str] = None) -> dict:
+    """A mutation's JSON body as a handler reads it (the ring's write
+    lane too: make_ring_write_fn)."""
     try:
-        body = await request.text()
+        body = raw.decode(charset or "utf-8")
         params = json.loads(body) if body else {}
     except ValueError as e:
         raise errors.bad_request(f"malformed request body: {e}")
@@ -749,27 +891,7 @@ def build_app(
                 _deadline.set_route_deadline(None)
 
     def auth(request, operation: str) -> str:
-        """-> owner.  No authorizer configured (unit harness) -> anon."""
-        if authorizer is None:
-            return "anonymous"
-        t0 = time.perf_counter()
-        t0_w = time.time_ns()
-        try:
-            owner = authorizer.authorize(
-                request.headers.get("Authorization"), operation
-            )
-        finally:
-            auth_ms = (time.perf_counter() - t0) * 1000
-            sink = request.get("dss_stages")
-            if sink is not None:
-                sink["auth_ms"] = round(auth_ms, 3)
-            th = _trace_handle(request)
-            if th is not None:
-                from dss_tpu.obs import trace as _trace
-
-                _trace.add_span(th, "auth_ms", t0_w, auth_ms)
-        request["dss_owner"] = owner
-        return owner
+        return _authorize(request, authorizer, operation)
 
     # -- health + metrics (no auth) ------------------------------------------
 

@@ -18,7 +18,9 @@ import time
 
 from aiohttp import web
 
-from dss_tpu.api.app import RID_SCOPES, SCD_SCOPES, build_app
+from dss_tpu.api.app import (
+    RID_SCOPES, SCD_SCOPES, build_app, make_ring_write_fn,
+)
 from dss_tpu.auth.authorizer import (
     Authorizer,
     JWKSResolver,
@@ -561,6 +563,10 @@ def build_worker(args) -> web.Application:
         worker_proxy=make_worker_proxy_middleware(
             args.leader_url, follower=follower,
             costs=front.costs if front is not None else None,
+            # a PUT of an op reference rides the ring to the owner's
+            # write lane, authenticated here as the leader would
+            ring=front,
+            authorizer=authorizer,
         ),
     )
     # the worker's boot heap is the initially-replayed WAL; tail
@@ -917,9 +923,10 @@ def build(args) -> web.Application:
         wal_seq_fn=(lambda: store.wal.seq) if args.workers > 0 else None,
     )
     # main() attaches the shared-memory front to the store (workers
-    # mode) after the listen sockets exist
+    # mode) after the listen sockets exist, with this write lane
     app["dss_store"] = store
     app["dss_metrics"] = metrics
+    app["dss_ring_write"] = make_ring_write_fn({"scd": scd}, metrics)
 
     # autotune profile provenance: stable gauge whether or not a
     # profile was loaded — 0.0 means "no profile or no timestamp",
@@ -1291,19 +1298,21 @@ def main():
                 worker_ttl_s=float(
                     os.environ.get("DSS_SHM_WORKER_TTL_S", 5.0)
                 ),
+                write_fn=app["dss_ring_write"],
             )
-            # the leader's stage observations (loopback-proxied
-            # writes) land in block N; its /metrics also renders the
-            # merged whole-front stage histograms
+            # the leader's stage observations (writes on the ring's
+            # write lane or the loopback proxy) land in block N; its
+            # /metrics also renders the merged whole-front stage
+            # histograms
             app["dss_metrics"].attach_stage_writer(
                 shmring.StageHistWriter(region, args.workers)
             )
             app["dss_metrics"].set_stage_agg(
                 lambda _r=region: shmring.shm_stage_hist(_r)
             )
-            # a proxied write is observed twice, by the worker around
-            # its hop and here: the owner's own interval gets a name
-            # of its own beside the merged handler_ms
+            # a write is observed twice, by the worker around its wait
+            # for the leader and here: the owner's own interval gets a
+            # name of its own beside the merged handler_ms
             app["dss_metrics"].handler_stages = (
                 "handler_ms", "leader_handler_ms",
             )

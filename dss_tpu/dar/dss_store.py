@@ -1888,13 +1888,14 @@ class DSSStore:
         )
 
     def attach_shm_front(self, region, *, threads: int = None,
-                         worker_ttl_s: float = 5.0):
+                         worker_ttl_s: float = 5.0, write_fn=None):
         """Make this store the device owner of a shared-memory serving
         front: every entity class's cell clock broadcasts its bumps
         into the region's fence segment, and a ShmOwner drain serves
-        ring requests through shm_serve.  Returns the started owner
-        (the caller — cmds/server.py — reclaims dead workers' slots
-        via owner.reclaim_worker)."""
+        ring requests through shm_serve (and mutations through
+        `write_fn`, api/app.py make_ring_write_fn).  Returns the started
+        owner (the caller — cmds/server.py — reclaims dead workers'
+        slots via owner.reclaim_worker)."""
         from dss_tpu.parallel import shmring
 
         if self._shm_owner is not None:
@@ -1906,7 +1907,7 @@ class DSSStore:
         owner = shmring.ShmOwner(
             region, self.shm_serve, threads=threads,
             wal_seq_fn=lambda: self.wal.seq,
-            worker_ttl_s=worker_ttl_s,
+            worker_ttl_s=worker_ttl_s, write_fn=write_fn,
         )
         owner.start()
         self._shm_owner = owner

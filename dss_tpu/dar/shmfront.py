@@ -34,6 +34,12 @@ hands the replica's own records, uncopied, to the services' encoder
 services/serialization.py); `search_*` copies them for a caller that
 changes or keeps what it gets.
 
+A mutation of api/app.py's RING_WRITES crosses the same ring to the
+owner's write lane (`ShmSearchFront.write`): authenticated here, run
+there by the leader's own service, its HTTP status and body handed back
+as they are, and the replica caught up to the commit before the answer
+leaves.  Only a write the owner never saw may take the proxy.
+
 Subscription classes (rid_sub / scd_sub) deliberately skip the
 worker-local cache: their records carry notification indexes that
 writes bump WITHOUT touching the cell clock (by design — see
@@ -47,7 +53,7 @@ from __future__ import annotations
 
 import copy
 import time
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -84,7 +90,7 @@ class ShmFallback(Exception):
 class ShmSearchFront:
     """Shared machinery of the worker-side wrappers: worker-local
     fenced cache, the ring client, the route decision, and the
-    replica-catchup wait."""
+    replica-catchup wait; and the worker's side of the write lane."""
 
     def __init__(self, region: shmring.ShmRegion,
                  client: shmring.ShmWorkerClient, follower, clock, *,
@@ -293,6 +299,65 @@ class ShmSearchFront:
         rcache.note_search(cls, epoch or self.fence_view.epoch(),
                            resp.gen, False)
         return resp.ids
+
+    def write(self, route: int, entity: str, owner: str, body: bytes, *,
+              deadline_s: Optional[float] = None,
+              th=None) -> Tuple[int, bytes, float]:
+        """One mutation through the owner's write lane (blocks: run it
+        on the executor) -> (HTTP status, body, ms waited for the
+        leader).  Raises ShmFallback only where the owner never saw
+        the request (owner's heartbeat stale, ring full, a request
+        larger than a slot, an injected enqueue fault); a write it was
+        handed is never sent again, and a wait that ends without its
+        answer is a 504 (or a 503 where the owner took the slot back).
+        After a success the replica catches up to the commit, as after
+        a proxied write."""
+        client = self.client
+        age_s = self.region.owner_heartbeat_age_s()
+        if age_s >= self.owner_ttl_s:
+            client.stat_add(shmring.WS_WRITE_PROXIED)
+            raise ShmFallback(f"owner heartbeat {age_s:.3f} s old")
+        t0 = time.perf_counter_ns()
+        t0_w = time.time_ns() if th is not None else 0
+        try:
+            resp = client.call_mutation(
+                route=route, entity=entity, owner=owner, body=body,
+                deadline_s=deadline_s,
+                trace_id=None if th is None else th.ctx.trace_id,
+                trace_sampled=th is not None,
+            )
+        except (shmring.RingFull, shmring.RingOversize,
+                chaos.FaultError) as e:
+            client.stat_add(shmring.WS_WRITE_PROXIED)
+            raise ShmFallback(type(e).__name__)
+        except shmring.RingReclaimed as e:
+            client.stat_add(shmring.WS_WRITE_RING)
+            raise errors.unavailable(f"write lane: {e}")
+        except shmring.RingTimeout as e:
+            client.stat_add(shmring.WS_WRITE_RING)
+            raise errors.deadline_exceeded(f"write lane: {e}")
+        wait_ms = (time.perf_counter_ns() - t0) / 1e6
+        client.stat_add(shmring.WS_WRITE_RING)
+        if th is not None:
+            sid = _trace.add_span(
+                th, "proxy", t0_w, wait_ms, attrs={"transport": "ring"}
+            )
+            t_claim, t_pickup, _ = resp.stamps
+            if resp.trace_ns and sid is not None and t_claim:
+                self._stitch_owner_spans(
+                    th, sid, resp.trace_ns, t0_w + t_claim - t0,
+                    t0_w + t_pickup - t0,
+                )
+        if not resp.body:
+            # the owner answered nothing of its own: dropped at its
+            # deadline before it started, or a slot it could not read
+            raise (errors.deadline_exceeded("request deadline expired "
+                                            "in the write lane")
+                   if resp.status == shmring.ST_DEADLINE else
+                   errors.internal("the write lane failed the request"))
+        if resp.status < 400 and resp.wal_seq:
+            self.follower.wait_for(int(resp.wal_seq), self.catchup_s)
+        return resp.status, resp.body, wait_ms
 
     @staticmethod
     def _stitch_owner_spans(th, ring_sid, trace_ns, claim_w: int,

@@ -61,6 +61,17 @@ replica-catchup bound for record assembly), the class write generation
 the slot exactly like the in-process admission path, so the shm route
 keeps the coalescer's admission/deadline semantics end to end.
 
+A slot can also carry a MUTATION: the request flag F_WRITE marks it,
+and the payload is a route number of the worker's dispatch table
+(api/app.py RING_WRITES), the entity id, the authenticated owner and
+the raw body; the response is an HTTP status, the body's bytes and the
+WAL sequence after the commit.  The scanner hands such slots to ONE
+write thread of their own (ShmOwner._write_loop): writes serialise on
+the store's lock anyway, and none ever holds a search serve thread.  A
+body larger than the slot is parked in a file beside the region
+(`spill_path`) and read once by the worker: a claimed write is never
+executed twice.
+
 Fault sites (chaos/faults.py): `shm.ring.enqueue` (worker side — an
 injected fault falls back to the loopback proxy, never a 5xx) and
 `shm.fence.broadcast` (owner side — an injected fault POISONS the
@@ -70,6 +81,7 @@ rather than ever serving across a missed bump).
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import errno
 import mmap
@@ -99,10 +111,13 @@ __all__ = [
     "SHM_CLASSES",
     "RingFull",
     "RingTimeout",
+    "RingReclaimed",
     "RingOversize",
     "ShmRegion",
     "ShmRequest",
     "ShmResponse",
+    "ShmMutation",
+    "ShmMutationResponse",
     "FenceMirror",
     "WorkerFenceView",
     "ShmOwner",
@@ -118,7 +133,7 @@ __all__ = [
 SHM_CLASSES = ("isa", "rid_sub", "op", "scd_sub", "constraint")
 
 MAGIC = 0x4453_5353_484D_5231  # "DSSSHMR1"
-VERSION = 5  # v2: trace words in the slot header + the per-process
+VERSION = 6  # v2: trace words in the slot header + the per-process
 #              stage-histogram segment (distributed tracing PR)
 #              v3: three clock stamps in every response + the stage
 #              blocks' new names (the ring split at its seams)
@@ -126,6 +141,7 @@ VERSION = 5  # v2: trace words in the slot header + the per-process
 #              that never rings it would leave every request to the
 #              scanner's backstop)
 #              v5: the stage blocks grow by the write path's legs
+#              v6: the write kind (F_WRITE) and its response
 
 HEADER_BYTES = 4096
 WSTAT_BYTES = 256  # 32 i64 counters per worker
@@ -158,6 +174,11 @@ F_HAS_T0 = 4
 F_HAS_T1 = 8
 F_HAS_OWNER = 16
 F_HAS_ALT_HI = 32
+# the slot carries a mutation (ShmRegion.write_mutation), not a search
+F_WRITE = 64
+
+# mutation response flag: the body is in the slot's spill file
+WRESP_F_SPILLED = 1
 
 # worker stat block indices (single-writer per block; the leader's
 # /metrics aggregation reads them as dss_shm_worker_* families)
@@ -185,6 +206,11 @@ WS_WAKE_BACKSTOPS = 15
 # remembered): services/serialization.py isas_body, operations_body
 WS_WIRE_MEMO_HITS = 16
 WS_WIRE_MEMO_MISSES = 17
+# this worker's PUTs of op references (api/app.py RING_WRITES) by the
+# transport that carried them: a ring slot, or the loopback proxy
+# (ring full, request larger than a slot, owner's heartbeat stale)
+WS_WRITE_RING = 18
+WS_WRITE_PROXIED = 19
 WSTAT_NAMES = {
     WS_ENQUEUED: "enqueued",
     WS_SERVED: "served",
@@ -202,6 +228,8 @@ WSTAT_NAMES = {
     WS_WAKE_BACKSTOPS: "wake_backstops",
     WS_WIRE_MEMO_HITS: "wire_memo_hits",
     WS_WIRE_MEMO_MISSES: "wire_memo_misses",
+    WS_WRITE_RING: "write_ring",
+    WS_WRITE_PROXIED: "write_proxied",
 }
 
 _OWNER_MAX = 120  # bytes of utf-8 owner scope a slot can carry
@@ -212,6 +240,7 @@ _OWNER_MAX = 120  # bytes of utf-8 owner scope a slot can carry
 # dss_shm_* families from its own /metrics endpoint: with the owner
 # off the public port, scrapes only ever land on workers.
 _OHDR_OFF = 64
+_HEARTBEAT_OFF = 40  # wall-clock ns of the owner's scanner, each loop
 OH_SERVED = 0
 OH_ERRORS = 1
 OH_DEADLINE_DROPS = 2
@@ -233,6 +262,11 @@ OH_DEVICE_SERVE_NS = 11
 # or only when the wait's backstop ran out (a lost or late wake)
 OH_WAKES = 12
 OH_WAKE_BACKSTOPS = 13
+# mutations the write thread ran (any HTTP status the handler gave),
+# and of those the answers too large for the slot (spilled).  Writes
+# stay out of every word above but OH_ERRORS and OH_DEADLINE_DROPS
+OH_WRITE_SERVED = 14
+OH_WRITE_SPILLED = 15
 # the owner's doorbell: one 32-bit word on a cache line of its own,
 # written by every worker (ShmRegion.write_request), waited on by the
 # owner's scanner
@@ -277,6 +311,20 @@ _RESP_HDR = struct.Struct("<iiqqdi")  # status, n_hits, wal_seq, gen,
 _PAYLOAD_OFF = _TRACE_OFF + _TRACE_BYTES
 _REQ_FIXED = _PAYLOAD_OFF + _REQ_HDR.size
 _RESP_FIXED = _PAYLOAD_OFF + _RESP_HDR.size
+# the write kind: F_WRITE in the flags word, which both request kinds
+# keep at the same place; then the route number's place is the search's
+# cls.  A mutation request: route, flags, deadline_ns, and the lengths
+# of the entity id, the owner and the body that follow it, each padded
+# to 8 bytes.  Its response: the HTTP status, flags (WRESP_F_SPILLED),
+# the WAL sequence after the commit, the body's length, then the body
+_FLAGS_OFF = _PAYLOAD_OFF + 4
+_WREQ_HDR = struct.Struct("<iiqiii4x")
+_WRESP_HDR = struct.Struct("<iiqq")
+_WREQ_FIXED = _PAYLOAD_OFF + _WREQ_HDR.size
+_WRESP_FIXED = _PAYLOAD_OFF + _WRESP_HDR.size
+# a mutation's wait where its request has no deadline: the loopback
+# proxy's own time-out (api/app.py make_worker_proxy_middleware)
+_WRITE_WAIT_S = 60.0
 
 
 def tid_split(trace_id: str) -> Tuple[int, int]:
@@ -314,6 +362,11 @@ class RingFull(RuntimeError):
 
 class RingTimeout(RuntimeError):
     """The owner did not answer within the wait bound."""
+
+
+class RingReclaimed(RingTimeout):
+    """The owner took the slot back unanswered: it declared this
+    worker dead (a stall, or a prior incarnation's death)."""
 
 
 class RingOversize(RuntimeError):
@@ -464,6 +517,8 @@ def empty_stats() -> dict:
         "dss_shm_serve_ms_total": 0.0,
         "dss_shm_owner_wakes_total": 0,
         "dss_shm_owner_wake_backstops_total": 0,
+        "dss_shm_write_served_total": 0,
+        "dss_shm_write_spilled_total": 0,
         "dss_shm_saturation": 0.0,
         "dss_shm_ring_full_total": 0,
     }
@@ -509,6 +564,10 @@ def front_stats(region: "ShmRegion") -> dict:
         # wait), against only after the wait's backstop ran out
         "dss_shm_owner_wakes_total": int(oh[OH_WAKES]),
         "dss_shm_owner_wake_backstops_total": int(oh[OH_WAKE_BACKSTOPS]),
+        # mutations the write thread ran, and the answers of those that
+        # did not fit their slot
+        "dss_shm_write_served_total": int(oh[OH_WRITE_SERVED]),
+        "dss_shm_write_spilled_total": int(oh[OH_WRITE_SPILLED]),
         # fraction of the whole front's slots in flight — the
         # DssShmRingSaturated alert input
         "dss_shm_saturation": round(
@@ -572,6 +631,34 @@ class ShmResponse:
         return bool(self.flags & RESP_F_MESH_SERVED)
 
 
+class ShmMutation:
+    """A decoded mutation slot (owner side): the route's number in the
+    worker's dispatch table, the entity id, the authenticated owner and
+    the request's raw body."""
+
+    __slots__ = ("route", "entity", "owner", "body", "deadline_ns",
+                 "worker", "slot", "req_id", "trace_id", "trace_sampled")
+
+    def __init__(self, **kw):
+        for k in self.__slots__:
+            setattr(self, k, kw.get(k))
+
+
+class ShmMutationResponse:
+    """A decoded mutation answer (worker side): the handler's HTTP
+    status and body, the WAL sequence after the commit, and the trace
+    words and clock stamps every response carries."""
+
+    __slots__ = ("status", "body", "wal_seq", "trace_ns", "stamps")
+
+    def __init__(self, status, body, wal_seq, trace_ns, stamps):
+        self.status = status
+        self.body = body
+        self.wal_seq = wal_seq
+        self.trace_ns = trace_ns
+        self.stamps = stamps
+
+
 class ShmRegion:
     """The mmap'd region: geometry, views, and slot codecs shared by
     the owner and worker endpoints.  One process calls `create`
@@ -626,6 +713,14 @@ class ShmRegion:
         # owner counter block (header): single-writer, any reader
         self._ohdr = np.ndarray(
             (16,), dtype=np.int64, buffer=mm, offset=_OHDR_OFF,
+        )
+        # the owner's heartbeat: one aligned word, stored and loaded
+        # whole.  struct.pack_into zero-fills its buffer before it
+        # writes the bytes, so a reader in another process could see
+        # 0 (measured: a PUT sent to the proxy for an owner "1.79e9 s
+        # old")
+        self._heartbeat = np.ndarray(
+            (1,), dtype=np.int64, buffer=mm, offset=_HEARTBEAT_OFF,
         )
         # the owner's doorbell, and where this mapping starts in this
         # process's memory: the waits name their words by address
@@ -703,6 +798,7 @@ class ShmRegion:
         self._fence_stamps = []
         self._states = None
         self._ohdr = None
+        self._heartbeat = None
         self._bell = None
         self._buf.release()
         self._mm.close()
@@ -719,10 +815,10 @@ class ShmRegion:
         )
 
     def set_owner_heartbeat(self) -> None:
-        struct.pack_into("<q", self._mm, 40, time.time_ns())
+        self._heartbeat[0] = time.time_ns()
 
     def owner_heartbeat_age_s(self) -> float:
-        hb = struct.unpack_from("<q", self._mm, 40)[0]
+        hb = int(self._heartbeat[0])
         return max(0.0, (time.time_ns() - hb) / 1e9)
 
     # -- worker stats --------------------------------------------------------
@@ -859,19 +955,7 @@ class ShmRegion:
         if owner_b:
             flags |= F_HAS_OWNER
         mm = self._mm
-        # trace words: id + sampled bit in, owner span slots zeroed
-        # (the response fills them) — fixed words, never serialized
-        if trace_id:
-            hi, lo = tid_split(trace_id)
-            tflags = TRACE_F_PRESENT | (
-                TRACE_F_SAMPLED if trace_sampled else 0
-            )
-        else:
-            hi = lo = tflags = 0
-        _TRACE_REQ.pack_into(mm, off + _TRACE_OFF, hi, lo, tflags)
-        _TRACE_RESP.pack_into(
-            mm, off + _TRACE_RESP_OFF, *([0] * _TRACE_RESP_WORDS)
-        )
+        self._write_trace_words(off, trace_id, trace_sampled)
         _REQ_HDR.pack_into(
             mm, off + _PAYLOAD_OFF, cls_idx, flags,
             0.0 if alt_lo is None else float(alt_lo),
@@ -886,9 +970,36 @@ class ShmRegion:
         p += _pad8(len(owner_b))
         if n:
             mm[p:p + 8 * n] = cells.tobytes()
-        struct.pack_into("<q", mm, off + 8, req_id)
+        self._publish_request(worker, slot, off, req_id)
+
+    def _write_trace_words(self, off: int, trace_id: Optional[str],
+                           trace_sampled: bool) -> None:
+        # trace words: id + sampled bit in, owner span slots zeroed
+        # (the response fills them) — fixed words, never serialized
+        if trace_id:
+            hi, lo = tid_split(trace_id)
+            tflags = TRACE_F_PRESENT | (
+                TRACE_F_SAMPLED if trace_sampled else 0
+            )
+        else:
+            hi = lo = tflags = 0
+        _TRACE_REQ.pack_into(self._mm, off + _TRACE_OFF, hi, lo, tflags)
+        _TRACE_RESP.pack_into(
+            self._mm, off + _TRACE_RESP_OFF, *([0] * _TRACE_RESP_WORDS)
+        )
+
+    def _read_trace_words(self, off: int) -> Tuple[Optional[str], bool]:
+        thi, tlo, tflags = _TRACE_REQ.unpack_from(self._mm, off + _TRACE_OFF)
+        return (
+            tid_join(thi, tlo) if tflags & TRACE_F_PRESENT else None,
+            bool(tflags & TRACE_F_SAMPLED),
+        )
+
+    def _publish_request(self, worker: int, slot: int, off: int,
+                         req_id: int) -> None:
+        struct.pack_into("<q", self._mm, off + 8, req_id)
         _PUBLISHED.pack_into(
-            mm, off + _PUBLISHED_OFF, time.perf_counter_ns()
+            self._mm, off + _PUBLISHED_OFF, time.perf_counter_ns()
         )
         # publish LAST: one aligned 8-byte store
         self._states[worker * self.depth + slot] = REQ
@@ -902,7 +1013,7 @@ class ShmRegion:
         off = self._slot_off(worker, slot)
         mm = self._mm
         req_id = struct.unpack_from("<q", mm, off + 8)[0]
-        thi, tlo, tflags = _TRACE_REQ.unpack_from(mm, off + _TRACE_OFF)
+        trace_id, trace_sampled = self._read_trace_words(off)
         (cls_idx, flags, alt_lo, alt_hi, t0, t1, now_ns, deadline_ns,
          owner_len, n) = _REQ_HDR.unpack_from(mm, off + _PAYLOAD_OFF)
         p = off + _REQ_FIXED
@@ -928,11 +1039,7 @@ class ShmRegion:
             owner=owner,
             allow_stale=bool(flags & F_ALLOW_STALE),
             worker=worker, slot=slot, req_id=req_id,
-            trace_id=(
-                tid_join(thi, tlo)
-                if tflags & TRACE_F_PRESENT else None
-            ),
-            trace_sampled=bool(tflags & TRACE_F_SAMPLED),
+            trace_id=trace_id, trace_sampled=trace_sampled,
         )
 
     def write_response(self, worker: int, slot: int, *, status: int,
@@ -951,10 +1058,7 @@ class ShmRegion:
         write's own is added just before the publish."""
         off = self._slot_off(worker, slot)
         mm = self._mm
-        if trace_ns is not None:
-            vec = list(trace_ns)[:_TRACE_RESP_WORDS]
-            vec += [0] * (_TRACE_RESP_WORDS - len(vec))
-            _TRACE_RESP.pack_into(mm, off + _TRACE_RESP_OFF, *vec)
+        self._write_trace_ns(off, trace_ns)
         n = len(ids)
         id_blob = b""
         if n:
@@ -977,8 +1081,19 @@ class ShmRegion:
             mm[p:p + 8 * n] = t1arr.tobytes()
             p += 8 * n
             mm[p:p + len(id_blob)] = id_blob
+        self._publish_response(worker, slot, off, stamps)
+
+    def _write_trace_ns(self, off: int,
+                        trace_ns: Optional[Sequence[int]]) -> None:
+        if trace_ns is not None:
+            vec = list(trace_ns)[:_TRACE_RESP_WORDS]
+            vec += [0] * (_TRACE_RESP_WORDS - len(vec))
+            _TRACE_RESP.pack_into(self._mm, off + _TRACE_RESP_OFF, *vec)
+
+    def _publish_response(self, worker: int, slot: int, off: int,
+                          stamps: Tuple[int, int]) -> None:
         _STAMPS.pack_into(
-            mm, off + _STAMPS_OFF, int(stamps[0]), int(stamps[1]),
+            self._mm, off + _STAMPS_OFF, int(stamps[0]), int(stamps[1]),
             time.perf_counter_ns(),
         )
         self._states[worker * self.depth + slot] = RESP
@@ -1003,6 +1118,117 @@ class ShmRegion:
             p += ln
         return ShmResponse(
             status, ids, t1s, wal_seq, gen, retry_after_s, flags,
+            trace_ns=_TRACE_RESP.unpack_from(mm, off + _TRACE_RESP_OFF),
+            stamps=_STAMPS.unpack_from(mm, off + _STAMPS_OFF),
+        )
+
+    # -- the write kind --------------------------------------------------------
+
+    def is_mutation(self, worker: int, slot: int) -> bool:
+        """Owner side, on a REQ slot: does it carry a mutation?"""
+        return bool(struct.unpack_from(
+            "<i", self._mm, self._slot_off(worker, slot) + _FLAGS_OFF
+        )[0] & F_WRITE)
+
+    def spill_path(self, worker: int, slot: int) -> str:
+        """Where an answer too large for the slot is parked: beside the
+        region, in the same private directory, one file a slot."""
+        return f"{self.path}.spill-{worker}-{slot}"
+
+    def drop_spill(self, worker: int, slot: int) -> None:
+        """Remove a parked answer nobody will read (its waiter gave up)."""
+        try:
+            os.unlink(self.spill_path(worker, slot))
+        except FileNotFoundError:
+            pass
+
+    def write_mutation(self, worker: int, slot: int, req_id: int, *,
+                       route: int, entity: str, owner: str, body: bytes,
+                       deadline_ns: int, trace_id: Optional[str] = None,
+                       trace_sampled: bool = False) -> None:
+        """Encode a mutation request, then publish state=REQ.  Raises
+        RingOversize, with the slot untouched, when it cannot fit."""
+        parts = (entity.encode("utf-8"), (owner or "").encode("utf-8"),
+                 bytes(body))
+        need = _WREQ_FIXED + sum(_pad8(len(b)) for b in parts)
+        if need > self.slot_bytes:
+            raise RingOversize(f"mutation of {need} bytes exceeds slot")
+        off = self._slot_off(worker, slot)
+        mm = self._mm
+        self._write_trace_words(off, trace_id, trace_sampled)
+        _WREQ_HDR.pack_into(
+            mm, off + _PAYLOAD_OFF, route, F_WRITE, int(deadline_ns),
+            *(len(b) for b in parts),
+        )
+        p = off + _WREQ_FIXED
+        for b in parts:
+            mm[p:p + len(b)] = b
+            p += _pad8(len(b))
+        self._publish_request(worker, slot, off, req_id)
+
+    def read_mutation(self, worker: int, slot: int) -> ShmMutation:
+        off = self._slot_off(worker, slot)
+        mm = self._mm
+        route, _flags, deadline_ns, *lens = _WREQ_HDR.unpack_from(
+            mm, off + _PAYLOAD_OFF
+        )
+        parts = []
+        p = off + _WREQ_FIXED
+        for n in lens:
+            parts.append(bytes(mm[p:p + n]))
+            p += _pad8(n)
+        trace_id, trace_sampled = self._read_trace_words(off)
+        return ShmMutation(
+            route=route, entity=parts[0].decode("utf-8"),
+            owner=parts[1].decode("utf-8"), body=parts[2],
+            deadline_ns=deadline_ns, worker=worker, slot=slot,
+            req_id=struct.unpack_from("<q", mm, off + 8)[0],
+            trace_id=trace_id, trace_sampled=trace_sampled,
+        )
+
+    def write_mutation_response(self, worker: int, slot: int, *,
+                                status: int, body: bytes = b"",
+                                wal_seq: int = 0,
+                                trace_ns: Optional[Sequence[int]] = None,
+                                stamps: Tuple[int, int] = (0, 0)) -> bool:
+        """Encode a mutation's answer over its request, then publish
+        state=RESP.  A body the slot cannot hold is written to the
+        slot's spill file first, and the worker reads it from there
+        once: the write is never run again.  -> True when spilled."""
+        off = self._slot_off(worker, slot)
+        mm = self._mm
+        spilled = _WRESP_FIXED + len(body) > self.slot_bytes
+        if spilled:
+            with open(self.spill_path(worker, slot), "wb") as fh:
+                fh.write(body)
+        self._write_trace_ns(off, trace_ns)
+        _WRESP_HDR.pack_into(
+            mm, off + _PAYLOAD_OFF, int(status),
+            WRESP_F_SPILLED if spilled else 0, int(wal_seq), len(body),
+        )
+        if not spilled:
+            p = off + _WRESP_FIXED
+            mm[p:p + len(body)] = body
+        self._publish_response(worker, slot, off, stamps)
+        return spilled
+
+    def read_mutation_response(self, worker: int,
+                               slot: int) -> ShmMutationResponse:
+        off = self._slot_off(worker, slot)
+        mm = self._mm
+        status, flags, wal_seq, n = _WRESP_HDR.unpack_from(
+            mm, off + _PAYLOAD_OFF
+        )
+        if flags & WRESP_F_SPILLED:
+            path = self.spill_path(worker, slot)
+            with open(path, "rb") as fh:
+                body = fh.read()
+            os.unlink(path)
+        else:
+            p = off + _WRESP_FIXED
+            body = bytes(mm[p:p + n])
+        return ShmMutationResponse(
+            status, body, wal_seq,
             trace_ns=_TRACE_RESP.unpack_from(mm, off + _TRACE_RESP_OFF),
             stamps=_STAMPS.unpack_from(mm, off + _STAMPS_OFF),
         )
@@ -1137,17 +1363,23 @@ class ShmOwner:
     across every worker ring and a small pool serves them through the
     store's normal search path (admission, deadline routing, planner,
     read cache — the whole pipeline), then publishes responses back
-    into the same slots.  Also reclaims rings of dead workers."""
+    into the same slots; one more thread, the write lane, runs the
+    mutations.  Also reclaims rings of dead workers."""
 
     def __init__(self, region: ShmRegion, serve_fn: Callable,
                  *, threads: int = None, wal_seq_fn: Callable = None,
-                 worker_ttl_s: float = 5.0):
+                 worker_ttl_s: float = 5.0, write_fn: Callable = None):
         """serve_fn(ShmRequest) -> (ids, t1s, gen); raises
         errors.StatusError subclasses for admission/deadline verdicts.
         wal_seq_fn() -> the WAL sequence already durable when the
-        answer was computed (the worker's catchup bound)."""
+        answer was computed (the worker's catchup bound).
+        write_fn(ShmMutation) -> (HTTP status, body bytes, after): the
+        mutation run as the leader's own handler runs it, every error
+        rendered; `after(handler_s)`, where not None, is called once
+        the answer is published, with the time from pickup to then."""
         self._region = region
         self._serve_fn = serve_fn
+        self._write_fn = write_fn
         self._wal_seq_fn = wal_seq_fn or (lambda: 0)
         self._threads = threads or min(
             4, max(2, (os.cpu_count() or 2))
@@ -1159,6 +1391,10 @@ class ShmOwner:
         self._qcond = threading.Condition(self._qlock)
         self._pool: List[threading.Thread] = []
         self._scanner: Optional[threading.Thread] = None
+        # the write lane: its own FIFO and its one thread
+        self._writes: "collections.deque" = collections.deque()
+        self._wcond = threading.Condition(threading.Lock())
+        self._writer: Optional[threading.Thread] = None
         self._dead_workers: set = set()
         # wall-clock ns when each dead worker was declared dead: only
         # a heartbeat written AFTER this (a respawned process, or a
@@ -1183,6 +1419,10 @@ class ShmOwner:
             )
             t.start()
             self._pool.append(t)
+        self._writer = threading.Thread(
+            target=self._write_loop, name="shm-write", daemon=True
+        )
+        self._writer.start()
         self._scanner = threading.Thread(
             target=self._scan_loop, name="shm-scan", daemon=True
         )
@@ -1192,10 +1432,13 @@ class ShmOwner:
         self._stop.set()
         with self._qcond:
             self._qcond.notify_all()
+        with self._wcond:
+            self._wcond.notify_all()
         if self._scanner is not None:
             self._scanner.join(timeout=5)
-        for t in self._pool:
-            t.join(timeout=5)
+        for t in self._pool + [self._writer]:
+            if t is not None:
+                t.join(timeout=5)
 
     # -- reclaim -------------------------------------------------------------
 
@@ -1241,6 +1484,7 @@ class ShmOwner:
             req_idx = np.nonzero(states == REQ)[0]
             if len(req_idx):
                 claimed = []
+                writes = []
                 late = False
                 t_claim = time.perf_counter_ns()
                 for flat in req_idx.tolist():
@@ -1254,11 +1498,17 @@ class ShmOwner:
                         self._count(OH_RECLAIMED)
                         continue
                     r.set_slot_state(w, s, BUSY)
-                    claimed.append((w, s, t_claim))
+                    (writes if r.is_mutation(w, s) else claimed).append(
+                        (w, s, t_claim)
+                    )
                 if claimed:
                     with self._qcond:
                         self._queue.extend(claimed)
                         self._qcond.notify_all()
+                if writes:
+                    with self._wcond:
+                        self._writes.extend(writes)
+                        self._wcond.notify()
                 self._count(OH_WAKE_BACKSTOPS if late else OH_WAKES)
                 turn, t_out = 0, 0
             else:
@@ -1413,6 +1663,84 @@ class ShmOwner:
                else OH_HOST_SERVE_NS] += took
         return ST_OK
 
+    def _write_loop(self) -> None:
+        """The write lane: one thread, first in first out.  Writes
+        serialise on the store's lock anyway; here none holds a search
+        serve thread, and the search-only owner words never count one."""
+        r = self._region
+        while True:
+            with self._wcond:
+                while not self._writes and not self._stop.is_set():
+                    with _trace.annotate("owner.idle"):
+                        self._wcond.wait(0.1)
+                if self._stop.is_set() and not self._writes:
+                    return
+                w, s, t_claim = self._writes.popleft()
+            stamps = (t_claim, time.perf_counter_ns())
+            done = None
+            try:
+                with _trace.annotate("owner.write"):
+                    done = self._serve_mutation(r.read_mutation(w, s), stamps)
+            except Exception:  # noqa: BLE001 — a bad slot must not kill the lane
+                # only before the answer was published: the slot is
+                # still this thread's, and the write is never re-run
+                self._count(OH_ERRORS)
+                try:
+                    r.write_mutation_response(
+                        w, s, status=ST_ERROR, stamps=stamps
+                    )
+                except Exception:  # noqa: BLE001
+                    r.free_slot(w, s)
+            if done is not None:
+                after, handler_s = done
+                try:
+                    after(handler_s)
+                except Exception:  # noqa: BLE001 — accounting only
+                    self._count(OH_ERRORS)
+
+    def _serve_mutation(self, req: ShmMutation, stamps: Tuple[int, int]):
+        """Run one mutation and publish its answer.  -> (after, the
+        handler's seconds from pickup to the answer published), or None
+        where there is nothing to call after."""
+        r = self._region
+        if req.deadline_ns and time.monotonic_ns() >= req.deadline_ns:
+            # the one point a write may be dropped: before it starts
+            self._count(OH_DEADLINE_DROPS)
+            r.write_mutation_response(
+                req.worker, req.slot, status=ST_DEADLINE, stamps=stamps
+            )
+            return None
+        if self._write_fn is None:
+            raise RuntimeError("this owner has no write lane function")
+        tok = None
+        if req.trace_id and req.trace_sampled:
+            tok = _trace.begin_collect(req.trace_id)
+        trace_vec = None
+        try:
+            status, body, after = self._write_fn(req)
+        finally:
+            if tok is not None:
+                trace_vec = _trace.owner_slot_vector(
+                    _trace.end_collect(tok),
+                    extra={
+                        "owner.queue_wait": (stamps[1] - stamps[0]) / 1e6,
+                        "owner.serve": (
+                            (time.perf_counter_ns() - stamps[1]) / 1e6
+                        ),
+                    },
+                )
+        spilled = r.write_mutation_response(
+            req.worker, req.slot, status=status, body=body,
+            wal_seq=self._wal_seq_fn() if status < 400 else 0,
+            trace_ns=trace_vec, stamps=stamps,
+        )
+        handler_s = (time.perf_counter_ns() - stamps[1]) / 1e9
+        with self._lock:
+            self._region._ohdr[OH_WRITE_SERVED] += 1
+            if spilled:
+                self._region._ohdr[OH_WRITE_SPILLED] += 1
+        return None if after is None else (after, handler_s)
+
     # -- introspection -------------------------------------------------------
 
     def stats(self) -> dict:
@@ -1480,6 +1808,8 @@ class ShmWorkerClient:
             for s in list(self._abandoned):
                 st = self._region.slot_state(self.worker, s)
                 if st == RESP:
+                    # an answer parked beside the slot goes with it
+                    self._region.drop_spill(self.worker, s)
                     self._region.set_slot_state(self.worker, s, FREE)
                 elif st != FREE:
                     continue
@@ -1518,17 +1848,12 @@ class ShmWorkerClient:
         slot's reserved trace words; a sampled request's response
         carries the owner's span-slot durations back (trace_ns)."""
         chaos.fault_point("shm.ring.enqueue", detail=cls)
-        r = self._region
-        slot = self._alloc()
-        wrote = False
-        try:
-            self._req_seq += 1
-            req_id = self._req_seq
-            wait_s = self._wait_s
-            if deadline_s is not None:
-                wait_s = min(wait_s, max(0.001, deadline_s))
-            deadline_ns = time.monotonic_ns() + int(wait_s * 1e9)
-            r.write_request(
+        wait_s = self._wait_s
+        if deadline_s is not None:
+            wait_s = min(wait_s, max(0.001, deadline_s))
+
+        def publish(slot, req_id, deadline_ns):
+            self._region.write_request(
                 self.worker, slot, req_id,
                 cls_idx=SHM_CLASSES.index(cls), cells=cells,
                 alt_lo=alt_lo, alt_hi=alt_hi, t0_ns=t0_ns, t1_ns=t1_ns,
@@ -1536,6 +1861,45 @@ class ShmWorkerClient:
                 owner=owner or "", allow_stale=allow_stale,
                 trace_id=trace_id, trace_sampled=trace_sampled,
             )
+
+        return self._round_trip(publish, wait_s, self._region.read_response)
+
+    def call_mutation(self, *, route: int, entity: str, owner: str,
+                      body: bytes, deadline_s: float = None,
+                      trace_id: str = None,
+                      trace_sampled: bool = False) -> ShmMutationResponse:
+        """One mutation through the owner's write lane.  RingFull and
+        RingOversize (and the `shm.ring.enqueue` seam) mean the owner
+        never saw it: the caller may send it another way.  RingTimeout
+        comes only after it was published, when the owner may have run
+        it: it must never be sent again.  Waits for the request's own
+        deadline, not the search's DSS_SHM_WAIT_S."""
+        chaos.fault_point("shm.ring.enqueue", detail="write")
+        wait_s = _WRITE_WAIT_S if deadline_s is None else max(0.001, deadline_s)
+
+        def publish(slot, req_id, deadline_ns):
+            self._region.write_mutation(
+                self.worker, slot, req_id, route=route, entity=entity,
+                owner=owner, body=body, deadline_ns=deadline_ns,
+                trace_id=trace_id, trace_sampled=trace_sampled,
+            )
+
+        return self._round_trip(
+            publish, wait_s, self._region.read_mutation_response
+        )
+
+    def _round_trip(self, publish: Callable, wait_s: float,
+                    read: Callable):
+        """publish(slot, req_id, deadline_ns) a request, wait for the
+        owner's answer, -> read(worker, slot) of it, the slot free
+        again."""
+        r = self._region
+        slot = self._alloc()
+        wrote = False
+        try:
+            self._req_seq += 1
+            deadline_ns = time.monotonic_ns() + int(wait_s * 1e9)
+            publish(slot, self._req_seq, deadline_ns)
             wrote = True
             self._region.stat_add(self.worker, WS_ENQUEUED)
             # block on the slot's state word until the owner wakes it
@@ -1559,7 +1923,7 @@ class ShmWorkerClient:
                     self._release(slot)
                     slot = None
                     self._region.stat_add(self.worker, WS_TIMEOUTS)
-                    raise RingTimeout(
+                    raise RingReclaimed(
                         "owner reclaimed the slot (worker marked dead)"
                     )
                 now = time.monotonic_ns()
@@ -1576,15 +1940,17 @@ class ShmWorkerClient:
                 # monotonic_ns and perf_counter_ns: one clock (above)
                 t_out = 0 if woken else now + limit_ns
                 turn += 1
-            resp = r.read_response(self.worker, slot)
+            try:
+                resp = read(self.worker, slot)
+            finally:
+                r.set_slot_state(self.worker, slot, FREE)
+                self._release(slot)
+                slot = None
             # a backstop found it: the answer was written (the owner's
             # stamp, on the host's one clock) before the last wait ran
             # out, and no wake-up came
             late = resp.stamps[2] < t_out
             self.stat_add(WS_WAKE_BACKSTOPS if late else WS_WAKES)
-            r.set_slot_state(self.worker, slot, FREE)
-            self._release(slot)
-            slot = None
             return resp
         except RingOversize:
             self._region.stat_add(self.worker, WS_OVERSIZE)

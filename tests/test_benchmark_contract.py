@@ -77,8 +77,8 @@ CONFIG = {
 }
 # a RID poll, a small op-intent check and a planned flight (PUT, 409 ->
 # key -> 200) in turn: every stage of a search through the ring and of
-# a notifying write through the proxy is observed; none reaches the
-# device route but the write's match (rqmatch)
+# a notifying write through the ring's write lane is observed; none
+# reaches the device route but the write's match (rqmatch)
 TRAFFIC = {"components": [
     {"share": 0.4, "endpoint": "rid_search",
      "w_cells": [1, 2], "h_cells": [1, 2]},
@@ -144,6 +144,9 @@ def test_the_boot_answered_searches_and_notifying_writes(served):
     assert leader["dss_scd_subscribers_notified_total"] >= 4
     assert leader["dss_push_offers_total"] == 4
     assert leader["dss_dar_scd_sub_co_plan_rqmatch"] == 4
+    # every PUT rode the ring to the owner's write lane
+    assert served["front"]["dss_shm_write_served_total"] >= 4
+    assert served["front"]["dss_shm_worker_write_proxied"] == 0
 
 
 def _allocator_peak(monkeypatch) -> bool:
@@ -189,8 +192,36 @@ def _match_without_a_pipeline(monkeypatch) -> bool:
     return "sub_match_ms" in sink and stage_name("sub_match_ms") != "other"
 
 
+def _exec_hops_without_the_ring(monkeypatch) -> bool:
+    """Stage `exec_wait_ms` of route `write`: the executor's two hops
+    around a PUT's service call, which a single-process server (and a
+    PUT that took the loopback proxy) pays; behind this boot's front a
+    PUT rides the ring to the owner's write lane, which has no
+    executor.  Checked on an app over a store of its own."""
+    import requests
+
+    from dss_tpu.api.app import build_app
+    from dss_tpu.clock import Clock
+    from dss_tpu.dar.dss_store import DSSStore
+    from dss_tpu.services.scd import SCDService
+    from tests.live_server import LiveServer
+    from tests.test_write_stages import OP1, _flight, _stages_of
+
+    store = DSSStore(storage="memory")
+    srv = LiveServer(build_app(None, SCDService(store.scd, Clock()), None,
+                               enable_scd=True, trace_requests=True))
+    try:
+        r = requests.put(f"{srv.base}/dss/v1/operation_references/{OP1}",
+                         json=_flight(), timeout=30)
+        return r.status_code == 200 and "exec_wait_ms" in _stages_of(r)
+    finally:
+        srv.stop()
+        store.close()
+
+
 # stages this boot's deployment never runs, and where each is checked
-STAGES_ELSEWHERE = {"sub_match_ms": _match_without_a_pipeline}
+STAGES_ELSEWHERE = {"sub_match_ms": _match_without_a_pipeline,
+                    "exec_wait_ms": _exec_hops_without_the_ring}
 
 
 @pytest.mark.parametrize("metric", _metric_files())

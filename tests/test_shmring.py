@@ -1013,7 +1013,9 @@ class _FrontHarness:
     the cmds/server.py worker topology, in-process."""
 
     def __init__(self, tmp_path, storage="memory", depth=16,
-                 cache_cap=256):
+                 cache_cap=256, slot_bytes=32768, writes=None):
+        """`writes(leader store, clock)` -> the owner's write lane
+        function (tests/test_ring_writes.py)."""
         self.clock = FakeClock(T0)
         self.wal_path = str(tmp_path / "wal.jsonl")
         self.leader = DSSStore(
@@ -1022,10 +1024,14 @@ class _FrontHarness:
         self.region_path = str(tmp_path / "ring.shm")
         region = shmring.ShmRegion.create(
             self.region_path, nworkers=1, depth=depth,
-            fence_slots=1 << 12,
+            slot_bytes=slot_bytes, fence_slots=1 << 12,
         )
         self.owner_region = region
-        self.owner = self.leader.attach_shm_front(region)
+        self.owner = self.leader.attach_shm_front(
+            region,
+            write_fn=None if writes is None
+            else writes(self.leader, self.clock),
+        )
         self.replica = DSSStore(storage="memory", clock=self.clock)
         self.follower = WalFollower(
             self.replica, self.wal_path, interval_s=0.005

@@ -236,6 +236,45 @@ def test_the_owner_of_a_front_names_its_handler_twice():
         (route, "handler_ms")][1:]
 
 
+def test_a_put_through_the_ring_keeps_its_lights(tmp_path, keypair):
+    """Behind a front a PUT rides the ring to the owner's write lane
+    (tests/test_ring_writes.py): the owner still marks every leg within
+    the request's `service_ms` and observes its handler, pickup to
+    answer, as `leader_handler_ms`; the worker's wait for the leader is
+    still `proxy_ms`; all on route class `write`.  No executor runs a
+    ring write, so `exec_wait_ms` is the one leaf it never marks."""
+    from dss_tpu.obs.metrics import route_class
+    from tests.test_ring_writes import OP, ROUTE, RingFront
+    from tests.test_ring_writes import _flight as _ring_flight
+
+    f = RingFront(tmp_path, keypair)
+    try:
+        assert f.put(OP.format(1), _ring_flight()).status_code == 200
+        r409 = f.put(OP.format(2), _ring_flight())
+        assert r409.status_code == 409, r409.text
+        key = [c["operation_reference"]["ovn"]
+               for c in r409.json()["entity_conflicts"]]
+        assert f.put(OP.format(2), _ring_flight(key)).status_code == 200
+        leader = {st: v for (r, st), v in
+                  f.leader_metrics.stage_hist_snapshot().items() if r == ROUTE}
+        worker = {st: v for (r, st), v in
+                  f.worker_metrics.stage_hist_snapshot().items() if r == ROUTE}
+        assert f.stats()["write_ring"] == 3
+    finally:
+        f.close()
+    assert route_class(ROUTE) == "write"
+    lit = set(LEAVES) - {"exec_wait_ms", "push_match_ms", "push_offer_ms"}
+    assert lit <= set(leader), lit - set(leader)
+    assert "exec_wait_ms" not in leader
+    _buckets, service_s, n = leader["service_ms"]
+    assert n == 3 and leader["leader_handler_ms"][2] == 3
+    assert sum(leader[name][1] for name in lit) <= service_s + 1e-6 * len(lit)
+    assert service_s <= leader["leader_handler_ms"][1]
+    # the worker's side: its wait for the leader, its own handler
+    assert worker["proxy_ms"][2] == 3 and worker["handler_ms"][2] == 3
+    assert "service_ms" not in worker
+
+
 def test_a_served_write_moves_the_journals_counters_by_its_records(flown):
     def moved(a, b, name):
         return b[name] - a[name]
