@@ -160,19 +160,24 @@ class _PushMixin:
     def _offer_push(self, trigger, entity, subs, *, removed=False,
                     emergency=False, alt_lo=None, alt_hi=None,
                     t_start=None, t_end=None) -> None:
-        """Hand the bumped subscriber set to the delivery pipeline —
-        post-journal, O(1) per subscriber (durable append + worker
-        wake); webhook I/O never runs on the write path."""
+        """Hand the bumped subscriber set to the delivery pipeline once
+        the transaction's journal records are durable (`_after_commit`)
+        — O(1) per subscriber (durable append + worker wake); webhook
+        I/O never runs on the write path."""
         push = self._push
         if push is None or not push.bound:
             return
-        with stages.stage("push_offer_ms", "push.offer"):
-            push.offer(
-                trigger, entity, subs, removed=removed,
-                emergency=emergency, alt_lo=alt_lo, alt_hi=alt_hi,
-                t_start_ns=None if t_start is None else to_nanos(t_start),
-                t_end_ns=None if t_end is None else to_nanos(t_end),
-            )
+
+        def offer():
+            with stages.stage("push_offer_ms", "push.offer"):
+                push.offer(
+                    trigger, entity, subs, removed=removed,
+                    emergency=emergency, alt_lo=alt_lo, alt_hi=alt_hi,
+                    t_start_ns=None if t_start is None else to_nanos(t_start),
+                    t_end_ns=None if t_end is None else to_nanos(t_end),
+                )
+
+        self._after_commit(offer)
 
 
 class _TxnTimeMixin:
@@ -204,9 +209,24 @@ class _TxnTimeMixin:
                 held.enter_context(self._txn())
             tl.now = to_nanos(self._clock.now())
             try:
-                yield
+                # the transaction's journal records go to the log as
+                # one append as it ends, the lock still held; what
+                # waits for them to be durable runs after that append
+                with self._journal_group() as after:
+                    tl.after = after
+                    yield
             finally:
-                tl.now = None
+                tl.now = tl.after = None
+
+    def _after_commit(self, fn) -> None:
+        """Run `fn` once this thread's transaction is durable: after
+        the outermost scope's journal append, and only if the scope
+        and the append both succeed; at once outside a transaction."""
+        after = getattr(self._txn_time, "after", None)
+        if after is None:
+            fn()
+        else:
+            after.append(fn)
 
     def _now_ns(self) -> int:
         pinned = getattr(self._txn_time, "now", None)
@@ -419,8 +439,9 @@ class OwnerInterner:
 
 class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
     def __init__(
-        self, *, clock, ts_oracle, owners, lock, journal, index_factory,
-        txn=None, capture_undo=False, cache=None, epoch_fn=None,
+        self, *, clock, ts_oracle, owners, lock, journal, journal_group,
+        index_factory, txn=None, capture_undo=False, cache=None,
+        epoch_fn=None,
     ):
         self._clock = clock
         self._ts = ts_oracle
@@ -428,6 +449,7 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
         self._lock = lock
         self._txn = txn if txn is not None else _lock_txn(lock)
         self._journal = journal
+        self._journal_group = journal_group
         self._index_factory = index_factory
         # region mode: each journal record carries an "undo" list (wal
         # records that revert the mutation) so the coordinator can roll
@@ -766,8 +788,9 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         return self._cst_index.stats()
 
     def __init__(
-        self, *, clock, ts_oracle, owners, lock, journal, index_factory,
-        txn=None, capture_undo=False, cache=None, epoch_fn=None,
+        self, *, clock, ts_oracle, owners, lock, journal, journal_group,
+        index_factory, txn=None, capture_undo=False, cache=None,
+        epoch_fn=None,
     ):
         self._clock = clock
         self._ts = ts_oracle
@@ -775,6 +798,7 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         self._lock = lock
         self._txn = txn if txn is not None else _lock_txn(lock)
         self._journal = journal
+        self._journal_group = journal_group
         self._index_factory = index_factory
         self._capture_undo = capture_undo
         self._init_txn_time()
@@ -1029,7 +1053,7 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
 
     def _notify_subs_locked(
         self, cells, *, trigger: str = "operations",
-        alt_lo=None, alt_hi=None, t_start=None, t_end=None,
+        alt_lo=None, alt_hi=None, t_start=None, t_end=None, ids=None,
     ) -> List[scdm.Subscription]:
         """Bump + return live subscriptions intersecting cells whose
         notification trigger matches the writing entity class
@@ -1046,17 +1070,20 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         a reverse query — instead of the read-side coalescer; the
         MatchStage contract keeps the id set bit-identical, so the
         returned subscriber list (and the response built from it)
-        cannot change."""
-        ids = self._push_match_ids(
-            "scd_sub", cells, alt_lo=alt_lo, alt_hi=alt_hi,
-            t_start_ns=None if t_start is None else to_nanos(t_start),
-            t_end_ns=None if t_end is None else to_nanos(t_end),
-        )
+        cannot change.  A caller that already holds the match's answer
+        (upsert_operation_with_subscription) hands it in as `ids`."""
+        if ids is None:
+            ids = self._push_match_ids(
+                "scd_sub", cells, alt_lo=alt_lo, alt_hi=alt_hi,
+                t_start_ns=None if t_start is None else to_nanos(t_start),
+                t_end_ns=None if t_end is None else to_nanos(t_end),
+            )
         want_constraints = trigger == "constraints"
         out = []
         undo = []
         # the bump and its journal record: O(matched) under the write
-        # lock (stage sub_bump_ms; dss.sub.bump on a capture)
+        # lock (stage sub_bump_ms; dss.sub.bump on a capture; the
+        # record is appended with the transaction's others as it ends)
         with stages.stage("sub_bump_ms", "sub.bump"):
             for i in sorted(ids):
                 prev = self._subs.get(i)
@@ -1161,42 +1188,91 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
             old = self._precheck_op_upsert(
                 op, key, check_key=not key_checked
             )
-            ts = self._ts.commit_ts()
-            stored = dataclasses.replace(
-                op,
-                version=(old.version if old else 0) + 1,
-                ovn=new_ovn_from_time(ts, op.id),
+            return self._notify_op_locked(self._put_op_locked(op, old))
+
+    def upsert_operation_with_subscription(
+        self, op, key, sub, *, key_checked: bool = False
+    ):
+        """A planned flight's 200 as one transaction: `sub`, the new
+        implicit subscription that `op` rides (op.subscription_id, the
+        op's cells, notify_for_operations), then the op.  The same as
+        upsert_subscription followed by upsert_operation, with the
+        subscription table read once: the quota's count and the
+        subscribers' match read one volume (the flight's cells, live
+        at the pinned now, no altitude or time filter) but for the
+        owner filter, and between them the table changes only by
+        `sub`.  So the match's answer, taken before `sub` is written,
+        gives the owner's count per cell (DSS0030, refused before
+        anything is written), and with `sub`, where it is live, the
+        subscribers.  The subscription's `affected` search is not
+        run: no caller reads it here."""
+        with self._txn_scope():
+            old = self._precheck_op_upsert(
+                op, key, check_key=not key_checked
             )
-            if self._capture_undo:
-                # exact inverse: restore whatever the id maps to NOW,
-                # including an expired (invisible) record `old` misses
-                prev_raw = self._ops.get(op.id)
-                undo = [
-                    {"t": "scd_op_put", "doc": codec.op_to_doc(prev_raw)}
-                    if prev_raw is not None
-                    else {"t": "scd_op_del", "id": stored.id}
-                ]
-            self._ops[stored.id] = stored
-            # the scd_op table's overlay splice, its wait for the
-            # table's write lock (a fold's swap holds it) included
-            with stages.stage("op_index_ms", "write.op_index"):
-                self._index_op(stored)
-            rec = {"t": "scd_op_put", "doc": codec.op_to_doc(stored)}
-            if self._capture_undo:
-                rec["undo"] = undo
-            self._journal(rec)
-            subs = self._notify_subs_locked(stored.cells)
-            self._offer_push(
-                "operations", stored, subs,
-                emergency=stored.state in (
-                    scdm.OperationState.NON_CONFORMING,
-                    scdm.OperationState.CONTINGENT,
-                ),
-                alt_lo=stored.altitude_lower,
-                alt_hi=stored.altitude_upper,
-                t_start=stored.start_time, t_end=stored.end_time,
+            old_sub = self._precheck_sub_upsert(sub)
+            ids = set(self._push_match_ids("scd_sub", op.cells))
+            # the quota counted from the answer (the owner's own by
+            # their records here, each one's cells by the table's),
+            # the new version's record and the scd_sub splice: stage
+            # sub_index_ms
+            with stages.stage("sub_index_ms", "write.sub_index"):
+                subs = self._subs
+                mine = [i for i in ids if i in subs
+                        and subs[i].owner == sub.owner]
+                stored_sub = self._put_sub_locked(
+                    sub, old_sub,
+                    self._sub_index.max_count_of(mine, sub.cells),
+                )
+            if self._visible_sub(stored_sub.id) is not None:
+                ids.add(stored_sub.id)
+            return self._notify_op_locked(
+                self._put_op_locked(op, old), ids=ids
             )
-            return dataclasses.replace(stored), subs
+
+    def _put_op_locked(self, op, old):
+        """The op's new version: record, overlay splice, journal."""
+        ts = self._ts.commit_ts()
+        stored = dataclasses.replace(
+            op,
+            version=(old.version if old else 0) + 1,
+            ovn=new_ovn_from_time(ts, op.id),
+        )
+        if self._capture_undo:
+            # exact inverse: restore whatever the id maps to NOW,
+            # including an expired (invisible) record `old` misses
+            prev_raw = self._ops.get(op.id)
+            undo = [
+                {"t": "scd_op_put", "doc": codec.op_to_doc(prev_raw)}
+                if prev_raw is not None
+                else {"t": "scd_op_del", "id": stored.id}
+            ]
+        self._ops[stored.id] = stored
+        # the scd_op table's overlay splice, its wait for the
+        # table's write lock (a fold's swap holds it) included
+        with stages.stage("op_index_ms", "write.op_index"):
+            self._index_op(stored)
+        rec = {"t": "scd_op_put", "doc": codec.op_to_doc(stored)}
+        if self._capture_undo:
+            rec["undo"] = undo
+        self._journal(rec)
+        return stored
+
+    def _notify_op_locked(self, stored, ids=None):
+        """The op's subscribers bumped and offered -> (a copy of the
+        op, the subscribers)."""
+        subs = self._notify_subs_locked(stored.cells, ids=ids)
+        self._offer_push(
+            "operations", stored, subs,
+            emergency=stored.state in (
+                scdm.OperationState.NON_CONFORMING,
+                scdm.OperationState.CONTINGENT,
+            ),
+            alt_lo=stored.altitude_lower,
+            alt_hi=stored.altitude_upper,
+            t_start=stored.start_time, t_end=stored.end_time,
+        )
+        return dataclasses.replace(stored), subs
 
     def delete_operation(self, id, owner):
         with self._txn_scope():
@@ -1351,56 +1427,18 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
 
     def upsert_subscription(self, sub):
         with self._txn_scope():
-            old = self._visible_sub(sub.id)
-            if old is None and sub.version != 0:
-                raise errors.not_found(sub.id)
-            if old is not None and sub.version == 0:
-                raise errors.already_exists(sub.id)
-            if old is not None and sub.version != old.version:
-                raise errors.version_mismatch("old version")
-            if old is not None and old.owner != sub.owner:
-                raise errors.permission_denied(
-                    f"Subscription is owned by {old.owner}"
-                )
+            old = self._precheck_sub_upsert(sub)
             # the quota count, the new version's record and the scd_sub
             # table's overlay splice: stage sub_index_ms
             with stages.stage("sub_index_ms", "write.sub_index"):
-                count = self._sub_index.max_owner_count(
-                    sub.cells, self._owners.intern(sub.owner),
-                    now=self._now_ns(),
+                stored = self._put_sub_locked(
+                    sub, old,
+                    self._sub_index.max_owner_count(
+                        sub.cells, self._owners.intern(sub.owner),
+                        now=self._now_ns(),
+                    ),
                 )
-                if count >= MAX_SCD_SUBSCRIPTIONS_PER_AREA:
-                    msg = (
-                        "too many existing subscriptions in this area "
-                        "already"
-                    )
-                    if old is not None:
-                        msg += ", rejecting update request"
-                    raise errors.exhausted(msg)
-                stored = dataclasses.replace(
-                    sub, version=(old.version if old else 0) + 1
-                )
-                if self._capture_undo:
-                    # exact inverse: raw get includes an expired
-                    # (invisible) record that `old` (visibility-
-                    # filtered) misses
-                    prev_raw = self._subs.get(sub.id)
-                    undo = [
-                        {
-                            "t": "scd_sub_put",
-                            "doc": codec.scd_sub_to_doc(prev_raw),
-                        }
-                        if prev_raw is not None
-                        else {"t": "scd_sub_del", "id": stored.id}
-                    ]
-                self._subs[stored.id] = stored
-                self._index_scd_sub(stored)
-            rec = {"t": "scd_sub_put", "doc": codec.scd_sub_to_doc(stored)}
-            if self._capture_undo:
-                rec["undo"] = undo
-            self._journal(rec)
-            # the operations the subscription's volume meets (a
-            # planned flight's put_operation discards them)
+            # the operations the subscription's volume meets
             with stages.stage("sub_affected_ms", "write.sub_affected"):
                 affected = (
                     self._search_ops(
@@ -1414,6 +1452,51 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
                     else []
                 )
             return dataclasses.replace(stored), affected
+
+    def _precheck_sub_upsert(self, sub):
+        """A subscription upsert's version fencing and ownership, no
+        mutation -> the visible old version (or None)."""
+        old = self._visible_sub(sub.id)
+        if old is None and sub.version != 0:
+            raise errors.not_found(sub.id)
+        if old is not None and sub.version == 0:
+            raise errors.already_exists(sub.id)
+        if old is not None and sub.version != old.version:
+            raise errors.version_mismatch("old version")
+        if old is not None and old.owner != sub.owner:
+            raise errors.permission_denied(
+                f"Subscription is owned by {old.owner}"
+            )
+        return old
+
+    def _put_sub_locked(self, sub, old, count: int):
+        """The subscription's new version: refused at the DSS0030
+        quota (`count`, the owner's most live subscriptions in one of
+        its cells), else record, overlay splice, journal."""
+        if count >= MAX_SCD_SUBSCRIPTIONS_PER_AREA:
+            msg = "too many existing subscriptions in this area already"
+            if old is not None:
+                msg += ", rejecting update request"
+            raise errors.exhausted(msg)
+        stored = dataclasses.replace(
+            sub, version=(old.version if old else 0) + 1
+        )
+        if self._capture_undo:
+            # exact inverse: raw get includes an expired (invisible)
+            # record that `old` (visibility-filtered) misses
+            prev_raw = self._subs.get(sub.id)
+            undo = [
+                {"t": "scd_sub_put", "doc": codec.scd_sub_to_doc(prev_raw)}
+                if prev_raw is not None
+                else {"t": "scd_sub_del", "id": stored.id}
+            ]
+        self._subs[stored.id] = stored
+        self._index_scd_sub(stored)
+        rec = {"t": "scd_sub_put", "doc": codec.scd_sub_to_doc(stored)}
+        if self._capture_undo:
+            rec["undo"] = undo
+        self._journal(rec)
+        return stored
 
     def delete_subscription(self, id, owner, version):
         with self._txn_scope():
@@ -1572,6 +1655,9 @@ class DSSStore:
             sink=self._boot_resolver and self._boot_resolver.consume,
         )
         self._lock = threading.RLock()
+        # the open journal group of this thread's transaction
+        # (_journal_group)
+        self._group = threading.local()
         self.region = None
         txn = None
         epoch_fn = None
@@ -1607,6 +1693,7 @@ class DSSStore:
             owners=owners,
             lock=self._lock,
             journal=self._journal,
+            journal_group=self._journal_group,
             index_factory=index_factory,
             txn=txn,
             capture_undo=bool(region_url),
@@ -1619,6 +1706,7 @@ class DSSStore:
             owners=owners,
             lock=self._lock,
             journal=self._journal,
+            journal_group=self._journal_group,
             index_factory=index_factory,
             txn=txn,
             capture_undo=bool(region_url),
@@ -1694,13 +1782,47 @@ class DSSStore:
     def _journal(self, rec: dict):
         if self._replaying:
             return
-        # stage wal_commit_ms, accumulated over a request's records
-        # (inside sub.bump: the span alone, sub_bump_ms holds it)
+        if self.region is not None:
+            # the coordinator batches a region txn's records itself
+            self.region.journal(rec)
+            return
+        group = getattr(self._group, "records", None)
+        if group is not None:
+            group.append(rec)
+        else:
+            self._append(rec)
+
+    def _append(self, *records: dict) -> None:
+        # stage wal_commit_ms: the one append of a transaction's
+        # records, write, flush and fsync
         with stages.stage("wal_commit_ms", "wal.commit"):
-            if self.region is not None:
-                self.region.journal(rec)
-            else:
-                self.wal.append(rec)
+            self.wal.append(*records)
+
+    @contextlib.contextmanager
+    def _journal_group(self):
+        """One transaction's journal (both sub-stores' `_txn_scope`,
+        outermost entry, the store's lock held): the records
+        `_journal` is handed inside it go to the WAL as ONE append as
+        it ends, an exception's end included, so the log never holds
+        less than memory and a transaction's records are durable
+        together.  Yields the list of what waits for them to be
+        durable (`_after_commit`: push offers), run after the append
+        and only if the transaction and the append both succeed.  An
+        entry inside an open group joins it."""
+        tl = self._group
+        if getattr(tl, "records", None) is not None:
+            yield tl.after
+            return
+        records, after = [], []
+        tl.records, tl.after = records, after
+        try:
+            yield after
+        finally:
+            tl.records = tl.after = None
+            if records:
+                self._append(*records)
+        for fn in after:
+            fn()
 
     def apply_log_record(self, rec: dict) -> None:
         """Apply one WAL/region-log record to the right sub-store

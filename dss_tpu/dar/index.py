@@ -101,6 +101,15 @@ class MemorySpatialIndex:
         recs = {i: r for i, r in enumerate(self._recs.values())}
         return oracle.max_count_per_cell(recs, keys, owner_id, now)
 
+    def max_count_of(self, ids, cells_u64) -> int:
+        """DarTable.max_count_of's twin: the most of `ids` (live
+        answers of a query over the cells) that hold one cell."""
+        held = [set(self._recs[i].keys.tolist()) for i in ids
+                if i in self._recs]
+        return max((sum(k in s for s in held)
+                    for k in np.unique(_to_keys(cells_u64)).tolist()),
+                   default=0)
+
     def stats(self) -> dict:
         return {
             "live_records": len(self._recs),
@@ -160,6 +169,9 @@ class TpuSpatialIndex:
         return self._table.max_owner_count(
             _to_keys(cells_u64), owner_id, now=int(now)
         )
+
+    def max_count_of(self, ids, cells_u64) -> int:
+        return self._table.max_count_of(ids, _to_keys(cells_u64))
 
     @property
     def cell_clock(self) -> tiersmod.CellClock:

@@ -957,6 +957,20 @@ class DarTable:
             owner_ids=np.asarray([owner_id], np.int32),
             state=st,
         )[0]
+        return self._max_count_per_key(st, ids, qk)
+
+    def max_count_of(self, ids, keys: np.ndarray) -> int:
+        """The per-key count of max_owner_count over records in hand:
+        the most of `ids` (live answers of a query over `keys`, e.g.
+        one owner's among a write's subscriber match) that hold one
+        key of `keys`."""
+        qk = np.unique(np.asarray(keys, np.int32).ravel())
+        if len(qk) == 0:
+            return 0
+        return self._max_count_per_key(self._state, ids, qk)
+
+    @staticmethod
+    def _max_count_per_key(st, ids, qk: np.ndarray) -> int:
         counts = {int(k): 0 for k in qk}
         for eid in ids:
             rec = st.pending.get(eid) or tiersmod.resolve_record(

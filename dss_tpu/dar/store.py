@@ -151,6 +151,16 @@ class SCDStore(abc.ABC):
         transaction (the pinned txn timestamp keeps answers equal)."""
 
     @abc.abstractmethod
+    def upsert_operation_with_subscription(
+        self, op: scdm.Operation, key: List[str], sub: scdm.Subscription,
+        *, key_checked: bool = False,
+    ) -> Tuple[scdm.Operation, List[scdm.Subscription]]:
+        """upsert_subscription(sub) then upsert_operation(op, key) as
+        one transaction, for an op that rides the new implicit
+        subscription `sub` (op.subscription_id == sub.id, the op's
+        cells); returns what upsert_operation returns."""
+
+    @abc.abstractmethod
     def validate_operation_upsert(self, op: scdm.Operation, key: List[str]) -> None:
         """Read-only run of upsert_operation's preconditions (version
         fencing, ownership, time range, OVN key check).  Must be called

@@ -147,9 +147,11 @@ class WriteAheadLog:
         self.recovered_truncation = False
         # what the journal cost since the process started, counted
         # where no request owns the work (under _lock; stats()):
-        # records, their bytes, fsyncs, and the seconds of the whole
-        # append and of os.fsync alone.  Recovery and replay move none.
+        # appends and the records they wrote, their bytes, fsyncs, and
+        # the seconds of the whole append and of os.fsync alone.
+        # Recovery and replay move none.
         self.appends = 0
+        self.records = 0
         self.bytes = 0
         self.fsyncs = 0
         self.append_s = 0.0
@@ -232,23 +234,32 @@ class WriteAheadLog:
         """Last assigned sequence number (leader-side freshness stamp)."""
         return self._seq
 
-    def append(self, record: dict) -> int:
+    def append(self, *records: dict) -> int:
+        """Write `records` as consecutive lines with consecutive seqs:
+        one write, one flush and (fsync on) one fsync for all of them,
+        so a transaction's records are durable together.  -> the last
+        record's seq."""
         with self._lock:
             t0 = time.perf_counter()
             # chaos seam BEFORE the seq assignment/write: an injected
             # append error leaves no half-recorded state, and a delay
             # models a slow disk stalling the writer
             fault_point("wal.append")
-            self._seq += 1
-            record = dict(record, seq=self._seq)
+            first = self._seq + 1
+            self._seq += len(records)
             if self._fh is not None:
-                line = json.dumps(record, separators=(",", ":")) + "\n"
-                self._fh.write(line)
+                data = "".join(
+                    json.dumps(dict(rec, seq=seq), separators=(",", ":"))
+                    + "\n"
+                    for seq, rec in enumerate(records, first)
+                )
+                self._fh.write(data)
                 self._fh.flush()
-                self.bytes += len(line)  # ensure_ascii: chars are bytes
+                self.bytes += len(data)  # ensure_ascii: chars are bytes
                 if self.fsync:
                     self._fsync_locked()
             self.appends += 1
+            self.records += len(records)
             self.append_s += time.perf_counter() - t0
             return self._seq
 
@@ -276,6 +287,7 @@ class WriteAheadLog:
         with self._lock:
             return {
                 "dss_wal_appends_total": self.appends,
+                "dss_wal_records_total": self.records,
                 "dss_wal_bytes_total": self.bytes,
                 "dss_wal_fsyncs_total": self.fsyncs,
                 "dss_wal_append_seconds_total": round(self.append_s, 6),

@@ -1325,6 +1325,13 @@ class FederatedSCDStore(SCDStore):
             op, key, key_checked=key_checked
         )
 
+    def upsert_operation_with_subscription(self, op, key, sub, *,
+                                           key_checked=False):
+        self._router.check_write(op.cells)
+        return self._local.upsert_operation_with_subscription(
+            op, key, sub, key_checked=key_checked
+        )
+
     def delete_operation(self, id, owner):
         return self._local.delete_operation(id, owner)
 

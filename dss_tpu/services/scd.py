@@ -212,9 +212,17 @@ class SCDService:
                 # routine outcome and must leave nothing to roll back.
                 self.store.validate_operation_upsert(op, key)
 
-            if not subscription_id:
-                sub, _ = self.store.upsert_subscription(
-                    scdm.Subscription(
+            with conflict_details():
+                # key_checked: the OVN search already ran in this txn
+                # scope (pinned timestamp -> same visibility answers)
+                if subscription_id:
+                    stored, subs = self.store.upsert_operation(
+                        op, key, key_checked=True
+                    )
+                else:
+                    # the implicit subscription and the op: one store
+                    # call, one read of the subscription table
+                    sub = scdm.Subscription(
                         id=str(uuidlib.uuid4()),
                         owner=owner,
                         start_time=u_extent.start_time,
@@ -229,15 +237,12 @@ class SCDService:
                         ),
                         implicit_subscription=True,
                     )
-                )
-                op.subscription_id = sub.id
-
-            with conflict_details():
-                # key_checked: the OVN search already ran in this txn
-                # scope (pinned timestamp -> same visibility answers)
-                stored, subs = self.store.upsert_operation(
-                    op, key, key_checked=True
-                )
+                    op.subscription_id = sub.id
+                    stored, subs = (
+                        self.store.upsert_operation_with_subscription(
+                            op, key, sub, key_checked=True
+                        )
+                    )
         with stages.stage("serialize_ms", "write.body"):
             return {
                 "operation_reference": ser.op_to_json(stored),

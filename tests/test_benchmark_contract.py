@@ -219,9 +219,42 @@ def _exec_hops_without_the_ring(monkeypatch) -> bool:
         store.close()
 
 
+def _affected_on_a_subscription_put(monkeypatch) -> bool:
+    """Stage `sub_affected_ms`: a subscription PUT's closing search for
+    the operations its volume meets (`upsert_subscription`, route class
+    `write`).  A planned flight makes its implicit subscription in one
+    transaction with the op and runs no such search, so the flights
+    this boot files never mark it.  Checked where it is marked."""
+    import numpy as np
+    from datetime import datetime, timedelta, timezone
+
+    from dss_tpu.dar.dss_store import DSSStore
+    from dss_tpu.geo import s2cell
+    from dss_tpu.models import scd as scdm
+    from dss_tpu.obs import stages
+    from dss_tpu.obs.metrics import stage_name
+
+    store = DSSStore(storage="memory")
+    t0 = datetime.now(timezone.utc)
+    cell = s2cell.cell_parent(s2cell.cell_id_from_latlng(40.0, -100.0, 30), 13)
+    sink = {}
+    stages.set_sink(sink)
+    try:
+        store.scd.upsert_subscription(scdm.Subscription(
+            id="00000000-0000-4000-8000-000000000001", owner="uss1",
+            start_time=t0, end_time=t0 + timedelta(hours=1),
+            cells=np.asarray([cell], np.uint64)))
+    finally:
+        stages.set_sink(None)
+        store.close()
+    return "sub_affected_ms" in sink and stage_name(
+        "sub_affected_ms") != "other"
+
+
 # stages this boot's deployment never runs, and where each is checked
 STAGES_ELSEWHERE = {"sub_match_ms": _match_without_a_pipeline,
-                    "exec_wait_ms": _exec_hops_without_the_ring}
+                    "exec_wait_ms": _exec_hops_without_the_ring,
+                    "sub_affected_ms": _affected_on_a_subscription_put}
 
 
 @pytest.mark.parametrize("metric", _metric_files())

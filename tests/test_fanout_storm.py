@@ -204,19 +204,20 @@ def storm(request, tmp_path_factory):
     try:
         for fid, owner, flat, lo, hi, t0, t1 in seeded_flights(rng):
             # as services/scd.py put_operation: the implicit
-            # subscription first, then the op intent under it
-            sub, _ = store.scd.upsert_subscription(model_sub(
-                uuid_of(3, len(returned)), owner, flat, t0, t1, True,
-                implicit=True))
+            # subscription and the op intent under it, one transaction
+            sub = model_sub(uuid_of(3, len(returned)), owner, flat, t0, t1,
+                            True, implicit=True)
             ref.add(sub.id, owner, flat, t1, True)
             want = ref.notified_by(flat, T0)
             for sid in want:
                 ref.index[sid] += 1
-            _, subs = store.scd.upsert_operation(scdm.Operation(
-                id=fid, owner=owner, start_time=t0, end_time=t1,
-                altitude_lower=lo, altitude_upper=hi,
-                state=scdm.OperationState.ACCEPTED, cells=CELLS[flat],
-                subscription_id=sub.id), [], key_checked=True)
+            _, subs = store.scd.upsert_operation_with_subscription(
+                scdm.Operation(
+                    id=fid, owner=owner, start_time=t0, end_time=t1,
+                    altitude_lower=lo, altitude_upper=hi,
+                    state=scdm.OperationState.ACCEPTED, cells=CELLS[flat],
+                    subscription_id=sub.id),
+                [], sub, key_checked=True)
             returned.append({s.id: s.notification_index for s in subs})
             expected.append(want)
     finally:

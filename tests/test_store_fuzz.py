@@ -243,12 +243,17 @@ def test_backends_agree_under_random_ops(seed, monkeypatch):
                 )
                 for n in stores
             }
-        elif op == 2:  # RID search
+        elif op == 2:  # RID search, polled twice as a display provider does
             area = _search_area(rng)
             outs = {
                 n: _norm_outcome(rid[n].search_isas, area)
                 for n in stores
             }
+            # nothing wrote in between: the repeat is the cached
+            # stores' read cache, and it answers what the first did
+            for n in stores:
+                again = _norm_outcome(rid[n].search_isas, area)
+                assert again == outs[n], (step, n, again, outs[n])
         elif op == 3:  # SCD op put (no key -> may 409-conflict)
             ext = _extents(rng)  # ONE draw: coherent volume + window
             body = {
