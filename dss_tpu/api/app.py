@@ -587,7 +587,8 @@ def make_worker_proxy_middleware(leader_url: str, follower=None,
     is authenticated here with `authorizer`, the one the leader holds,
     and crosses to the owner's write lane; it takes the proxy only
     where the owner never saw it (ShmFallback), and the worker's wait
-    for the leader is stage `proxy_ms` by either transport."""
+    for the leader is stage `proxy_ms` by either transport, and its
+    replica's catch-up after a success stage `catchup_ms`."""
     import aiohttp as _aiohttp
 
     from dss_tpu.dar.shmfront import ShmFallback
@@ -604,14 +605,15 @@ def make_worker_proxy_middleware(leader_url: str, follower=None,
         body = await request.read()
         route_dl = request.get("dss_deadline")
         th = _trace_handle(request)
+        loop = asyncio.get_running_loop()
         try:
-            status, payload, wait_ms = await asyncio.get_running_loop(
-            ).run_in_executor(None, functools.partial(
-                ring.write, index, request.match_info[key], owner, body,
-                deadline_s=None if route_dl is None
-                else route_dl - time.monotonic(),
-                th=th,
-            ))
+            status, payload, wait_ms, catchup_ms = await loop.run_in_executor(
+                None, functools.partial(
+                    ring.write, index, request.match_info[key], owner, body,
+                    deadline_s=None if route_dl is None
+                    else route_dl - time.monotonic(),
+                    th=th,
+                ))
         except ShmFallback as e:
             # dss_shm_worker_write_proxied counts it; the log says why
             log.info("write took the loopback proxy: %s", e.reason)
@@ -619,6 +621,8 @@ def make_worker_proxy_middleware(leader_url: str, follower=None,
         sink = request.get("dss_stages")
         if sink is not None:
             sink["proxy_ms"] = round(sink.get("proxy_ms", 0.0) + wait_ms, 3)
+            if catchup_ms is not None:
+                sink["catchup_ms"] = round(catchup_ms, 3)
         return web.Response(
             body=payload, status=status, content_type="application/json"
         )
@@ -709,9 +713,13 @@ def make_worker_proxy_middleware(leader_url: str, follower=None,
             and upstream.status < 400
         ):
             loop = asyncio.get_running_loop()
+            t_cu0 = time.perf_counter()
             await loop.run_in_executor(
                 None, functools.partial(follower.wait_for, int(seq), 1.0)
             )
+            if sink is not None:
+                sink["catchup_ms"] = round(
+                    (time.perf_counter() - t_cu0) * 1000.0, 3)
         return web.Response(
             body=payload,
             status=upstream.status,

@@ -31,7 +31,6 @@ by the loop and says so.
 
 from __future__ import annotations
 
-import dataclasses
 import time
 from typing import Callable, Dict, Iterable, List, NamedTuple
 
@@ -43,8 +42,35 @@ from dss_tpu.dar.oracle import Record
 from dss_tpu.dar.wal import FORMAT_RECORD_TYPE
 from dss_tpu.geo import s2cell
 from dss_tpu.runtime import gc_paused
+from dss_tpu.services.serialization import _WIRE
 
 _PUT, _DEL, _BUMP = range(3)
+
+
+def bump_sub(subs: Dict[str, object], sub_id: str):
+    """The next notification index of subscription `sub_id`: a new
+    record, equal to the stored one but for the index, stored under
+    the id and returned; None if the id is absent.  The one way a
+    bump is made, on the leader's notify paths, in a replica's
+    `apply_wal` and in a bulk boot.
+
+    Copy-on-write: a lock-free reader that holds the old record keeps
+    the old index, since no field of a stored record is ever assigned.
+    The copy is shallow, its fields taken as they are with no
+    `__init__` run, and it leaves out the old record's remembered wire
+    bytes (services/serialization.py `_WIRE`), which its own first
+    answer makes anew."""
+    sub = subs.get(sub_id)
+    if sub is None:
+        return None
+    fields = dict(sub.__dict__)
+    fields.pop(_WIRE, None)
+    fields["notification_index"] = sub.notification_index + 1
+    bumped = object.__new__(type(sub))
+    bumped.__dict__ = fields
+    subs[sub_id] = bumped
+    return bumped
+
 
 class _Class(NamedTuple):
     """Where one entity class lives in a DSSStore."""
@@ -142,14 +168,7 @@ class Resolver:
                     else:
                         models = live[cls]
                         for i in rec["ids"]:
-                            m = models.get(i)
-                            if m is not None:
-                                models[i] = dataclasses.replace(
-                                    m,
-                                    notification_index=(
-                                        m.notification_index + 1
-                                    ),
-                                )
+                            bump_sub(models, i)
                 except Exception as e:  # noqa: BLE001 — the loop decides
                     self.refused = f"{type(e).__name__}: {e}"
                     self.live = {c: {} for c in _CLASSES}  # free it now

@@ -105,20 +105,6 @@ def _lock_txn(lock):
     return txn
 
 
-def _bump_sub(subs: Dict[str, object], sub_id: str):
-    """Copy-on-write notification-index bump: replaces the stored record
-    (lock-free readers may hold a reference to the current object).
-    Returns the bumped record, or None if absent."""
-    sub = subs.get(sub_id)
-    if sub is None:
-        return None
-    bumped = dataclasses.replace(
-        sub, notification_index=sub.notification_index + 1
-    )
-    subs[sub_id] = bumped
-    return bumped
-
-
 class _PushMixin:
     """Reverse-query push wiring shared by both sub-stores
     (dss_tpu/push/): DSSStore.attach_push hands the pipeline to the
@@ -722,7 +708,8 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
 
     def update_notification_idxs_in_cells(self, cells, *, entity=None,
                                           removed=False):
-        """Bump + return RID subscriptions intersecting cells.  The
+        """Bump + return RID subscriptions intersecting cells: the
+        stored records, which callers only read (boot.bump_sub).  The
         service passes the triggering ISA as `entity` so an attached
         push pipeline can fan the bump out as deliveries; without a
         pipeline the extra args are inert."""
@@ -737,9 +724,9 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
                         undo.append(
                             {"t": "rid_sub_put", "doc": codec.rid_sub_to_doc(prev)}
                         )
-                bumped = _bump_sub(self._subs, i)
+                bumped = boot.bump_sub(self._subs, i)
                 if bumped is not None:
-                    out.append(dataclasses.replace(bumped))
+                    out.append(bumped)
             if out:
                 rec = {"t": "rid_sub_bump", "ids": [s.id for s in out]}
                 if self._capture_undo:
@@ -768,7 +755,7 @@ class RIDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, RIDStore):
             self._sub_index.remove(rec["id"])
         elif t == "rid_sub_bump":
             for i in rec["ids"]:
-                _bump_sub(self._subs, i)
+                boot.bump_sub(self._subs, i)
 
 
 class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
@@ -1071,7 +1058,11 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
         MatchStage contract keeps the id set bit-identical, so the
         returned subscriber list (and the response built from it)
         cannot change.  A caller that already holds the match's answer
-        (upsert_operation_with_subscription) hands it in as `ids`."""
+        (upsert_operation_with_subscription) hands it in as `ids`.
+
+        The records returned are the stored ones, one new object each
+        (boot.bump_sub): the response and the push offer read them and
+        never assign a field."""
         if ids is None:
             ids = self._push_match_ids(
                 "scd_sub", cells, alt_lo=alt_lo, alt_hi=alt_hi,
@@ -1099,9 +1090,7 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
                         "t": "scd_sub_put",
                         "doc": codec.scd_sub_to_doc(prev),
                     })
-                bumped = _bump_sub(self._subs, i)
-                if bumped is not None:
-                    out.append(dataclasses.replace(bumped))
+                out.append(boot.bump_sub(self._subs, i))
             if out:
                 rec = {"t": "scd_sub_bump", "ids": [s.id for s in out]}
                 if self._capture_undo:
@@ -1581,7 +1570,7 @@ class SCDStoreImpl(_PushMixin, _TxnTimeMixin, _CachedSearchMixin, SCDStore):
             self._sub_index.remove(rec["id"])
         elif t == "scd_sub_bump":
             for i in rec["ids"]:
-                _bump_sub(self._subs, i)
+                boot.bump_sub(self._subs, i)
         elif t == "scd_cst_put":
             cst = codec.doc_to_constraint(rec["doc"])
             self._csts[cst.id] = cst

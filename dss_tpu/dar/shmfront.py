@@ -277,12 +277,8 @@ class ShmSearchFront:
             # replica catchup: assemble records at least as new as the
             # answer (bounded — a timeout proceeds with the replica's
             # bounded staleness, same contract as the write proxy)
-            t_cu_w, t_cu0 = time.time_ns(), time.perf_counter()
-            self.follower.wait_for(int(resp.wal_seq), self.catchup_s)
-            cu_ms = (time.perf_counter() - t_cu0) * 1000.0
-            _stages.mark("catchup_ms", cu_ms, span=False)
-            if th is not None:
-                _trace.add_span(th, "replica.catchup", t_cu_w, cu_ms)
+            _stages.mark("catchup_ms", self._catch_up(resp.wal_seq, th),
+                         span=False)
         if use_cache and not resp.mesh_served:
             # a bounded-stale mesh answer must not be stamped fresh
             # behind the fence (the fence cannot see the replica's
@@ -302,16 +298,18 @@ class ShmSearchFront:
 
     def write(self, route: int, entity: str, owner: str, body: bytes, *,
               deadline_s: Optional[float] = None,
-              th=None) -> Tuple[int, bytes, float]:
+              th=None) -> Tuple[int, bytes, float, Optional[float]]:
         """One mutation through the owner's write lane (blocks: run it
         on the executor) -> (HTTP status, body, ms waited for the
-        leader).  Raises ShmFallback only where the owner never saw
-        the request (owner's heartbeat stale, ring full, a request
-        larger than a slot, an injected enqueue fault); a write it was
-        handed is never sent again, and a wait that ends without its
-        answer is a 504 (or a 503 where the owner took the slot back).
-        After a success the replica catches up to the commit, as after
-        a proxied write."""
+        leader, ms waited for the replica or None).  Raises ShmFallback
+        only where the owner never saw the request (owner's heartbeat
+        stale, ring full, a request larger than a slot, an injected
+        enqueue fault); a write it was handed is never sent again, and
+        a wait that ends without its answer is a 504 (or a 503 where
+        the owner took the slot back).  After a success the replica
+        catches up to the commit, as after a proxied write: the
+        worker's read-your-writes wait, whose milliseconds are the
+        fourth element (None where no success asked for it)."""
         client = self.client
         age_s = self.region.owner_heartbeat_age_s()
         if age_s >= self.owner_ttl_s:
@@ -355,9 +353,21 @@ class ShmSearchFront:
                                             "in the write lane")
                    if resp.status == shmring.ST_DEADLINE else
                    errors.internal("the write lane failed the request"))
+        catchup_ms = None
         if resp.status < 400 and resp.wal_seq:
-            self.follower.wait_for(int(resp.wal_seq), self.catchup_s)
-        return resp.status, resp.body, wait_ms
+            catchup_ms = self._catch_up(resp.wal_seq, th)
+        return resp.status, resp.body, wait_ms, catchup_ms
+
+    def _catch_up(self, wal_seq, th) -> float:
+        """Wait (bounded by `catchup_s`) for the replica to apply the
+        journal up to `wal_seq` -> the milliseconds waited; a span
+        `replica.catchup` of a sampled request."""
+        t_cu_w, t_cu0 = time.time_ns(), time.perf_counter()
+        self.follower.wait_for(int(wal_seq), self.catchup_s)
+        cu_ms = (time.perf_counter() - t_cu0) * 1000.0
+        if th is not None:
+            _trace.add_span(th, "replica.catchup", t_cu_w, cu_ms)
+        return cu_ms
 
     @staticmethod
     def _stitch_owner_spans(th, ring_sid, trace_ns, claim_w: int,

@@ -278,6 +278,47 @@ def test_a_put_through_the_ring_keeps_its_lights(tmp_path, keypair):
     assert "service_ms" not in worker
 
 
+def test_a_write_marks_the_replicas_catch_up_on_the_worker(tmp_path,
+                                                           keypair):
+    """After a success the worker waits for its replica to apply the
+    write before it answers (read-your-writes): stage `catchup_ms` of
+    route class `write`, by either transport, outside `proxy_ms`; a
+    409 journals nothing and waits for nothing; the leader marks none.
+    `write_catchup_ms_mean` reads it (dssbench/metrics)."""
+    from tests.test_ring_writes import OP, ROUTE, RingFront
+    from tests.test_ring_writes import _flight as _ring_flight
+
+    f = RingFront(tmp_path, keypair)
+    try:
+        assert f.put(OP.format(1), _ring_flight()).status_code == 200
+        r409 = f.put(OP.format(2), _ring_flight())
+        assert r409.status_code == 409, r409.text
+        key = [c["operation_reference"]["ovn"]
+               for c in r409.json()["entity_conflicts"]]
+        assert f.put(OP.format(2), _ring_flight(key)).status_code == 200
+        ring = {st: v for (r, st), v in
+                f.worker_metrics.stage_hist_snapshot().items() if r == ROUTE}
+        f.h.front.owner_ttl_s = -1.0  # the next write takes the proxy
+        r409 = f.put(OP.format(3), _ring_flight())
+        key = [c["operation_reference"]["ovn"]
+               for c in r409.json()["entity_conflicts"]]
+        assert f.put(OP.format(3), _ring_flight(key)).status_code == 200
+        both = {st: v for (r, st), v in
+                f.worker_metrics.stage_hist_snapshot().items() if r == ROUTE}
+        leader = {st for (r, st) in f.leader_metrics.stage_hist_snapshot()}
+        st = f.stats()
+        assert st["write_ring"] == 3 and st["write_proxied"] == 2
+        # the replica holds every write the worker answered
+        assert f.h.follower.applied_seq >= f.h.leader.wal.seq
+    finally:
+        f.close()
+    assert ring["catchup_ms"][2] == 2 and ring["proxy_ms"][2] == 3
+    assert both["catchup_ms"][2] == 3 and both["proxy_ms"][2] == 5
+    assert 0 < both["catchup_ms"][1]
+    assert "catchup_ms" not in leader
+    assert "catchup_ms" not in LEAVES
+
+
 def test_a_served_write_moves_the_journals_counters_by_its_records(flown):
     def moved(a, b, name):
         return b[name] - a[name]
